@@ -432,6 +432,9 @@ def run_dist_child(pid: int, nprocs: int, block: int, reps: int) -> None:
 # parent: orchestrate subprocesses, aggregate, write the artifact
 # ----------------------------------------------------------------------
 def _spawn_child(args, devcount: int):
+    """Start one CPU-ONLY study child (``JAX_PLATFORMS=cpu``, a virtual
+    device farm): the study measures XLA's CPU collectives and never
+    runs on a chip path, so no child here ever needs a TPU."""
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     # keep inherited XLA flags but OWN the device count: a stale

@@ -23,7 +23,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from sparkrdma_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sparkrdma_tpu.models.terasort import KEY_BITS, SENTINEL

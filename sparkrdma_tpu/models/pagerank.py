@@ -27,7 +27,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from sparkrdma_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 
 from sparkrdma_tpu.parallel.mesh import make_mesh, shard_spec

@@ -29,7 +29,7 @@ from typing import Dict, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from sparkrdma_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sparkrdma_tpu.ops.ulysses_attention import ulysses_shard_attention

@@ -1,32 +1,27 @@
 """ctypes binding for the native off-heap arena (arena.cpp).
 
-Builds the shared library on first use with g++ (cached next to the
-source). If the toolchain is unavailable the caller falls back to
+Builds the shared library on first use with g++, cached next to the
+source under a name keyed by the source and flags (transport_lib's
+``build_library``). If the build fails the caller falls back to
 anonymous ``mmap`` allocations (sparkrdma_tpu.memory.buffer) — same
-semantics, same page alignment, slightly slower alloc path.
+semantics, same page alignment, slightly slower alloc path — and g++'s
+stderr is logged once.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
-import subprocess
 import threading
 from typing import Optional, Tuple
 
-_HERE = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_HERE, "arena.cpp")
+# same build contract as transport_lib.py (sanitizer flags included)
+from sparkrdma_tpu.native.transport_lib import NativeBuildError, build_library
 
-# same SPARKRDMA_NATIVE_SANITIZE contract as transport_lib.py: build a
-# sanitizer-instrumented .so under its own cache name
-from sparkrdma_tpu.native.transport_lib import _SANITIZE, _build_flags  # noqa: E402
+logger = logging.getLogger(__name__)
 
-_SO = os.path.join(
-    _HERE,
-    "_libsrt_arena.%s.so" % _SANITIZE.replace(",", "-").replace("=", "_")
-    if _SANITIZE
-    else "_libsrt_arena.so",
-)
+_SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "arena.cpp")
 
 _lib = None
 _lib_lock = threading.Lock()
@@ -41,16 +36,9 @@ def _load() -> Optional[ctypes.CDLL]:
         if _lib is not None or _build_failed:
             return _lib
         try:
-            if not os.path.exists(_SO) or (
-                os.path.exists(_SRC) and os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-            ):
-                subprocess.run(
-                    ["g++", *_build_flags(), "-o", _SO, _SRC],
-                    check=True,
-                    capture_output=True,
-                )
-            lib = ctypes.CDLL(_SO)
-        except (OSError, subprocess.CalledProcessError):
+            lib = ctypes.CDLL(build_library("_libsrt_arena", _SRC))
+        except (NativeBuildError, OSError):
+            logger.warning("native arena unavailable", exc_info=True)
             _build_failed = True
             return None
         lib.srt_arena_create.restype = ctypes.c_void_p

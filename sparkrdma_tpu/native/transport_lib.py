@@ -1,14 +1,19 @@
 """ctypes binding for the native transport data plane (transport.cpp).
 
-Builds on first use with g++ (cached next to the source), exactly like
-the arena binding. Falls back to None when the toolchain is missing —
-callers then use the pure-Python transport (same wire format, same
-semantics, slower per-byte path).
+Builds on first use with g++, exactly like the arena binding. The
+shared object is cached next to the source under a name keyed by a
+hash of the source and the compiler flags, so only a binary built from
+the ``.cpp`` on disk can load — a stale one copied along with the tree
+never matches. When the build fails, ``load()`` returns None and
+:func:`build_error` keeps g++'s stderr: ``transport=auto`` then picks
+the pure-Python transport (same wire format), while an explicit
+``transport=native`` fails with that stderr (transport.create_node).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import shutil
 import subprocess
@@ -34,17 +39,6 @@ _NO_IOURING = os.environ.get(
 ).strip() not in ("", "0")
 
 
-def _so_path(base: str) -> str:
-    tags = []
-    if _SANITIZE:
-        tags.append(_SANITIZE.replace(",", "-").replace("=", "_"))
-    if _NO_IOURING:
-        tags.append("nouring")
-    if tags:
-        return os.path.join(_HERE, f"{base}.{'.'.join(tags)}.so")
-    return os.path.join(_HERE, f"{base}.so")
-
-
 def _build_flags() -> list:
     flags = ["-O2", "-std=c++17", "-shared", "-fPIC", "-pthread"]
     if _SANITIZE:
@@ -54,11 +48,52 @@ def _build_flags() -> list:
     return flags
 
 
-_SO = _so_path("_libsrt_transport")
+class NativeBuildError(RuntimeError):
+    """g++ could not build a native library; the message carries its
+    stderr."""
+
+
+def so_path(base: str, src: str) -> str:
+    """Cache path of ``src``'s shared object: ``<base>.<hash>.so``,
+    the hash covering the source bytes and the build flags."""
+    h = hashlib.sha256()
+    with open(src, "rb") as f:
+        h.update(f.read())
+    h.update("\0".join(_build_flags()).encode())
+    return os.path.join(_HERE, f"{base}.{h.hexdigest()[:16]}.so")
+
+
+def build_library(base: str, src: str) -> str:
+    """Return the path of ``src``'s shared object, building it first
+    when no object of this source and these flags is cached. Raises
+    :class:`NativeBuildError` with g++'s stderr on failure."""
+    so = so_path(base, src)
+    if os.path.exists(so):
+        return so
+    # build beside the target and rename into place: concurrent
+    # builders (test workers) never load a half-written object
+    tmp = f"{so}.{os.getpid()}.tmp"
+    try:
+        proc = subprocess.run(
+            ["g++", *_build_flags(), "-o", tmp, src],
+            capture_output=True, text=True,
+        )
+    except OSError as e:
+        raise NativeBuildError(f"cannot run g++ for {src}: {e}") from e
+    if proc.returncode != 0:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+        raise NativeBuildError(
+            f"g++ failed building {src} (rc={proc.returncode}):\n"
+            f"{proc.stderr}"
+        )
+    os.replace(tmp, so)
+    return so
+
 
 _lib = None
 _lib_lock = threading.Lock()
-_build_failed = False
+_build_error: Optional[str] = None
 
 # completion kinds (transport.cpp)
 COMP_SEND_DONE = 1
@@ -90,25 +125,16 @@ class SrtComp(ctypes.Structure):
 
 
 def load() -> Optional[ctypes.CDLL]:
-    global _lib, _build_failed
-    if _lib is not None or _build_failed:
+    global _lib, _build_error
+    if _lib is not None or _build_error is not None:
         return _lib
     with _lib_lock:
-        if _lib is not None or _build_failed:
+        if _lib is not None or _build_error is not None:
             return _lib
         try:
-            if not os.path.exists(_SO) or (
-                os.path.exists(_SRC)
-                and os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-            ):
-                subprocess.run(
-                    ["g++", *_build_flags(), "-o", _SO, _SRC],
-                    check=True,
-                    capture_output=True,
-                )
-            lib = ctypes.CDLL(_SO)
-        except (OSError, subprocess.CalledProcessError):
-            _build_failed = True
+            lib = ctypes.CDLL(build_library("_libsrt_transport", _SRC))
+        except (NativeBuildError, OSError) as e:
+            _build_error = str(e)
             return None
         lib.srt_node_create.restype = ctypes.c_void_p
         lib.srt_node_create.argtypes = [ctypes.c_char_p, ctypes.c_uint16, ctypes.c_int]
@@ -189,6 +215,12 @@ def available() -> bool:
     return load() is not None
 
 
+def build_error() -> Optional[str]:
+    """Why the native plane is unavailable (g++'s stderr), or None."""
+    load()
+    return _build_error
+
+
 def toolchain_available() -> bool:
     """True when the native plane is *buildable* here: g++ on PATH or a
     prebuilt .so already cached. Distinct from ``available()``, which
@@ -196,4 +228,6 @@ def toolchain_available() -> bool:
     their skip on THIS so a transport.cpp compile breakage fails
     loudly instead of silently skipping. Cheap (no build triggered),
     so safe to call at pytest collection time."""
-    return shutil.which("g++") is not None or os.path.exists(_SO)
+    return shutil.which("g++") is not None or os.path.exists(
+        so_path("_libsrt_transport", _SRC)
+    )

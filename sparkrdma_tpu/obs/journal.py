@@ -29,7 +29,7 @@ without order. The journal makes them an ordered record:
   order always preserved (``seq`` is strictly increasing per process
   and the process HLC never goes backward).
 
-Event taxonomy (``kind`` values; docs/OBSERVABILITY.md "Event journal
+Event classification (``kind`` values; docs/OBSERVABILITY.md "Event journal
 & capacity plane"):
 
 ==================  ===================================================

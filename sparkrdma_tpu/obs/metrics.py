@@ -57,6 +57,7 @@ METRIC_FAMILIES: Dict[str, Tuple[str, frozenset]] = {
     # whole-stage collective shuffle (shuffle/collective.py, planner.py)
     "collective.plans": ("counter", _L({"role"})),
     "collective.waves": ("counter", _L({"role", "schedule"})),
+    "collective.mover_dispatches": ("counter", _L({"role", "mover"})),
     "collective.blocks": ("counter", _L({"role"})),
     "collective.bytes": ("counter", _L({"role"})),
     "collective.fused_merges": ("counter", _L({"role"})),

@@ -44,7 +44,7 @@ from jax.sharding import Mesh, NamedSharding
 
 from sparkrdma_tpu.obs import get_registry
 from sparkrdma_tpu.parallel.mesh import shard_spec
-from sparkrdma_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 MIN_BUCKET = 1024
 

@@ -13,25 +13,28 @@ path. Two movers are provided behind one call:
   ``shard_map`` exactly as the guide prescribes. TPU meshes only.
 - ``emulated_pull``: ``jax.device_put`` of the source array onto the
   destination device — the same copy expressed through XLA's transfer
-  engine. On a CPU mesh (``JAX_PLATFORMS=cpu``) this is the ONLY
-  mover, which is what makes the whole plane testable in tier-1; on
-  TPU it is also the fallback for single-device processes where no
-  ICI ring exists.
+  engine. It is the per-block planner's mover on every platform, and
+  the wave mover wherever ``is_tpu_mesh()`` is false (the CPU mesh of
+  the tier-1 tests).
 
-The planner (shuffle/device_fetch.py) decides per block whether either
-mover applies; this module only moves bytes.
+The wave programs take a per-row HOP lane: device ``k`` sends row ``i``
+to device ``(k + hop[i]) % n``, so every device sends and receives
+exactly once per row (a rotation, never a fan-in that would leave a
+receive semaphore unsignalled and hang the mesh). A zero hop is a
+local DMA on each device.
+
+The planner (shuffle/device_fetch.py) decides per block whether a
+mover applies; this module only moves bytes, and a mover failure
+raises to the caller.
 """
 
 from __future__ import annotations
 
 import functools
-import logging
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
-
-logger = logging.getLogger(__name__)
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def mesh_device_count() -> int:
@@ -39,10 +42,7 @@ def mesh_device_count() -> int:
 
 
 def is_tpu_mesh() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def emulated_pull(src_array, dst_device):
@@ -52,11 +52,7 @@ def emulated_pull(src_array, dst_device):
     plain buffer copy on the CPU backend. Blocks until the bytes are
     resident so the caller may adopt the result into its arena and
     immediately recycle/unpin the source."""
-    try:
-        src_devices = src_array.devices()
-    except Exception:
-        src_devices = set()
-    if dst_device in src_devices:
+    if dst_device in src_array.devices():
         # src already lives on dst_device: device_put would be a no-op
         # (or an alias of the same buffer). The caller is about to
         # unpin the source arena slab — whose later spill DELETES that
@@ -83,8 +79,6 @@ def _neighbor_pull_program(axis_size: int, shape, dtype_str: str):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from sparkrdma_tpu.utils.jax_compat import shard_map
-
     dtype = jnp.dtype(dtype_str)
 
     def kernel(src_ref, dst_ref, send_sem, recv_sem):
@@ -107,8 +101,8 @@ def _neighbor_pull_program(axis_size: int, shape, dtype_str: str):
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=0,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         scratch_shapes=([pltpu.SemaphoreType.DMA] * 2),
     )
 
@@ -116,13 +110,12 @@ def _neighbor_pull_program(axis_size: int, shape, dtype_str: str):
         kernel,
         out_shape=jax.ShapeDtypeStruct(shape, dtype),
         grid_spec=grid_spec,
+        name="pallas_neighbor_pull",
     )
-
-    from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(jax.devices()[:axis_size], ("x",))
     f = shard_map(
-        pull, mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_rep=False
+        pull, mesh=mesh, in_specs=P("x"), out_specs=P("x"), check_vma=False
     )
     return jax.jit(f)
 
@@ -143,16 +136,64 @@ def pallas_neighbor_pull(sharded_blocks):
     return prog(sharded_blocks)
 
 
+# The wave programs carry each row's bucket as [bucket // 128, 128]:
+# the HBM tiling then covers only a row's own two minor dims, so slicing
+# one row off the leading axis stays tile-aligned (a 2-D [rows, bucket]
+# ref tiles rows in groups of 8 and Mosaic refuses a 1-row slice).
+# Callers shape the stack on the host, where the reshape is free; done
+# inside the program it costs a relayout pass. Buckets are >= 1 KiB,
+# so every class divides into lanes.
+_LANES = 128
+
+
+def wave_row_shape(bucket_elems: int):
+    """Device shape of one wave row of ``bucket_elems`` elements."""
+    return (bucket_elems // _LANES, _LANES)
+
+
+def _hop_copy(src, dst, send_sem, recv_sem, hop, axis_size: int):
+    """Start/wait pair moving ``src`` into ``dst`` on the device ``hop``
+    steps right along the ``x`` axis. Every device runs the same hop
+    for a given row, so each one sends once and receives once: the
+    copy is a rotation of the row around the ring. Hop 0 is a local
+    DMA (one semaphore); a one-device mesh compiles only that."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    local = pltpu.make_async_copy(src, dst, recv_sem)
+    if axis_size == 1:
+        return local.start, local.wait
+    target = jax.lax.rem(jax.lax.axis_index("x") + hop, axis_size)
+    remote = pltpu.make_async_remote_copy(
+        src_ref=src,
+        dst_ref=dst,
+        send_sem=send_sem,
+        recv_sem=recv_sem,
+        device_id=(target,),
+        device_id_type=pltpu.DeviceIdType.MESH,
+    )
+
+    def start():
+        pl.when(hop == 0)(local.start)
+        pl.when(hop != 0)(remote.start)
+
+    def wait():
+        pl.when(hop == 0)(local.wait)
+        pl.when(hop != 0)(remote.wait)
+
+    return start, wait
+
+
 @functools.lru_cache(maxsize=64)
 def _wave_pull_program(axis_size: int, rows: int, bucket_elems: int,
                        dtype_str: str):
     """Jitted shard_map'd Pallas program moving a whole fetch WAVE in
-    one kernel epoch: ``rows`` one-sided remote DMAs started together,
-    waited together — the batched multi-block pull the per-block
-    ``_neighbor_pull_program`` is the building block for. Row *i*'s
-    source device rides in a scalar-prefetch lane (the WR list's
-    per-entry rkey analogue), so one executable serves every wave of
-    the same (rows, bucket) class regardless of which peers it names.
+    one kernel epoch: ``rows`` DMAs started together, waited together —
+    the batched multi-block pull the per-block
+    ``_neighbor_pull_program`` is the building block for. Row *i*'s hop
+    rides in a scalar-prefetch lane (the WR list's per-entry rkey
+    analogue), so one executable serves every wave of the same
+    (rows, bucket) class regardless of which peers it names.
 
     Cached per (mesh size, bucketed rows, bucket elems, dtype) — the
     shuffle-schedule compiler buckets both axes so ragged stages reuse
@@ -160,44 +201,24 @@ def _wave_pull_program(axis_size: int, rows: int, bucket_elems: int,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from sparkrdma_tpu.utils.jax_compat import shard_map
-
     dtype = jnp.dtype(dtype_str)
 
-    def kernel(src_ids, src_ref, dst_ref, send_sem, recv_sem):
-        def start(i, _):
-            op = pltpu.make_async_remote_copy(
-                src_ref=src_ref.at[i],
-                dst_ref=dst_ref.at[i],
-                send_sem=send_sem.at[i],
-                recv_sem=recv_sem.at[i],
-                device_id=(src_ids[i],),
-                device_id_type=pltpu.DeviceIdType.MESH,
+    def kernel(hops, src_ref, dst_ref, send_sem, recv_sem):
+        def copy(i):
+            return _hop_copy(
+                src_ref.at[i], dst_ref.at[i], send_sem.at[i],
+                recv_sem.at[i], hops[i], axis_size,
             )
-            op.start()
-            return _
-
-        def wait(i, _):
-            op = pltpu.make_async_remote_copy(
-                src_ref=src_ref.at[i],
-                dst_ref=dst_ref.at[i],
-                send_sem=send_sem.at[i],
-                recv_sem=recv_sem.at[i],
-                device_id=(src_ids[i],),
-                device_id_type=pltpu.DeviceIdType.MESH,
-            )
-            op.wait()
-            return _
 
         # every DMA in flight before the first wait: the epoch's wall
         # is max(row latency), not sum — the whole point of the wave
-        jax.lax.fori_loop(0, rows, start, 0)
-        jax.lax.fori_loop(0, rows, wait, 0)
+        jax.lax.fori_loop(0, rows, lambda i, c: (copy(i)[0](), c)[1], 0)
+        jax.lax.fori_loop(0, rows, lambda i, c: (copy(i)[1](), c)[1], 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         scratch_shapes=(
             [pltpu.SemaphoreType.DMA((rows,))] * 2
         ),
@@ -205,33 +226,34 @@ def _wave_pull_program(axis_size: int, rows: int, bucket_elems: int,
 
     pull = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((rows, bucket_elems), dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (rows, *wave_row_shape(bucket_elems)), dtype
+        ),
         grid_spec=grid_spec,
+        name="pallas_wave_pull",
     )
-
-    from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(jax.devices()[:axis_size], ("x",))
     f = shard_map(
         pull, mesh=mesh, in_specs=(P(), P("x")), out_specs=P("x"),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(f)
 
 
-def pallas_wave_pull(src_ids, stacked_sharded):
-    """Run one wave's batched remote pull over a sharded [n*rows, b]
-    array; ``src_ids`` is the int32 per-row source-device lane. TPU
-    meshes only — the schedule compiler gates on ``is_tpu_mesh()`` and
-    uses :func:`emulated_wave_pull` otherwise."""
+def pallas_wave_pull(hops, stacked_sharded):
+    """Run one wave's batched pull over a [n*rows, *wave_row_shape(b)]
+    array sharded row-block-wise over the mesh; ``hops`` is the int32
+    per-row lane (device ``k``'s row ``i`` lands on device
+    ``(k + hops[i]) % n``). TPU meshes only — the schedule compiler
+    gates on ``is_tpu_mesh()`` and uses the emulated halves otherwise."""
     if not is_tpu_mesh():
         raise RuntimeError("pallas_wave_pull requires a TPU mesh")
     n = mesh_device_count()
     rows = stacked_sharded.shape[0] // n
-    prog = _wave_pull_program(
-        n, rows, stacked_sharded.shape[1], str(stacked_sharded.dtype)
-    )
-    return prog(src_ids, stacked_sharded)
+    bucket = stacked_sharded.shape[1] * stacked_sharded.shape[2]
+    prog = _wave_pull_program(n, rows, bucket, str(stacked_sharded.dtype))
+    return prog(hops, stacked_sharded)
 
 
 @functools.lru_cache(maxsize=1)
@@ -252,11 +274,7 @@ def emulated_row_pull_start(src_array, dst_device):
     before adopting. Same-device sources go through a jitted copy (an
     independent buffer the source arena's later spill cannot delete);
     cross-device sources ride the transfer engine."""
-    try:
-        src_devices = src_array.devices()
-    except Exception:
-        src_devices = set()
-    if dst_device in src_devices:
+    if dst_device in src_array.devices():
         return _same_device_copy_program()(src_array)
     return jax.device_put(src_array, dst_device)
 
@@ -290,45 +308,39 @@ def emulated_wave_pull(stacked_host, dst_device):
 def _pipelined_wave_pull_program(axis_size: int, depth: int, rows: int,
                                  bucket_elems: int, dtype_str: str):
     """Depth-aware double-buffered wave program: ``depth`` waves of
-    ``rows`` one-sided remote DMAs each, with wave d+1's DMAs STARTED
-    before wave d's wait loop runs — so the interconnect always has a
-    wave in flight while the previous one drains. One DMA-semaphore
-    array per in-flight wave (send and recv), exactly the per-lane
-    scratch shape of ``_wave_pull_program`` replicated per pipeline
-    slot, so wave d's waits never consume wave d+1's completions.
+    ``rows`` DMAs each, with wave d+1's DMAs STARTED before wave d's
+    wait loop runs — so the interconnect always has a wave in flight
+    while the previous one drains. One DMA-semaphore array per
+    in-flight wave (send and recv), exactly the per-lane scratch shape
+    of ``_wave_pull_program`` replicated per pipeline slot, so wave d's
+    waits never consume wave d+1's completions.
 
     The caller groups consecutive same-(rows, bucket) waves up to the
-    ``collective.pipelineDepth`` knob; ragged neighbors fall back to
-    the single-wave program. Cached per (mesh size, depth, rows class,
+    ``collective.pipelineDepth`` knob; ragged neighbors run the
+    single-wave program. Cached per (mesh size, depth, rows class,
     bucket class, dtype) like every other wave executable."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    from sparkrdma_tpu.utils.jax_compat import shard_map
-
     dtype = jnp.dtype(dtype_str)
 
-    def kernel(src_ids, src_ref, dst_ref, *sems):
+    def kernel(hops, src_ref, dst_ref, *sems):
         send_sems, recv_sems = sems[:depth], sems[depth:]
 
-        def _op(d, i):
-            return pltpu.make_async_remote_copy(
-                src_ref=src_ref.at[d, i],
-                dst_ref=dst_ref.at[d, i],
-                send_sem=send_sems[d].at[i],
-                recv_sem=recv_sems[d].at[i],
-                device_id=(src_ids[d, i],),
-                device_id_type=pltpu.DeviceIdType.MESH,
+        def copy(d, i):
+            return _hop_copy(
+                src_ref.at[d, i], dst_ref.at[d, i], send_sems[d].at[i],
+                recv_sems[d].at[i], hops[d, i], axis_size,
             )
 
         def start_wave(d):
             jax.lax.fori_loop(
-                0, rows, lambda i, _: (_op(d, i).start(), _)[1], 0
+                0, rows, lambda i, c: (copy(d, i)[0](), c)[1], 0
             )
 
         def wait_wave(d):
             jax.lax.fori_loop(
-                0, rows, lambda i, _: (_op(d, i).wait(), _)[1], 0
+                0, rows, lambda i, c: (copy(d, i)[1](), c)[1], 0
             )
 
         # the pipeline: wave d+1 is airborne before wave d drains, so
@@ -343,8 +355,8 @@ def _pipelined_wave_pull_program(axis_size: int, depth: int, rows: int,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY),
         scratch_shapes=(
             [pltpu.SemaphoreType.DMA((rows,))] * (2 * depth)
         ),
@@ -352,47 +364,33 @@ def _pipelined_wave_pull_program(axis_size: int, depth: int, rows: int,
 
     pull = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((depth, rows, bucket_elems), dtype),
+        out_shape=jax.ShapeDtypeStruct(
+            (depth, rows, *wave_row_shape(bucket_elems)), dtype
+        ),
         grid_spec=grid_spec,
+        name="pallas_pipelined_wave_pull",
     )
-
-    from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(jax.devices()[:axis_size], ("x",))
     f = shard_map(
         pull, mesh=mesh, in_specs=(P(), P("x")), out_specs=P("x"),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(f)
 
 
-def pallas_pipelined_wave_pull(src_ids, stacked_sharded, depth: int):
+def pallas_pipelined_wave_pull(hops, stacked_sharded, depth: int):
     """Run ``depth`` same-class waves as one double-buffered kernel
-    epoch over a sharded [n*depth, rows, b] array; ``src_ids`` is the
-    [depth, rows] int32 source-device lane. TPU meshes only — the
-    schedule compiler gates on ``is_tpu_mesh()`` and uses the
-    emulated issue/wait halves otherwise."""
+    epoch over a [n*depth, rows, *wave_row_shape(b)] array sharded over
+    the mesh; ``hops`` is the [depth, rows] int32 hop lane. TPU meshes
+    only — the schedule compiler gates on ``is_tpu_mesh()`` and uses
+    the emulated issue/wait halves otherwise."""
     if not is_tpu_mesh():
         raise RuntimeError("pallas_pipelined_wave_pull requires a TPU mesh")
     n = mesh_device_count()
     rows = stacked_sharded.shape[1]
+    bucket = stacked_sharded.shape[2] * stacked_sharded.shape[3]
     prog = _pipelined_wave_pull_program(
-        n, depth, rows, stacked_sharded.shape[2], str(stacked_sharded.dtype)
+        n, depth, rows, bucket, str(stacked_sharded.dtype)
     )
-    return prog(src_ids, stacked_sharded)
-
-
-def pull_block(src_array, dst_device) -> Optional[object]:
-    """Best-effort single-block pull used by the planner.
-
-    Today both the TPU and emulated paths route through the transfer
-    engine (``emulated_pull``); the ring-scheduled Pallas program above
-    is used by the bench's device A/B and is the building block for
-    batched multi-block pulls (one program invocation moving a whole
-    fetch window). Returns None on any failure — the planner treats
-    that as one more reason to fall back to host fetch."""
-    try:
-        return emulated_pull(src_array, dst_device)
-    except Exception:
-        logger.exception("device pull failed; falling back to host path")
-        return None
+    return prog(hops, stacked_sharded)

@@ -24,7 +24,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from sparkrdma_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sparkrdma_tpu.parallel.mesh import make_mesh

@@ -21,7 +21,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from sparkrdma_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sparkrdma_tpu.ops.pallas_attention import flash_attention

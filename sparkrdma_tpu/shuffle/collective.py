@@ -19,13 +19,12 @@ concurrently with the last via the caller's ``drain`` callback.
 Movers, by regime:
 
 - TPU mesh: ``ops/remote_copy.pallas_wave_pull`` — one Pallas kernel
-  epoch issuing ``rows`` ``make_async_remote_copy`` DMAs together
-  (start all, wait all), per-row source device ids in a
-  scalar-prefetch lane so one executable serves any peer set.
+  epoch issuing ``rows`` DMAs together (start all, wait all), a per-row
+  hop lane in scalar prefetch so one executable serves any peer set.
   Consecutive same-class waves coalesce into the depth-aware
   ``pallas_pipelined_wave_pull`` program — one DMA-semaphore array per
   in-flight wave, wave d+1 started before wave d drains.
-- Everywhere else (and on any TPU-side surprise): the emulated mover's
+- Everywhere else (the CPU mesh): the emulated mover's
   ISSUE/CONSUME halves (``emulated_row_pull_start`` /
   ``emulated_wave_wait``) — per-row pulls started together without
   waiting, landed slabs adopted directly (the same single-copy
@@ -50,7 +49,8 @@ WaveAutoTuner` re-derives the effective ``collective.waveBytes`` per
 the job's TimeBreakdown and profiler gap frames — the second identical
 stage of a job already runs with the adjusted cut.
 
-Degrade ladder (every rung silent, byte-identical):
+Degrade ladder (byte-identical; on a TPU mesh a mover failure raises
+instead, since the Pallas movers are the path under test there):
 
 | condition                                   | outcome             |
 |---------------------------------------------|---------------------|
@@ -58,7 +58,8 @@ Degrade ladder (every rung silent, byte-identical):
 | < ``collective.minBlocks`` device blocks     | per-block planner   |
 | block fails eligibility (size/dtype/arena)   | per-block planner   |
 | slab evicted/spilled between plan and pin    | host triple, degrade++ |
-| wave mover fails (issue OR landing)          | host triple, degrade++ |
+| emulated mover fails (issue OR landing)     | host triple, degrade++ |
+| Pallas mover fails (TPU mesh)               | raises              |
 | row adoption fails mid-pipeline              | host triple, degrade++ |
 | abort unwinds with waves in flight           | pins closed, rows degrade |
 """
@@ -227,8 +228,8 @@ class _InflightWave:
         # per wave: assembled host stack (None when every row rode the
         # fast lane or a view)
         self.stacked_hosts: List[Optional[np.ndarray]] = []
-        # TPU/fallback in-flight device object: ("single"|"pipelined",
-        # async sharded result) or ("emulated", [stacks])
+        # TPU in-flight kernel result: ("single"|"pipelined", async
+        # sharded output)
         self.landed = None
         self.nbytes = 0
         self.live = 0
@@ -461,10 +462,10 @@ class ShuffleScheduleCompiler:
         — the caller host-fetches them; with fusion on, a miss also
         unfuses its partition (the survivors land per block, the host
         fills the gap), so the byte content of the stage is identical
-        on every path. Per-entry failures never raise; if an exception
-        DOES unwind (e.g. out of ``drain``), every in-flight entry's
-        pins are closed on the way out — no slab or pin outlives the
-        stage."""
+        on every path. Per-entry failures raise only from a Pallas
+        mover on a TPU mesh; if an exception DOES unwind (that, or
+        ``drain``), every in-flight entry's pins are closed on the way
+        out — no slab or pin outlives the stage."""
         if not plan.waves:
             return [], []
         fused = bool(fused) and self._conf.collective_fused_merge
@@ -563,6 +564,16 @@ class ShuffleScheduleCompiler:
         return results, degraded
 
     # ------------------------------------------------------------------
+    def _mover_dispatched(self, mover: str) -> None:
+        """Count one pipeline entry's dispatch by the mover that ran it
+        (``collective.mover_dispatches{mover}``): the on-chip smoke
+        asserts from this that the Pallas movers, not the transfer
+        engine, carried a TPU mesh's waves."""
+        get_registry().counter(
+            "collective.mover_dispatches", role=self._executor_id,
+            mover=mover,
+        ).inc()
+
     def _program_key_seen(self, key) -> None:
         with self._cache_lock:
             if key in self._seen_programs:
@@ -604,9 +615,10 @@ class ShuffleScheduleCompiler:
         """Pin, assemble, and DISPATCH one pipeline entry without
         waiting — the issue half of the double buffer. Rows that fail
         the under-pin residency re-check come back in ``entry.dead``
-        (the caller degrades them); a mover surprise returns None and
-        the whole entry degrades. The entry's pins stay held until its
-        consume: the source slabs must outlive the in-flight DMAs."""
+        (the caller degrades them); an emulated mover failure returns
+        None and the whole entry degrades, while on a TPU mesh any
+        failure raises. The entry's pins stay held until its consume:
+        the source slabs must outlive the in-flight DMAs."""
         t0 = time.perf_counter()
         itemsize = np.dtype(dtype).itemsize
         tpu = remote_copy.is_tpu_mesh()
@@ -682,6 +694,8 @@ class ShuffleScheduleCompiler:
                 return entry
             if tpu:
                 entry.landed = self._dispatch_pallas(waves, entry, dtype)
+            else:
+                self._mover_dispatched("emulated")
             if len(waves) > 1:
                 key = ("wave-pipe", len(waves), waves[0].rows_b,
                        waves[0].bucket_elems, np.dtype(dtype).name)
@@ -692,8 +706,12 @@ class ShuffleScheduleCompiler:
                            np.dtype(dtype).name)
                     self._program_key_seen(key)
         except Exception:
-            logger.exception("collective wave issue failed; degrading to host")
             pins.close()
+            if tpu:
+                # on a TPU mesh the Pallas movers ARE the path: a failure
+                # there is a bug to surface, not a row to degrade
+                raise
+            logger.exception("collective wave issue failed; degrading to host")
             return None
         entry.live = len(live_rows)
         entry.nbytes = sum(r.elems * itemsize for r in live_rows)
@@ -713,48 +731,50 @@ class ShuffleScheduleCompiler:
 
     def _dispatch_pallas(self, waves: List[CollectiveWave],
                          entry: _InflightWave, dtype):
-        """START the entry's remote DMAs as one kernel epoch (the
-        depth-aware double-buffered program when the entry carries a
-        same-class run) WITHOUT waiting; consume slices the landed
-        result per wave. The send-layout shards carry the waves on
-        every source device; the per-row id lane names which peer's
-        DMA lands each row. Any bring-up surprise falls back to the
-        transfer engine — same bytes."""
+        """START the entry's DMAs as one kernel epoch (the depth-aware
+        double-buffered program when the entry carries a same-class
+        run) WITHOUT waiting; consume slices the landed result per
+        wave. The send-layout shards carry the waves on every device;
+        the per-row hop lane makes this executor's device receive row
+        i from the chip that published it. A mover failure raises."""
         import jax
 
-        n = remote_copy.mesh_device_count()
-        try:
-            if len(waves) == 1:
-                wave = waves[0]
-                ids = np.zeros((wave.rows_b,), dtype=np.int32)
-                for i, row in enumerate(wave.rows):
-                    ids[i] = max(0, row.loc.block.device_coords) % n
-                sharded = jax.device_put(
-                    np.tile(entry.stacked_hosts[0], (n, 1))
-                )
-                return ("single", remote_copy.pallas_wave_pull(ids, sharded))
-            depth = len(waves)
-            rows_b = waves[0].rows_b
-            b_elems = waves[0].bucket_elems
-            ids = np.zeros((depth, rows_b), dtype=np.int32)
-            stack = np.zeros((depth, rows_b, b_elems), dtype=dtype)
-            for d, wave in enumerate(waves):
-                stack[d] = entry.stacked_hosts[d]
-                for i, row in enumerate(wave.rows):
-                    ids[d, i] = max(0, row.loc.block.device_coords) % n
-            sharded = jax.device_put(np.tile(stack, (n, 1, 1)))
-            return (
-                "pipelined",
-                remote_copy.pallas_pipelined_wave_pull(ids, sharded, depth),
+        devices = jax.devices()[: remote_copy.mesh_device_count()]
+        n = len(devices)
+        index = {d.id: k for k, d in enumerate(devices)}
+        dst = index[self._dev.device.id]
+
+        def hop(row: _Row) -> int:
+            return (dst - index[row.loc.block.device_coords]) % n
+
+        lanes = remote_copy.wave_row_shape(waves[0].bucket_elems)
+        if len(waves) == 1:
+            wave = waves[0]
+            hops = np.zeros((wave.rows_b,), dtype=np.int32)
+            for i, row in enumerate(wave.rows):
+                hops[i] = hop(row)
+            sharded = jax.device_put(
+                np.tile(entry.stacked_hosts[0], (n, 1)).reshape(-1, *lanes)
             )
-        except Exception:
-            logger.exception("pallas wave mover failed; using transfer engine")
-            return ("emulated", [
-                remote_copy.emulated_wave_issue(
-                    entry.stacked_hosts[d], self._dev.device
-                )
-                for d in range(len(waves))
-            ])
+            self._mover_dispatched("pallas_wave_pull")
+            return ("single", remote_copy.pallas_wave_pull(hops, sharded))
+        depth = len(waves)
+        rows_b = waves[0].rows_b
+        b_elems = waves[0].bucket_elems
+        hops = np.zeros((depth, rows_b), dtype=np.int32)
+        stack = np.zeros((depth, rows_b, b_elems), dtype=dtype)
+        for d, wave in enumerate(waves):
+            stack[d] = entry.stacked_hosts[d]
+            for i, row in enumerate(wave.rows):
+                hops[d, i] = hop(row)
+        sharded = jax.device_put(
+            np.tile(stack, (n, 1, 1)).reshape(-1, rows_b, *lanes)
+        )
+        self._mover_dispatched("pallas_pipelined_wave_pull")
+        return (
+            "pipelined",
+            remote_copy.pallas_pipelined_wave_pull(hops, sharded, depth),
+        )
 
     def _consume_entry(
         self, entry: _InflightWave, shuffle_id: int, dtype, fused: bool,
@@ -762,10 +782,10 @@ class ShuffleScheduleCompiler:
         reg, overlapped: bool, stats: Dict[str, float],
     ) -> None:
         """Wait for one entry's transfers (the recv-semaphore wait),
-        release its pins, and adopt its rows into arena slabs. Never
-        raises: a landing failure degrades the entry, an adoption
+        release its pins, and adopt its rows into arena slabs. An
+        emulated landing failure degrades the entry and an adoption
         failure degrades the affected rows — the pipeline keeps
-        flowing either way."""
+        flowing; a Pallas landing failure raises."""
         t0 = time.perf_counter()
         role = self._executor_id
         try:
@@ -778,6 +798,11 @@ class ShuffleScheduleCompiler:
             remote_copy.emulated_wave_wait(waiting)
             stacked_devs = self._landed_stacks(entry)
         except Exception:
+            if entry.landed is not None:
+                # a Pallas epoch that failed to land is a device fault:
+                # surface it (the pins still release)
+                entry.close()
+                raise
             logger.exception(
                 "collective wave landing failed; degrading to host"
             )
@@ -847,24 +872,22 @@ class ShuffleScheduleCompiler:
     _schedule_label = "ring"
 
     def _landed_stacks(self, entry: _InflightWave):
-        """Per-wave landed device stacks for the TPU/fallback paths
-        (None on the pure emulated path, whose rows adopt from the
-        fast-lane arrays and the host assembly directly)."""
+        """Per-wave landed device stacks of a Pallas entry, read from
+        this executor's own shard of the kernel's output (None on the
+        emulated path, whose rows adopt from the fast-lane arrays and
+        the host assembly directly)."""
         if entry.landed is None:
             return None
-        import jax
-
         kind, obj = entry.landed
-        if kind == "emulated":
-            return obj
-        if kind == "single":
-            wave = entry.waves[0]
-            arr = np.asarray(obj)[: wave.rows_b]
-            return [jax.device_put(arr, self._dev.device)]
-        arr = np.asarray(obj)[: len(entry.waves)]
+        mine = next(
+            s.data for s in obj.addressable_shards
+            if s.device == self._dev.device
+        )
+        waves = [mine] if kind == "single" else list(mine)
+        # back to [rows, bucket] (one on-device relayout per wave)
         return [
-            jax.device_put(arr[d], self._dev.device)
-            for d in range(len(entry.waves))
+            w.reshape(wave.rows_b, wave.bucket_elems)
+            for w, wave in zip(waves, entry.waves)
         ]
 
     def _adopt_wave(self, wave, stacked_dev, dtype, fused, fusable_pids,

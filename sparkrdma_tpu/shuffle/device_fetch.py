@@ -7,8 +7,8 @@ its ``(device_coords, arena_handle, arena_offset)`` next to the host
 extension), and the planner here decides per block whether the bytes
 can move HBM→HBM — a Pallas/transfer-engine pull with no host CPU in
 the data path (ops/remote_copy.py) — or must take the host socket
-path. The host triple is ALWAYS valid; every planner outcome other
-than a completed pull is a silent fallback, never an error, so an
+path. The host triple is ALWAYS valid; every planner decision in the
+table below other than a pull is a fallback, never an error, so an
 arena that spilled (or freed) the shard mid-job degrades to exactly
 the pre-existing behavior.
 
@@ -29,8 +29,10 @@ Planner decision table (see DESIGN.md §17):
 | source arena not mesh-visible                | host, fallback++|
 | arena slab freed / spilled / being spilled   | host, fallback++|
 | staged dtype ≠ requested dtype               | host, fallback++|
-| pull itself fails                            | host, fallback++|
 | otherwise                                    | device pull    |
+
+A failure of the mover itself is not in the table: it raises to the
+fetch, like any other device error.
 
 Checksums are verified at publish time on the host copy; the device
 copy is the same staged bytes, so device pulls trust them (the host
@@ -149,16 +151,12 @@ class DeviceFetchPlane:
     def try_pull(self, loc: PartitionLocation, dtype=np.uint8) -> Optional[DeviceBuffer]:
         """Plan + execute one block pull; None means 'use the host path'.
 
-        Never raises: any surprise inside the mover is swallowed into a
-        fallback (the acceptance bar — an eviction/spill race degrades,
-        it does not error)."""
+        The planner's decisions (the module table) return None; an
+        eviction/spill race degrades, it does not error. A failure of
+        the mover itself raises."""
         t0 = time.perf_counter()
         try:
             return self._try_pull(loc, dtype)
-        except Exception:
-            logger.exception("device pull errored; using host path")
-            self._fallback("unexpected error")
-            return None
         finally:
             self._m_plan_ms.observe((time.perf_counter() - t0) * 1e3)
 
@@ -187,10 +185,7 @@ class DeviceFetchPlane:
                 # free, a device-side cast would compile per shape
                 self._fallback("staged dtype mismatch")
                 return None
-            pulled = remote_copy.pull_block(src.array, self._dev.device)
-            if pulled is None:
-                self._fallback("mover failed")
-                return None
+            pulled = remote_copy.emulated_pull(src.array, self._dev.device)
             # adopt into the local arena: source and destination size
             # classes match (same power-of-two classing both sides), so
             # the pulled slab-capacity array fits exactly

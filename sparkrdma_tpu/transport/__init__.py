@@ -7,19 +7,23 @@ def create_node(conf, host, is_executor, executor_id, recv_listener=None,
                 peer_lost_listener=None):
     """Node factory honoring ``tpu.shuffle.transport`` (python | native).
 
-    Native (C++ epoll data plane) silently falls back to the Python
-    transport when the toolchain is unavailable — same wire format."""
+    ``native`` resolves to the C++ epoll data plane; where it was asked
+    for and cannot be built, this raises with g++'s stderr (``auto``
+    already resolved to python when the build fails)."""
     if conf.transport == "native":
-        from sparkrdma_tpu.native.transport_lib import available
+        from sparkrdma_tpu.native.transport_lib import available, build_error
 
-        if available():
-            from sparkrdma_tpu.transport.native_node import NativeTpuNode
-
-            return NativeTpuNode(
-                conf, host, is_executor, executor_id,
-                recv_listener=recv_listener,
-                peer_lost_listener=peer_lost_listener,
+        if not available():
+            raise RuntimeError(
+                f"tpu.shuffle.transport=native is unavailable:\n{build_error()}"
             )
+        from sparkrdma_tpu.transport.native_node import NativeTpuNode
+
+        return NativeTpuNode(
+            conf, host, is_executor, executor_id,
+            recv_listener=recv_listener,
+            peer_lost_listener=peer_lost_listener,
+        )
     return TpuNode(
         conf, host, is_executor, executor_id,
         recv_listener=recv_listener,

@@ -1,0 +1,144 @@
+"""Compile the main path's device programs for a described TPU v5e.
+
+No chip is attached here: JAX's TPU compiler compiles for a v5e:2x2
+topology that is described, not present (on-chip-measurement guide,
+section 2). That catches what interpret mode cannot — tiling-misaligned
+ref slices, programs that do not fit HBM, kernels that cannot be
+partitioned — at no chip time. Nothing runs, so nothing here is a time
+or a result.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process at a time may load the TPU library, and every
+xdist worker imports this file. The program builders take their mesh
+from ``jax.devices()``; the tests hand them the described devices by
+patching that call around the build.
+"""
+
+import re
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+V5E_HBM = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a described-topology compile cannot be read back without a chip:
+    # keep the persistent cache out of it
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.fixture()
+def described_devices(topo, monkeypatch):
+    """Program builders see the described chips as ``jax.devices()``."""
+    monkeypatch.setattr(jax, "devices", lambda *a, **k: list(topo.devices))
+    return list(topo.devices)
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize(
+    "n, depth, rows, bucket_elems",
+    [
+        (4, 1, 4, 1 << 22),   # --chips 4 wave: 4 rows of 16 MiB
+        (4, 2, 4, 1 << 22),   # ... pipelined at depth 2
+        (1, 1, 1, 1 << 25),   # one-chip e2e: a 128 MiB row, local DMA
+        (1, 2, 1, 1 << 25),
+        (4, 1, 8, 256),       # smallest bucket class (1 KiB of uint32)
+    ],
+)
+def test_wave_programs_compile(described_devices, n, depth, rows,
+                               bucket_elems):
+    from sparkrdma_tpu.ops import remote_copy
+
+    mesh = Mesh(np.array(described_devices[:n]), ("x",))
+    rep, sh = NamedSharding(mesh, P()), NamedSharding(mesh, P("x"))
+    lanes = remote_copy.wave_row_shape(bucket_elems)
+    if depth == 1:
+        prog = remote_copy._wave_pull_program.__wrapped__(
+            n, rows, bucket_elems, "uint32"
+        )
+        args = (_sds((rows,), jnp.int32, rep),
+                _sds((n * rows, *lanes), jnp.uint32, sh))
+    else:
+        prog = remote_copy._pipelined_wave_pull_program.__wrapped__(
+            n, depth, rows, bucket_elems, "uint32"
+        )
+        args = (_sds((depth, rows), jnp.int32, rep),
+                _sds((n * depth, rows, *lanes), jnp.uint32, sh))
+    compiled = prog.lower(*args).compile()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    # rows arrive already lane-shaped: no relayout pass around the DMAs
+    assert not re.search(r"\sfusion\(", hlo)
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes < V5E_HBM
+
+
+def test_neighbor_pull_compiles(described_devices):
+    from sparkrdma_tpu.ops import remote_copy
+
+    mesh = Mesh(np.array(described_devices), ("x",))
+    prog = remote_copy._neighbor_pull_program.__wrapped__(
+        4, (1, 1 << 20), "uint32"
+    )
+    compiled = prog.lower(
+        _sds((4, 1 << 20), jnp.uint32, NamedSharding(mesh, P("x")))
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_map_shard_sorter_compiles_one_chip(topo):
+    """The map side's device sort + cut at 2^27 keys (one executor's
+    half of the 1 GiB TeraSort)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from sparkrdma_tpu.models import MapShardSorter
+
+    one = SingleDeviceSharding(topo.devices[0])
+    compiled = MapShardSorter()._step.lower(
+        _sds((1 << 27,), jnp.uint32, one),
+        _sds((7,), jnp.uint32, one),
+        _sds((), jnp.int32, one),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes >= 1 << 29
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM
+
+
+def test_terasorter_step_compiles_four_chips(topo):
+    """The mesh TeraSort step at 2^28 keys over the 2x2 mesh: one
+    all-to-all exchange between the local and the merge sorts."""
+    from sparkrdma_tpu.models import TeraSorter
+    from sparkrdma_tpu.parallel.mesh import make_mesh, shard_spec
+
+    mesh = make_mesh(list(topo.devices))
+    sorter = TeraSorter(mesh)
+    n_local = (1 << 28) // sorter.num_shards
+    compiled = sorter.step(n_local).lower(
+        _sds((1 << 28,), jnp.uint32, NamedSharding(mesh, shard_spec(mesh)))
+    ).compile()
+    assert "all-to-all" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM

@@ -588,3 +588,80 @@ def test_planner_lane_balanced_cuts():
     # balanced lanes change nothing
     even = {src: [25] * p for src in ("la", "lb", "lc", "ld")}
     assert ap.plan(sizes, n, lane_sizes=even) == base
+
+
+# ----------------------------------------------------------------------
+# the TPU mesh path: Pallas movers, no silent transfer-engine fallback
+# ----------------------------------------------------------------------
+def _clear_wave_programs():
+    from sparkrdma_tpu.ops import remote_copy
+
+    remote_copy._wave_pull_program.cache_clear()
+    remote_copy._pipelined_wave_pull_program.cache_clear()
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_tpu_mesh_pallas_mover_failure_raises(cluster, monkeypatch, depth):
+    """On a TPU mesh the Pallas movers are the path: a mover failure
+    raises out of the fetch instead of degrading its rows to the host
+    triple, and the pins still release."""
+    from sparkrdma_tpu.ops import remote_copy
+
+    conf, io_map, io_red = cluster
+    conf.set("tpu.shuffle.collective.autoTune", "false")
+    conf.set("tpu.shuffle.collective.waveBytes", "192k")
+    conf.set("tpu.shuffle.collective.pipelineDepth", str(depth))
+    _publish_shards(io_map, seed=97)
+    degrades = _counter("collective.degrades", "cs-red")
+    d0 = degrades.value
+
+    def broken(*_a, **_k):
+        raise RuntimeError("injected: pallas mover failed")
+
+    monkeypatch.setattr(remote_copy, "is_tpu_mesh", lambda: True)
+    monkeypatch.setattr(remote_copy, "pallas_wave_pull", broken)
+    monkeypatch.setattr(remote_copy, "pallas_pipelined_wave_pull", broken)
+    with pytest.raises(RuntimeError, match="injected: pallas mover"):
+        io_red.fetch_host_blocks(91, 0, 3, timeout_s=30)
+    assert degrades.value == d0, "a TPU mover failure must not degrade"
+    assert not io_map.device_buffers._pins
+
+
+def test_pallas_wave_fetch_four_devices_interpreted(monkeypatch):
+    """chip_smoke.py's --chips 4 wave phase, rehearsed on the CPU mesh:
+    the real Pallas wave programs in TPU interpret mode (remote DMAs and
+    semaphores simulated across devices), four executors with arenas
+    on four devices, depth 1 and 2 — byte-identical to the host path,
+    carried by the Pallas movers only."""
+    import importlib.util
+    import os
+
+    import jax
+    from jax.experimental.pallas import tpu as pltpu
+
+    from sparkrdma_tpu.ops import remote_copy
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke",
+        os.path.join(os.path.dirname(__file__), "..", "chip_smoke.py"),
+    )
+    chip_smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(chip_smoke)
+
+    monkeypatch.setattr(remote_copy, "is_tpu_mesh", lambda: True)
+    # a 4-device mesh, as on the 4-chip host (the interpreter's
+    # cross-device rendezvous stalls on the 8-device farm's thread pool)
+    monkeypatch.setattr(remote_copy, "mesh_device_count", lambda: 4)
+    _clear_wave_programs()
+    pltpu.set_tpu_interpret_mode(pltpu.InterpretParams())
+    try:
+        rec = chip_smoke.phase_wave_fetch(
+            jax.devices()[:4], block_keys=1 << 12, transport="python"
+        )
+    finally:
+        pltpu.set_tpu_interpret_mode(None)
+        _clear_wave_programs()
+    assert rec["arena_devices"] == [d.id for d in jax.devices()[:4]]
+    assert rec["depth1"]["movers"]["pallas_wave_pull"] > 0
+    assert rec["depth2"]["movers"]["pallas_pipelined_wave_pull"] > 0
+    assert rec["depth1"]["blocks_pulled"] == 32

@@ -201,6 +201,26 @@ def test_planner_degrades_to_host_on_arena_spill(cluster):
     assert fallbacks.value - f0 == 3, "each block counts one fallback"
 
 
+def test_mover_failure_surfaces_instead_of_falling_back(cluster, monkeypatch):
+    """A failure of the mover itself is not a planner decision: it
+    raises out of the fetch, and no fallback is counted for it."""
+    from sparkrdma_tpu.ops import remote_copy
+
+    conf, io_map, io_red = cluster
+    conf.set("tpu.shuffle.collective.enabled", "false")
+    _publish(io_map)
+
+    def broken(*_a, **_k):
+        raise RuntimeError("injected: transfer engine failed")
+
+    monkeypatch.setattr(remote_copy, "emulated_pull", broken)
+    _pulls, fallbacks = _plane_counters("dfp-red")
+    f0 = fallbacks.value
+    with pytest.raises(RuntimeError, match="injected: transfer engine"):
+        io_red.fetch_device_blocks(81, 0, 3, timeout_s=30)
+    assert fallbacks.value == f0
+
+
 def test_planner_skips_blocks_below_min_bytes(cluster):
     """Blocks under deviceFetch.minBlockBytes publish no pull-worthy
     offer the planner accepts: host path, one fallback each (the device

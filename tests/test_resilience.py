@@ -2,7 +2,7 @@
 
 RetryPolicy determinism, the CircuitBreaker state machine, the checksum
 utility, the RPC checksum wire extension (including legacy frames), the
-fault-plan spec grammar, and the error taxonomy in shuffle/errors.py.
+fault-plan spec grammar, and the error classification in shuffle/errors.py.
 """
 
 import zlib
@@ -228,9 +228,9 @@ def test_publish_msg_checksum_survives_segmentation():
 
 
 # ----------------------------------------------------------------------
-# errors taxonomy
+# errors classification
 # ----------------------------------------------------------------------
-def test_error_taxonomy():
+def test_error_classification():
     mid = ShuffleManagerId("h", 1, "e")
     f = FetchFailedError(mid, 1, 2, 3, "boom")
     assert isinstance(f, ShuffleError)
