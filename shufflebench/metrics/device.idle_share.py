@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of the device op intervals) / window, averaged over chips."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    lo, hi = run.trace.window()
+    return 100.0 * (1.0 - run.trace.busy_s() / ((hi - lo) / 1e9))
