@@ -420,8 +420,6 @@ def bench_e2e_terasort(gb: float, transport: str, reducers: int = 8,
         driver.stop()
 
     total = sum(phases.values())
-    ft = float(metrics.get("fetch_transport_s", 0.0))
-    fs = float(metrics.get("fetch_stage_s", 0.0))
     # publish cost: the solo uncontended measurement scaled to all
     # executors (see above). Busy timers from the pipelined phase stay
     # in the table, labeled contended, for transparency.
@@ -449,16 +447,14 @@ def bench_e2e_terasort(gb: float, transport: str, reducers: int = 8,
             pipe_report.stage_busy_s["publish"], 3
         ),
         "map_pipeline_overlap_saved_s": round(pipe_report.overlap_s, 3),
-        "framework_fetch_transport_s": round(ft, 3),
         "framework_reduce_residual_s": round(reduce_residual, 3),
-        "host_to_hbm_staging_s": round(fs, 3),
         "verify_readback_s": extra_busy["verify_readback_s"],
     }
-    # the framework's OWN code (registration+publish+location RPC+READ
-    # transport+orchestration residual): what the reference's plugin
+    # the framework's OWN code (registration+publish+orchestration
+    # residual): what the reference's plugin
     # adds over Spark's sort machinery — compare against
     # host_sort_baseline_s
-    framework_attributable = publish_uncontended + ft + reduce_residual
+    framework_attributable = publish_uncontended + reduce_residual
     report(
         "terasort_e2e", total,
         gb=round(n * 4 / (1 << 30), 3), transport=transport,
@@ -479,8 +475,8 @@ def bench_e2e_terasort(gb: float, transport: str, reducers: int = 8,
         note=(
             "seconds is the measured wall: map+publish wall plus reduce "
             "wall, host->HBM staging included. framework_attributable_s "
-            "is the framework's OWN code (uncontended publish + fetch "
-            "transport + reduce orchestration residual — the role the "
+            "is the framework's OWN code (uncontended publish + reduce "
+            "orchestration residual — the role the "
             "reference's plugin plays over Spark's sort machinery); "
             "busy rows overlap and do not sum to the wall"
         ),

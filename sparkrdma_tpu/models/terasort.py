@@ -34,6 +34,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from sparkrdma_tpu.obs import get_tracer
 from sparkrdma_tpu.ops.sort import (
     device_sort,
     merge_received,
@@ -67,8 +68,11 @@ class MapShardSorter:
     nothing.
     """
 
-    def __init__(self, device=None):
+    def __init__(self, device=None, tracer=None):
         self._device = device
+        # map.sort.{pad,h2d,device,d2h} spans: the host work around the
+        # sort, apart from the sort itself
+        self._tracer = tracer if tracer is not None else get_tracer("map")
 
         @jax.jit
         def _step(padded, edges, n_valid):
@@ -108,20 +112,27 @@ class MapShardSorter:
         Returns ``(sorted_keys [n], bounds [num_reducers + 1])`` with
         reducer r's keys at ``sorted_keys[bounds[r]:bounds[r + 1]]``.
         """
+        timed = self._tracer.timed
         n = len(keys)
         cap = self._size_class(n)
-        padded = np.full((cap,), np.uint32(SENTINEL), dtype=np.uint32)
-        padded[:n] = keys
-        dev = jnp.asarray(padded)
-        if self._device is not None:
-            dev = jax.device_put(dev, self._device)
-        s, cuts = self._step(
-            dev, jnp.asarray(edges, jnp.uint32), jnp.int32(n)
-        )
-        local = np.asarray(s)[:n]
-        bounds = np.concatenate(
-            [[0], np.asarray(cuts, dtype=np.int64), [n]]
-        )
+        with timed("map.sort.pad"):
+            padded = np.full((cap,), np.uint32(SENTINEL), dtype=np.uint32)
+            padded[:n] = keys
+        with timed("map.sort.h2d"):
+            dev = jnp.asarray(padded)
+            if self._device is not None:
+                dev = jax.device_put(dev, self._device)
+            dev.block_until_ready()
+        with timed("map.sort.device"):
+            s, cuts = self._step(
+                dev, jnp.asarray(edges, jnp.uint32), jnp.int32(n)
+            )
+            jax.block_until_ready((s, cuts))
+        with timed("map.sort.d2h"):
+            local = np.asarray(s)[:n]
+            bounds = np.concatenate(
+                [[0], np.asarray(cuts, dtype=np.int64), [n]]
+            )
         return local, bounds
 
     def sort_columnar_partition(

@@ -49,10 +49,14 @@ PREFIX_CATEGORIES: Dict[str, str] = {
     "reader.pipeline.merge": DEVICE_COMPUTE,
     "reader.pipeline.stage": DEVICE_COMPUTE,
     "writer.pipeline.stage": DEVICE_COMPUTE,
+    "map.sort.": DEVICE_COMPUTE,
+    "map.stage.": DEVICE_COMPUTE,
     "exchange.": DEVICE_COMPUTE,
     "shuffle.collective.wave": DMA_WAVE,
     "shuffle.collective": DMA_WAVE,
     "device_fetch.": DMA_WAVE,
+    "fetch.plan": DMA_WAVE,
+    "fetch.wave.": DMA_WAVE,
     "shuffle.fetch": HOST_READ,  # fetch group (NOT fetch_request: see RPC)
     "transport.native_read": HOST_READ,
     "reader.pipeline.fetch": HOST_READ,
@@ -61,6 +65,7 @@ PREFIX_CATEGORIES: Dict[str, str] = {
     "shuffle.fetch_request": RPC,
     "shuffle.publish": RPC,
     "shuffle.resolve": RPC,
+    "fetch.resolve": RPC,
     "shuffle.register": RPC,
     "writer.pipeline.publish": RPC,
     "shuffle.push": RPC,

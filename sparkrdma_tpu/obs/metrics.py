@@ -70,6 +70,7 @@ METRIC_FAMILIES: Dict[str, Tuple[str, frozenset]] = {
     "collective.wave_dispatch_ms": ("histogram", _L({"role", "schedule"})),
     "collective.wave_inflight": ("histogram", _L({"role"})),
     "collective.wave_overlap_ms": ("counter", _L({"role"})),
+    "collective.assembly_bytes": ("counter", _L({"role"})),
     "collective.autotune_adjustments": ("counter", _L({"role"})),
     "collective.tuned_wave_bytes": ("gauge", _L({"role"})),
     # critical-path attribution (obs/critpath.py)
@@ -118,6 +119,24 @@ METRIC_FAMILIES: Dict[str, Tuple[str, frozenset]] = {
     "hbm.spill_victims": ("counter", _L()),
     "hbm.disk_spills": ("counter", _L()),
     "hbm.in_use_bytes": ("gauge", _L()),
+    "hbm.slab_payload_bytes": ("counter", _L()),
+    "hbm.slab_bytes": ("counter", _L()),
+    # span-duration histograms (obs/trace.py Tracer.timed, "<span>_ms"):
+    # map sort and staging (models/terasort.py, shuffle/device_io.py),
+    # fetch resolve, planning and waves (device_io.py, collective.py)
+    "map.sort.pad_ms": ("histogram", _L()),
+    "map.sort.h2d_ms": ("histogram", _L()),
+    "map.sort.device_ms": ("histogram", _L()),
+    "map.sort.d2h_ms": ("histogram", _L()),
+    "map.stage.copy_ms": ("histogram", _L()),
+    "map.stage.checksum_ms": ("histogram", _L()),
+    "map.stage.arena_ms": ("histogram", _L()),
+    "fetch.resolve_ms": ("histogram", _L()),
+    "fetch.plan_ms": ("histogram", _L()),
+    "fetch.wave.assemble_ms": ("histogram", _L()),
+    "fetch.wave.h2d_ms": ("histogram", _L()),
+    "fetch.wave.wait_ms": ("histogram", _L()),
+    "fetch.wave.adopt_ms": ("histogram", _L()),
     # registered-buffer pool (memory/)
     "mempool.hits": ("counter", _L()),
     "mempool.misses": ("counter", _L()),

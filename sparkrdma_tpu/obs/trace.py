@@ -35,10 +35,13 @@ import contextvars
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
 from typing import Dict, Iterable, List, Optional
+
+from sparkrdma_tpu.obs.metrics import get_registry
 
 # Wall-clock anchor for the perf_counter timeline (export-time rebase).
 _EPOCH = time.time() - time.perf_counter()
@@ -83,6 +86,18 @@ _named: Dict[str, "Tracer"] = {}
 def now() -> float:
     """Monotonic timestamp compatible with ``Tracer.record``."""
     return time.perf_counter()
+
+
+def _annotation(name: str, role: str):
+    """``jax.profiler.TraceAnnotation(name, role=role)`` once the process
+    has imported jax, else None: a profiler session can only exist after
+    that, and ``obs/`` never imports jax itself. The ``role`` stat marks
+    the event as a program span in the ``.xplane.pb``."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name, role=role)
 
 
 def epoch_anchor() -> float:
@@ -191,6 +206,8 @@ class Tracer:
         self._spans: "deque[Span]" = deque(maxlen=max(1, int(max_spans)))
         self._lock = threading.Lock()
         self._bindings: Dict[int, int] = {}
+        # ``timed`` span name -> its duration histogram
+        self._hists: Dict[str, object] = {}
         with _tracers_lock:
             _tracers.append(self)
 
@@ -240,9 +257,15 @@ class Tracer:
         token = _current_span.set(sp)
         if _span_watch:
             _active_by_ident[sp.tid] = sp
+        # the same span on the profiler's clock, beside the device ops
+        ann = _annotation(name, self.role)
+        if ann is not None:
+            ann.__enter__()
         try:
             yield sp
         finally:
+            if ann is not None:
+                ann.__exit__(None, None, None)
             _current_span.reset(token)
             if _span_watch:
                 if parent is not None:
@@ -254,6 +277,21 @@ class Tracer:
                 sp.trace_id = self._resolve_trace(trace_id, shuffle_id, parent)
             with self._lock:
                 self._spans.append(sp)
+
+    @contextlib.contextmanager
+    def timed(self, name: str, **args):
+        """:meth:`span` that also observes its duration on the registry
+        histogram ``<name>_ms``, whether or not spans are recorded: the
+        per-layer readers sum those histograms over a window."""
+        hist = self._hists.get(name)
+        if hist is None:
+            hist = self._hists[name] = get_registry().histogram(name + "_ms")
+        t0 = now()
+        try:
+            with self.span(name, **args) as sp:
+                yield sp
+        finally:
+            hist.observe((now() - t0) * 1e3)
 
     def record(self, name: str, start: float, end: float,
                shuffle_id: Optional[int] = None, trace_id: int = 0,

@@ -47,6 +47,10 @@ _M_POOL_HITS = get_registry().counter("hbm.pool_hits")
 _M_POOL_MISSES = get_registry().counter("hbm.pool_misses")
 _M_SPILL_VICTIMS = get_registry().counter("hbm.spill_victims")
 _M_DISK_SPILLS = get_registry().counter("hbm.disk_spills")
+# bytes asked for and size-class bytes of every slab handed out: their
+# ratio is how full the power-of-two classes run
+_M_SLAB_PAYLOAD = get_registry().counter("hbm.slab_payload_bytes")
+_M_SLAB_BYTES = get_registry().counter("hbm.slab_bytes")
 # summed across managers; the gauge's high-water mark is the figure of
 # interest for sizing hbm.maxBytes
 _G_IN_USE = get_registry().gauge("hbm.in_use_bytes")
@@ -668,6 +672,8 @@ class DeviceBufferManager:
             # the pooled slab re-enters the budget: spill LRU others if
             # that pushed us over the cap
             self._make_room(0, {pooled.handle})
+            _M_SLAB_PAYLOAD.inc(nbytes)
+            _M_SLAB_BYTES.inc(cls)
             return pooled
         _M_POOL_MISSES.inc()
         self._make_room(cls)
@@ -695,6 +701,8 @@ class DeviceBufferManager:
                 self._allocating -= 1
             with self._evict_cond:
                 self._evict_cond.notify_all()
+        _M_SLAB_PAYLOAD.inc(nbytes)
+        _M_SLAB_BYTES.inc(cls)
         return buf
 
     def put(self, buf: DeviceBuffer) -> None:

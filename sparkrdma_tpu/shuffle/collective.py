@@ -77,7 +77,7 @@ import numpy as np
 
 from sparkrdma_tpu.analysis.lockorder import named_lock
 from sparkrdma_tpu.locations import PartitionLocation
-from sparkrdma_tpu.obs import get_registry
+from sparkrdma_tpu.obs import get_registry, get_tracer
 from sparkrdma_tpu.ops import remote_copy
 from sparkrdma_tpu.ops.exchange import round_bucket, round_rows
 from sparkrdma_tpu.ops.hbm_arena import (
@@ -249,7 +249,7 @@ class ShuffleScheduleCompiler:
         self._conf = conf
         self._dev = dev
         self._executor_id = executor_id
-        self._tracer = tracer
+        self._tracer = tracer if tracer is not None else get_tracer(executor_id)
         # program-cache bookkeeping (the lru_caches hold the programs;
         # this counts resolutions for the compile-churn metrics)
         self._seen_programs: set = set()
@@ -270,6 +270,11 @@ class ShuffleScheduleCompiler:
         )
         self._m_inflight = reg.histogram(
             "collective.wave_inflight", role=role
+        )
+        # host bytes the TPU wave assembly moves: source slabs read back,
+        # and the tiled send stack put back on the device
+        self._m_assembly_bytes = reg.counter(
+            "collective.assembly_bytes", role=role
         )
         # the device-fetch plane's counters stay the one source of truth
         # for "blocks that moved HBM->HBM" vs "device offers declined":
@@ -476,17 +481,11 @@ class ShuffleScheduleCompiler:
         degraded: List[PartitionLocation] = []
         self._m_plans.inc()
         stats = {"dispatch_ms": 0.0, "wave_ms": 0.0, "overlap_ms": 0.0}
-        span = (
-            self._tracer.span(
-                "shuffle.collective", shuffle_id=shuffle_id,
-                schedule=plan.schedule, waves=len(plan.waves),
-                blocks=plan.device_blocks, depth=depth,
-            )
-            if self._tracer is not None
-            else None
-        )
-        ctx = span if span is not None else _null_ctx()
-        with ctx:
+        with self._tracer.span(
+            "shuffle.collective", shuffle_id=shuffle_id,
+            schedule=plan.schedule, waves=len(plan.waves),
+            blocks=plan.device_blocks, depth=depth,
+        ):
             # pids that lose a row to degradation must not fuse: the
             # host path refills per block, so survivors stay per block
             unfusable: set = set()
@@ -626,62 +625,10 @@ class ShuffleScheduleCompiler:
         entry = _InflightWave(waves, pins, t0)
         try:
             for wave in waves:
-                rows_b, b_elems = wave.rows_b, wave.bucket_elems
-                stacked: Optional[np.ndarray] = (
-                    np.zeros((rows_b, b_elems), dtype=dtype) if tpu else None
-                )
-                arrs: Dict[int, object] = {}
-                views: Dict[int, np.ndarray] = {}
-                for i, row in enumerate(wave.rows):
-                    blk = row.loc.block
-                    arena = visible_arena(row.loc.manager_id.executor_id)
-                    src = None
-                    if arena is not None:
-                        src = pins.enter_context(
-                            arena.pinned_if_resident(blk.arena_handle)
-                        )
-                    if (
-                        src is None
-                        or blk.arena_offset + blk.length > src.capacity
-                        or np.dtype(src.array.dtype) != np.dtype(dtype)
-                    ):
-                        row.live = False
-                        entry.dead.append(row)
-                        continue
-                    fuse_row = fused and row.loc.partition_id in fusable_pids
-                    if (
-                        not tpu
-                        and not fuse_row
-                        and blk.arena_offset == 0
-                        and src.array.nbytes == _size_class(blk.length)
-                    ):
-                        # fast lane: START the row's pull now (async;
-                        # same-device sources go through a jitted copy,
-                        # cross-device through the transfer engine) and
-                        # adopt the landed slab whole at consume — the
-                        # per-block planner's single-copy semantics,
-                        # batched and overlapped
-                        arrs[i] = remote_copy.emulated_row_pull_start(
-                            src.array, self._dev.device
-                        )
-                        continue
-                    host = np.asarray(src.array).view(dtype)
-                    off = blk.arena_offset // itemsize
-                    if not tpu and fuse_row:
-                        # fused CPU row: hold a zero-copy view of the
-                        # pinned source — the merge at consume
-                        # concatenates straight from it, skipping the
-                        # stacked-assembly copy (the pin stays held
-                        # through adoption, so the view stays valid)
-                        views[i] = host[off : off + row.elems]
-                        continue
-                    # the emulated gather: source HBM -> host lane of
-                    # the assembled stack (the TPU path DMAs
-                    # source-side shards instead; off TPU this lane
-                    # carries offset/class-mismatched rows)
-                    if stacked is None:
-                        stacked = np.zeros((rows_b, b_elems), dtype=dtype)
-                    stacked[i, : row.elems] = host[off : off + row.elems]
+                with self._tracer.timed("fetch.wave.assemble"):
+                    arrs, views, stacked = self._assemble_wave(
+                        wave, entry, dtype, tpu, fused, fusable_pids
+                    )
                 entry.row_arrs.append(arrs)
                 entry.row_views.append(views)
                 entry.stacked_hosts.append(stacked)
@@ -729,6 +676,75 @@ class ShuffleScheduleCompiler:
             self._m_overlap.inc(dispatch_ms)
         return entry
 
+    def _assemble_wave(self, wave: CollectiveWave, entry: _InflightWave,
+                       dtype, tpu: bool, fused: bool,
+                       fusable_pids: frozenset):
+        """Pin one wave's source slabs (into ``entry.pins``) and lay out
+        its rows: returns ``(arrs, views, stacked)`` — fast-lane pulls
+        started, zero-copy views of fused CPU rows, and the host stack
+        the other rows are copied into. Rows whose source is gone land
+        in ``entry.dead``."""
+        itemsize = np.dtype(dtype).itemsize
+        pins = entry.pins
+        rows_b, b_elems = wave.rows_b, wave.bucket_elems
+        stacked: Optional[np.ndarray] = (
+            np.zeros((rows_b, b_elems), dtype=dtype) if tpu else None
+        )
+        arrs: Dict[int, object] = {}
+        views: Dict[int, np.ndarray] = {}
+        for i, row in enumerate(wave.rows):
+            blk = row.loc.block
+            arena = visible_arena(row.loc.manager_id.executor_id)
+            src = None
+            if arena is not None:
+                src = pins.enter_context(
+                    arena.pinned_if_resident(blk.arena_handle)
+                )
+            if (
+                src is None
+                or blk.arena_offset + blk.length > src.capacity
+                or np.dtype(src.array.dtype) != np.dtype(dtype)
+            ):
+                row.live = False
+                entry.dead.append(row)
+                continue
+            fuse_row = fused and row.loc.partition_id in fusable_pids
+            if (
+                not tpu
+                and not fuse_row
+                and blk.arena_offset == 0
+                and src.array.nbytes == _size_class(blk.length)
+            ):
+                # fast lane: START the row's pull now (async;
+                # same-device sources go through a jitted copy,
+                # cross-device through the transfer engine) and
+                # adopt the landed slab whole at consume — the
+                # per-block planner's single-copy semantics,
+                # batched and overlapped
+                arrs[i] = remote_copy.emulated_row_pull_start(
+                    src.array, self._dev.device
+                )
+                continue
+            host = np.asarray(src.array).view(dtype)
+            self._m_assembly_bytes.inc(host.nbytes)
+            off = blk.arena_offset // itemsize
+            if not tpu and fuse_row:
+                # fused CPU row: hold a zero-copy view of the
+                # pinned source — the merge at consume
+                # concatenates straight from it, skipping the
+                # stacked-assembly copy (the pin stays held
+                # through adoption, so the view stays valid)
+                views[i] = host[off : off + row.elems]
+                continue
+            # the emulated gather: source HBM -> host lane of
+            # the assembled stack (the TPU path DMAs
+            # source-side shards instead; off TPU this lane
+            # carries offset/class-mismatched rows)
+            if stacked is None:
+                stacked = np.zeros((rows_b, b_elems), dtype=dtype)
+            stacked[i, : row.elems] = host[off : off + row.elems]
+        return arrs, views, stacked
+
     def _dispatch_pallas(self, waves: List[CollectiveWave],
                          entry: _InflightWave, dtype):
         """START the entry's DMAs as one kernel epoch (the depth-aware
@@ -753,9 +769,10 @@ class ShuffleScheduleCompiler:
             hops = np.zeros((wave.rows_b,), dtype=np.int32)
             for i, row in enumerate(wave.rows):
                 hops[i] = hop(row)
-            sharded = jax.device_put(
-                np.tile(entry.stacked_hosts[0], (n, 1)).reshape(-1, *lanes)
-            )
+            with self._tracer.timed("fetch.wave.h2d"):
+                tiled = np.tile(entry.stacked_hosts[0], (n, 1))
+                self._m_assembly_bytes.inc(tiled.nbytes)
+                sharded = jax.device_put(tiled.reshape(-1, *lanes))
             self._mover_dispatched("pallas_wave_pull")
             return ("single", remote_copy.pallas_wave_pull(hops, sharded))
         depth = len(waves)
@@ -767,9 +784,10 @@ class ShuffleScheduleCompiler:
             stack[d] = entry.stacked_hosts[d]
             for i, row in enumerate(wave.rows):
                 hops[d, i] = hop(row)
-        sharded = jax.device_put(
-            np.tile(stack, (n, 1, 1)).reshape(-1, rows_b, *lanes)
-        )
+        with self._tracer.timed("fetch.wave.h2d"):
+            tiled = np.tile(stack, (n, 1, 1))
+            self._m_assembly_bytes.inc(tiled.nbytes)
+            sharded = jax.device_put(tiled.reshape(-1, rows_b, *lanes))
         self._mover_dispatched("pallas_pipelined_wave_pull")
         return (
             "pipelined",
@@ -795,8 +813,9 @@ class ShuffleScheduleCompiler:
             if entry.landed is not None:
                 _, obj = entry.landed
                 waiting.extend(obj if isinstance(obj, list) else [obj])
-            remote_copy.emulated_wave_wait(waiting)
-            stacked_devs = self._landed_stacks(entry)
+            with self._tracer.timed("fetch.wave.wait"):
+                remote_copy.emulated_wave_wait(waiting)
+                stacked_devs = self._landed_stacks(entry)
         except Exception:
             if entry.landed is not None:
                 # a Pallas epoch that failed to land is a device fault:
@@ -830,14 +849,22 @@ class ShuffleScheduleCompiler:
                     "collective.waves", role=role,
                     schedule=self._schedule_label,
                 ).inc()
-                out, failed = self._adopt_wave(
-                    wave,
-                    stacked_devs[d] if stacked_devs is not None else None,
-                    dtype, fused, fusable_pids - unfusable,
-                    stacked_host=entry.stacked_hosts[d],
-                    row_arrs=entry.row_arrs[d],
-                    row_views=entry.row_views[d],
-                )
+                # per-wave span (dma-wave attribution, obs/attr.py):
+                # nests under execute()'s shuffle.collective span via the
+                # contextvar parent, so the critical path can enter the
+                # wave level instead of one opaque multi-wave slice
+                with self._tracer.span(
+                    "shuffle.collective.wave", shuffle_id=shuffle_id,
+                    rows=len(live), bytes=nbytes,
+                ), self._tracer.timed("fetch.wave.adopt"):
+                    out, failed = self._adopt_wave(
+                        wave,
+                        stacked_devs[d] if stacked_devs is not None else None,
+                        dtype, fused, fusable_pids - unfusable,
+                        stacked_host=entry.stacked_hosts[d],
+                        row_arrs=entry.row_arrs[d],
+                        row_views=entry.row_views[d],
+                    )
                 results.extend(out)
                 _degrade_rows(failed)
                 reg.histogram(
@@ -845,20 +872,6 @@ class ShuffleScheduleCompiler:
                     schedule=self._schedule_label,
                 ).observe((now - entry.t0) * 1e3)
                 stats["wave_ms"] += (now - entry.t0) * 1e3
-                if self._tracer is not None:
-                    # per-wave span (dma-wave attribution, obs/attr.py):
-                    # nests under execute()'s shuffle.collective span
-                    # via the contextvar parent, so the critical path
-                    # can enter the wave level instead of one opaque
-                    # multi-wave slice
-                    self._tracer.record(
-                        "shuffle.collective.wave",
-                        entry.t0,
-                        time.perf_counter(),
-                        shuffle_id=shuffle_id,
-                        rows=len(live),
-                        bytes=nbytes,
-                    )
         finally:
             entry.close()
         consume_ms = (time.perf_counter() - t0) * 1e3
@@ -1029,8 +1042,3 @@ class ShuffleScheduleCompiler:
             i = j
         return out, failed
 
-
-def _null_ctx():
-    import contextlib
-
-    return contextlib.nullcontext()

@@ -200,24 +200,14 @@ class DeviceShuffleIO:
         self._arena_published: Dict[int, List[DeviceBuffer]] = {}
         register_arena(manager.executor_id, self._dev)
         self._plane = DeviceFetchPlane(conf, self._dev, manager.executor_id)
+        self._tracer = manager.tracer
         # whole-stage schedule compiler (DESIGN.md §22): batches the
         # stage's device-resident blocks into compiled DMA waves; the
         # per-block plane above stays the path for its passthrough set
         self._collective = ShuffleScheduleCompiler(
-            conf, self._dev, manager.executor_id,
-            tracer=getattr(manager, "tracer", None),
+            conf, self._dev, manager.executor_id, tracer=self._tracer,
         )
         self._lock = threading.Lock()
-        # fetch-phase accounting:
-        #   transport_s — waiting for bytes to ARRIVE in host memory
-        #     (RPC, one-sided READ, pread/mmap, sockets).
-        #   stage_s — host -> HBM staging (jax.device_put via
-        #     stage_view).
-        self._fetch_stats = {
-            "fetch_transport_s": 0.0,
-            "fetch_stage_s": 0.0,
-            "fetch_bytes": 0,
-        }
 
     @property
     def device_buffers(self) -> DeviceBufferManager:
@@ -246,6 +236,7 @@ class DeviceShuffleIO:
         conf = mgr.conf
         dev_plane = conf.device_fetch_enabled
         dev_min = conf.device_fetch_min_block_bytes
+        timed = self._tracer.timed
         locs: List[PartitionLocation] = []
         staged = []
         arena_staged: List[DeviceBuffer] = []
@@ -254,12 +245,13 @@ class DeviceShuffleIO:
             # readback lands in a host array and its bytes move straight
             # into the registered shm view (no intermediate tobytes()/
             # write() materializations — SURVEY.md §7.3(3))
-            host = np.asarray(arr)
-            nbytes = host.nbytes
-            buf = mgr.buffer_manager.get(nbytes)
-            np.frombuffer(buf.view, dtype=np.uint8, count=nbytes)[:] = (
-                host.reshape(-1).view(np.uint8)
-            )
+            with timed("map.stage.copy"):
+                host = np.asarray(arr)
+                nbytes = host.nbytes
+                buf = mgr.buffer_manager.get(nbytes)
+                np.frombuffer(buf.view, dtype=np.uint8, count=nbytes)[:] = (
+                    host.reshape(-1).view(np.uint8)
+                )
             staged.append(buf)
             # integrity tag computed HERE, while the bytes are still
             # cache-hot from the copy above and this runs on the map
@@ -268,7 +260,10 @@ class DeviceShuffleIO:
             # the serial publish RPC no longer pays a CRC per block
             ck_algo = ck = 0
             if conf.resilience_checksums and nbytes:
-                ck_algo, ck = _checksum.compute(host.reshape(-1).view(np.uint8))
+                with timed("map.stage.checksum"):
+                    ck_algo, ck = _checksum.compute(
+                        host.reshape(-1).view(np.uint8)
+                    )
             block = BlockLocation(
                 0, nbytes, buf.mkey, checksum=ck, checksum_algo=ck_algo,
                 block_format=block_format,
@@ -280,10 +275,11 @@ class DeviceShuffleIO:
                 # triple above stays the durable fallback. Best-effort —
                 # arena pressure (MemoryError) just skips the extension.
                 try:
-                    abuf = self._dev.stage_view(
-                        host.reshape(-1).view(np.uint8), nbytes,
-                        dtype=host.dtype,
-                    )
+                    with timed("map.stage.arena"):
+                        abuf = self._dev.stage_view(
+                            host.reshape(-1).view(np.uint8), nbytes,
+                            dtype=host.dtype,
+                        )
                 except MemoryError:
                     abuf = None
                 if abuf is not None:
@@ -468,9 +464,6 @@ class DeviceShuffleIO:
             # the location RPC is transport: bytes can't arrive before
             # the driver answers where they are
             t_transport += time.perf_counter() - tw
-            with self._lock:
-                self._fetch_stats["fetch_transport_s"] += t_transport
-            t_transport = 0.0
 
         out: Dict[int, List[DeviceBuffer]] = {}
         my_id = mgr.executor_id
@@ -686,10 +679,6 @@ class DeviceShuffleIO:
                 entry[4]()  # abandon_or_reclaim
             raise
         finally:
-            with self._lock:
-                self._fetch_stats["fetch_transport_s"] += t_transport
-                self._fetch_stats["fetch_stage_s"] += t_stage
-                self._fetch_stats["fetch_bytes"] += n_bytes
             reg = get_registry()
             reg.histogram("device_fetch.transport_ms").observe(t_transport * 1e3)
             reg.histogram("device_fetch.stage_ms").observe(t_stage * 1e3)
@@ -738,9 +727,10 @@ class DeviceShuffleIO:
         )
         tw = time.perf_counter()
         try:
-            locations: List[PartitionLocation] = future.result(
-                timeout=max(0.0, deadline - time.monotonic())
-            )
+            with self._tracer.timed("fetch.resolve"):
+                locations: List[PartitionLocation] = future.result(
+                    timeout=max(0.0, deadline - time.monotonic())
+                )
         except Exception as e:
             raise MetadataFetchFailedError(shuffle_id, start_partition, str(e))
         finally:
@@ -752,7 +742,8 @@ class DeviceShuffleIO:
         # whole-stage compile, UNFUSED: the split-phase pipeline's
         # verify/stage seams are per block, so every wave row comes
         # back as its own DevicePulledBlock
-        cplan = self._collective.plan(locations, dtype)
+        with self._tracer.timed("fetch.plan"):
+            cplan = self._collective.plan(locations, dtype)
         pending: List[Optional[Tuple]] = []
         arrivals: "queue.Queue[int]" = queue.Queue()
         try:
@@ -877,9 +868,6 @@ class DeviceShuffleIO:
                 entry[4]()  # abandon_or_reclaim
             raise
         finally:
-            with self._lock:
-                self._fetch_stats["fetch_transport_s"] += t_transport
-                self._fetch_stats["fetch_bytes"] += n_bytes
             reg_ = get_registry()
             reg_.histogram("device_fetch.transport_ms").observe(t_transport * 1e3)
             reg_.counter("device_fetch.bytes").inc(n_bytes)
@@ -909,10 +897,6 @@ class DeviceShuffleIO:
         _loc, obj, done, errbox, abandon = entry
         ok = done.wait(timeout_s)
         t = time.perf_counter() - tw
-        with self._lock:
-            self._fetch_stats["fetch_transport_s"] += t
-            if ok and not errbox:
-                self._fetch_stats["fetch_bytes"] += loc.block.length
         get_registry().histogram("device_fetch.transport_ms").observe(t * 1e3)
         if not ok:
             abandon()  # read still in flight: listener becomes the owner
@@ -999,8 +983,6 @@ class DeviceShuffleIO:
         finally:
             hb.release()
             t = time.perf_counter() - ts
-            with self._lock:
-                self._fetch_stats["fetch_stage_s"] += t
             get_registry().histogram("device_fetch.stage_ms").observe(t * 1e3)
         return dev
 
@@ -1015,11 +997,6 @@ class DeviceShuffleIO:
         snap["hbm_in_use_bytes"] = self._dev.in_use_bytes
         snap["hbm_spill_count"] = self._dev.spill_count
         snap["hbm_disk_spill_count"] = self._dev.disk_spill_count
-        with self._lock:
-            snap.update(
-                {k: round(v, 3) if isinstance(v, float) else v
-                 for k, v in self._fetch_stats.items()}
-            )
         return snap
 
     def unpublish(self, shuffle_id: int) -> None:

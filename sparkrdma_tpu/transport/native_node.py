@@ -215,6 +215,8 @@ class NativeTpuChannel:
         self._m_send_bytes = reg.counter("transport.send_bytes", purpose=purpose)
         self._m_reads = reg.counter("transport.reads", purpose=purpose)
         self._m_read_bytes = reg.counter("transport.read_bytes", purpose=purpose)
+        self._m_recvs = reg.counter("transport.recvs", purpose=purpose)
+        self._m_recv_bytes = reg.counter("transport.recv_bytes", purpose=purpose)
         self._m_overflow = reg.counter("transport.send_overflow", purpose=purpose)
 
     def _acquire_or_queue(self, permits: int, item) -> bool:
@@ -759,7 +761,11 @@ class NativeTpuNode:
             )
             with self._lock:
                 ch = self._channels.get(c.channel)
-            if ch is not None and self._recv_listener is not None:
+            if ch is None:
+                return
+            ch._m_recvs.inc()
+            ch._m_recv_bytes.inc(len(payload))
+            if self._recv_listener is not None:
                 self._recv_listener(ch, payload)
             return
         if c.kind == tl.COMP_SEND_DONE:

@@ -225,6 +225,10 @@ def test_cluster_shuffle_trace_and_registry(tmp_path):
             "tpu.shuffle.shuffleWriteMethod": "wrapper",
             "tpu.shuffle.shuffleWriteBlockSize": "65536",
             "tpu.shuffle.shuffleReadBlockSize": "65536",
+            # reads land in pooled registered buffers, so the pool layer
+            # is on the path: mapped delivery (the native transport's
+            # default) lands them in page-cache mappings instead
+            "tpu.shuffle.mappedFetch": "false",
         }
     )
     driver = TpuShuffleManager(conf, is_driver=True)
