@@ -71,6 +71,7 @@ METRIC_FAMILIES: Dict[str, Tuple[str, frozenset]] = {
     "collective.wave_inflight": ("histogram", _L({"role"})),
     "collective.wave_overlap_ms": ("counter", _L({"role"})),
     "collective.assembly_bytes": ("counter", _L({"role"})),
+    "collective.device_assembled_rows": ("counter", _L({"role"})),
     "collective.autotune_adjustments": ("counter", _L({"role"})),
     "collective.tuned_wave_bytes": ("gauge", _L({"role"})),
     # critical-path attribution (obs/critpath.py)

@@ -314,7 +314,9 @@ class _AllocatorStack:
 
 
 class DeviceBufferManager:
-    """Size-classed pool of HBM slabs for one device."""
+    """Size-classed pool of HBM slabs for one device. Freed slabs stay
+    pooled while any slab is live; once the last one is freed, the idle
+    pool is released, all but the preallocated slabs."""
 
     def __init__(self, device=None, max_bytes: int = 0, prealloc: int = 0,
                  prealloc_size: int = 0, max_host_bytes: int = 0,
@@ -345,8 +347,11 @@ class DeviceBufferManager:
         self._evict_cond = threading.Condition(named_lock("hbm.evict"))
         self._lock = named_lock("hbm.manager")
         self._stopped = False
-        # optional warm-up (reference maxAggPrealloc, RdmaBufferManager.java:84-91)
+        # optional warm-up (reference maxAggPrealloc, RdmaBufferManager.java:84-91);
+        # these slabs stay pooled when the rest of an idle pool is released
+        self._prealloc: Dict[int, int] = {}
         if prealloc > 0 and prealloc_size > 0:
+            self._prealloc[_size_class(prealloc_size)] = prealloc
             bufs = [self.get(prealloc_size) for _ in range(prealloc)]
             for b in bufs:
                 b.free()
@@ -741,6 +746,7 @@ class DeviceBufferManager:
                     pass
             if buf.array is None:
                 return
+            idle: List[DeviceBuffer] = []
             with self._lock:
                 self._in_use_bytes -= buf.capacity
                 stopped = self._stopped
@@ -748,6 +754,19 @@ class DeviceBufferManager:
                     buf.array.delete()
                 else:
                     self._stacks[buf.capacity].stack.append(buf)
+                    if not self._handles and not self._allocating:
+                        # the last live slab came back: the pool would
+                        # otherwise hold its high-water mark of HBM
+                        # under whatever runs next, so it goes back to
+                        # the device down to the preallocated slabs
+                        # (the next get allocates again)
+                        for cls, s in self._stacks.items():
+                            keep = self._prealloc.get(cls, 0)
+                            idle.extend(s.stack[keep:])
+                            del s.stack[keep:]
+            for b in idle:
+                b.array.delete()
+                b.array = None
             _G_IN_USE.add(-buf.capacity)
             with self._evict_cond:
                 self._evict_cond.notify_all()

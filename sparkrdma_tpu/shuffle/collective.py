@@ -23,7 +23,10 @@ Movers, by regime:
   hop lane in scalar prefetch so one executable serves any peer set.
   Consecutive same-class waves coalesce into the depth-aware
   ``pallas_pipelined_wave_pull`` program — one DMA-semaphore array per
-  in-flight wave, wave d+1 started before wave d drains.
+  in-flight wave, wave d+1 started before wave d drains. The send
+  stack the kernel reads is gathered on the device from the pinned
+  source slabs (``_send_gather_program``): each device lays out the
+  rows whose slab it holds, so no payload byte crosses to the host.
 - Everywhere else (the CPU mesh): the emulated mover's
   ISSUE/CONSUME halves (``emulated_row_pull_start`` /
   ``emulated_wave_wait``) — per-row pulls started together without
@@ -136,6 +139,86 @@ def _compaction_program(rows_b: int, bucket_elems: int, dtype_str: str):
     return jax.jit(fn)
 
 
+@functools.lru_cache(maxsize=64)
+def _send_gather_program(stack_shape: Tuple[int, ...], dtype_str: str):
+    """Jitted send-stack gather of one row: writes
+    ``src[off:off + len]``, zero-filled to the bucket, into slot
+    ``slot`` of a send stack in the movers' lane layout
+    (``[rows_b, *lanes]``, or ``[depth, rows_b, *lanes]`` for a
+    pipelined entry; slot ``d * rows_b + i`` is wave d's row i). The
+    stack is donated, so the row lands in place. ``(slot, off, len)``
+    is a runtime operand, so jit compiles one executable per source
+    slab class for a stack shape, whatever the blocks' lengths and the
+    mix of classes in a wave."""
+    import jax
+    import jax.numpy as jnp
+
+    dtype = jnp.dtype(dtype_str)
+    lanes = tuple(stack_shape[-2:])
+    bucket_elems = lanes[0] * lanes[1]
+
+    def collective_send_gather(stack, src, meta):
+        col = jnp.arange(bucket_elems, dtype=jnp.int32).reshape(lanes)
+        zero = jnp.zeros((), dtype)
+        # a bucket of zeros past the slab's end keeps the window whole
+        # at any in-bounds offset (dynamic_slice would clamp the start
+        # instead, shifting the row)
+        src = jnp.pad(src, (0, bucket_elems))
+        win = jax.lax.dynamic_slice(src, (meta[1],), (bucket_elems,))
+        row = jnp.where(col < meta[2], win.reshape(lanes), zero)
+        rows = jax.lax.dynamic_update_slice(
+            stack.reshape(-1, *lanes), row[None], (meta[0], 0, 0)
+        )
+        return rows.reshape(stack_shape)
+
+    return jax.jit(collective_send_gather, donate_argnums=0)
+
+
+@functools.lru_cache(maxsize=64)
+def _row_take_program(slots: int, bucket_elems: int, take_elems: int,
+                      dtype_str: str):
+    """Jitted adoption of one landed wave row: slot ``slot`` (a runtime
+    operand) of this device's landed shard — ``slots`` rows of
+    ``bucket_elems`` in the movers' lane layout — flattened and cut to
+    ``take_elems``, the row's slab class. The send-stack gather zeroed
+    every row past its length, so the cut row is the whole slab: one
+    copy, and no slice or pad program per block length."""
+    import jax
+    import jax.numpy as jnp
+
+    jnp.dtype(dtype_str)  # validate the cache key up front
+    lanes = remote_copy.wave_row_shape(bucket_elems)
+
+    def collective_row_take(landed, slot):
+        rows = landed.reshape(slots, *lanes)
+        row = jax.lax.dynamic_index_in_dim(rows, slot, keepdims=False)
+        return row.reshape(bucket_elems)[:take_elems]
+
+    return jax.jit(collective_row_take)
+
+
+def send_stack_shard(rows: Dict[int, Tuple[object, int, int]], device,
+                     depth: int, rows_b: int, bucket_elems: int, dtype):
+    """One device's shard of a TPU entry's send stack. ``rows`` maps a
+    row slot to ``(source array, element offset, element length)``,
+    every source resident on ``device``; the other slots stay zero.
+    Returns ``(shard, program keys)``: one key (source class, stack
+    shape, dtype) per class written."""
+    import jax.numpy as jnp
+
+    lanes = remote_copy.wave_row_shape(bucket_elems)
+    shape = (rows_b, *lanes) if depth == 1 else (depth, rows_b, *lanes)
+    dtype_str = np.dtype(dtype).name
+    prog = _send_gather_program(shape, dtype_str)
+    stack = jnp.zeros(shape, dtype, device=device)
+    keys = set()
+    for slot in sorted(rows):
+        src, off, n = rows[slot]
+        keys.add((src.shape[0], shape, dtype_str))
+        stack = prog(stack, src, np.array([slot, off, n], dtype=np.int32))
+    return stack, keys
+
+
 class _Row:
     """One device-resident block scheduled into a wave."""
 
@@ -226,7 +309,7 @@ class _InflightWave:
         # the stacked-assembly copy; valid only while pins are held)
         self.row_views: List[Dict[int, np.ndarray]] = []
         # per wave: assembled host stack (None when every row rode the
-        # fast lane or a view)
+        # fast lane or a view, and on a TPU mesh)
         self.stacked_hosts: List[Optional[np.ndarray]] = []
         # TPU in-flight kernel result: ("single"|"pipelined", async
         # sharded output)
@@ -271,10 +354,14 @@ class ShuffleScheduleCompiler:
         self._m_inflight = reg.histogram(
             "collective.wave_inflight", role=role
         )
-        # host bytes the TPU wave assembly moves: source slabs read back,
-        # and the tiled send stack put back on the device
+        # host bytes the wave assembly moves: source slabs read back
+        # (off a TPU mesh; on one the send stack is gathered on device)
         self._m_assembly_bytes = reg.counter(
             "collective.assembly_bytes", role=role
+        )
+        # TPU mesh rows laid into the send stack on the device
+        self._m_device_rows = reg.counter(
+            "collective.device_assembled_rows", role=role
         )
         # the device-fetch plane's counters stay the one source of truth
         # for "blocks that moved HBM->HBM" vs "device offers declined":
@@ -623,12 +710,20 @@ class ShuffleScheduleCompiler:
         tpu = remote_copy.is_tpu_mesh()
         pins = ExitStack()
         entry = _InflightWave(waves, pins, t0)
+        # TPU mesh, per wave: row index -> pinned source slab
+        sources: List[Dict[int, DeviceBuffer]] = []
         try:
             for wave in waves:
                 with self._tracer.timed("fetch.wave.assemble"):
-                    arrs, views, stacked = self._assemble_wave(
-                        wave, entry, dtype, tpu, fused, fusable_pids
-                    )
+                    if tpu:
+                        # the send stack is gathered on the device at
+                        # dispatch: here the sources are only pinned
+                        sources.append(self._pin_rows(wave, entry, dtype))
+                        arrs, views, stacked = {}, {}, None
+                    else:
+                        arrs, views, stacked = self._assemble_wave(
+                            wave, entry, dtype, fused, fusable_pids
+                        )
                 entry.row_arrs.append(arrs)
                 entry.row_views.append(views)
                 entry.stacked_hosts.append(stacked)
@@ -640,7 +735,7 @@ class ShuffleScheduleCompiler:
                 entry.all_dead = True
                 return entry
             if tpu:
-                entry.landed = self._dispatch_pallas(waves, entry, dtype)
+                entry.landed = self._dispatch_pallas(waves, sources, dtype)
             else:
                 self._mover_dispatched("emulated")
             if len(waves) > 1:
@@ -676,28 +771,18 @@ class ShuffleScheduleCompiler:
             self._m_overlap.inc(dispatch_ms)
         return entry
 
-    def _assemble_wave(self, wave: CollectiveWave, entry: _InflightWave,
-                       dtype, tpu: bool, fused: bool,
-                       fusable_pids: frozenset):
-        """Pin one wave's source slabs (into ``entry.pins``) and lay out
-        its rows: returns ``(arrs, views, stacked)`` — fast-lane pulls
-        started, zero-copy views of fused CPU rows, and the host stack
-        the other rows are copied into. Rows whose source is gone land
-        in ``entry.dead``."""
-        itemsize = np.dtype(dtype).itemsize
-        pins = entry.pins
-        rows_b, b_elems = wave.rows_b, wave.bucket_elems
-        stacked: Optional[np.ndarray] = (
-            np.zeros((rows_b, b_elems), dtype=dtype) if tpu else None
-        )
-        arrs: Dict[int, object] = {}
-        views: Dict[int, np.ndarray] = {}
+    def _pin_rows(self, wave: CollectiveWave, entry: _InflightWave,
+                  dtype) -> Dict[int, DeviceBuffer]:
+        """Pin one wave's source slabs (into ``entry.pins``): returns
+        row index -> pinned buffer for every row whose source is still
+        resident and of ``dtype``; the others land in ``entry.dead``."""
+        srcs: Dict[int, DeviceBuffer] = {}
         for i, row in enumerate(wave.rows):
             blk = row.loc.block
             arena = visible_arena(row.loc.manager_id.executor_id)
             src = None
             if arena is not None:
-                src = pins.enter_context(
+                src = entry.pins.enter_context(
                     arena.pinned_if_resident(blk.arena_handle)
                 )
             if (
@@ -708,10 +793,25 @@ class ShuffleScheduleCompiler:
                 row.live = False
                 entry.dead.append(row)
                 continue
+            srcs[i] = src
+        return srcs
+
+    def _assemble_wave(self, wave: CollectiveWave, entry: _InflightWave,
+                       dtype, fused: bool, fusable_pids: frozenset):
+        """Off a TPU mesh: pin one wave's source slabs and lay out its
+        rows: returns ``(arrs, views, stacked)`` — fast-lane pulls
+        started, zero-copy views of fused rows, and the host stack the
+        other rows are copied into."""
+        itemsize = np.dtype(dtype).itemsize
+        stacked: Optional[np.ndarray] = None
+        arrs: Dict[int, object] = {}
+        views: Dict[int, np.ndarray] = {}
+        for i, src in self._pin_rows(wave, entry, dtype).items():
+            row = wave.rows[i]
+            blk = row.loc.block
             fuse_row = fused and row.loc.partition_id in fusable_pids
             if (
-                not tpu
-                and not fuse_row
+                not fuse_row
                 and blk.arena_offset == 0
                 and src.array.nbytes == _size_class(blk.length)
             ):
@@ -728,70 +828,83 @@ class ShuffleScheduleCompiler:
             host = np.asarray(src.array).view(dtype)
             self._m_assembly_bytes.inc(host.nbytes)
             off = blk.arena_offset // itemsize
-            if not tpu and fuse_row:
-                # fused CPU row: hold a zero-copy view of the
-                # pinned source — the merge at consume
-                # concatenates straight from it, skipping the
-                # stacked-assembly copy (the pin stays held
-                # through adoption, so the view stays valid)
+            if fuse_row:
+                # fused row: hold a zero-copy view of the pinned
+                # source — the merge at consume concatenates
+                # straight from it, skipping the stacked-assembly
+                # copy (the pin stays held through adoption, so the
+                # view stays valid)
                 views[i] = host[off : off + row.elems]
                 continue
-            # the emulated gather: source HBM -> host lane of
-            # the assembled stack (the TPU path DMAs
-            # source-side shards instead; off TPU this lane
-            # carries offset/class-mismatched rows)
+            # the emulated gather: source HBM -> host lane of the
+            # assembled stack, for offset/class-mismatched rows
             if stacked is None:
-                stacked = np.zeros((rows_b, b_elems), dtype=dtype)
+                stacked = np.zeros(
+                    (wave.rows_b, wave.bucket_elems), dtype=dtype
+                )
             stacked[i, : row.elems] = host[off : off + row.elems]
         return arrs, views, stacked
 
     def _dispatch_pallas(self, waves: List[CollectiveWave],
-                         entry: _InflightWave, dtype):
+                         sources: List[Dict[int, DeviceBuffer]], dtype):
         """START the entry's DMAs as one kernel epoch (the depth-aware
         double-buffered program when the entry carries a same-class
         run) WITHOUT waiting; consume slices the landed result per
-        wave. The send-layout shards carry the waves on every device;
-        the per-row hop lane makes this executor's device receive row
-        i from the chip that published it. A mover failure raises."""
+        wave. Each device's shard of the send layout holds the rows
+        whose source slab it holds, gathered there; the per-row hop
+        lane makes this executor's device receive row i from that chip.
+        A mover failure raises."""
         import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         devices = jax.devices()[: remote_copy.mesh_device_count()]
         n = len(devices)
         index = {d.id: k for k, d in enumerate(devices)}
         dst = index[self._dev.device.id]
-
-        def hop(row: _Row) -> int:
-            return (dst - index[row.loc.block.device_coords]) % n
-
-        lanes = remote_copy.wave_row_shape(waves[0].bucket_elems)
-        if len(waves) == 1:
-            wave = waves[0]
-            hops = np.zeros((wave.rows_b,), dtype=np.int32)
-            for i, row in enumerate(wave.rows):
-                hops[i] = hop(row)
-            with self._tracer.timed("fetch.wave.h2d"):
-                tiled = np.tile(entry.stacked_hosts[0], (n, 1))
-                self._m_assembly_bytes.inc(tiled.nbytes)
-                sharded = jax.device_put(tiled.reshape(-1, *lanes))
-            self._mover_dispatched("pallas_wave_pull")
-            return ("single", remote_copy.pallas_wave_pull(hops, sharded))
-        depth = len(waves)
-        rows_b = waves[0].rows_b
+        itemsize = np.dtype(dtype).itemsize
+        depth, rows_b = len(waves), waves[0].rows_b
         b_elems = waves[0].bucket_elems
         hops = np.zeros((depth, rows_b), dtype=np.int32)
-        stack = np.zeros((depth, rows_b, b_elems), dtype=dtype)
-        for d, wave in enumerate(waves):
-            stack[d] = entry.stacked_hosts[d]
-            for i, row in enumerate(wave.rows):
-                hops[d, i] = hop(row)
+        # per mesh device: row slot -> (source array, offset, length)
+        shard_rows: List[Dict[int, Tuple[object, int, int]]] = [
+            {} for _ in devices
+        ]
+        for d, (wave, srcs) in enumerate(zip(waves, sources)):
+            for i, src in srcs.items():
+                row = wave.rows[i]
+                k = index[src.device.id]
+                hops[d, i] = (dst - k) % n
+                shard_rows[k][d * rows_b + i] = (
+                    src.array, row.loc.block.arena_offset // itemsize,
+                    row.elems,
+                )
+        mesh = Mesh(devices, ("x",))
         with self._tracer.timed("fetch.wave.h2d"):
-            tiled = np.tile(stack, (n, 1, 1))
-            self._m_assembly_bytes.inc(tiled.nbytes)
-            sharded = jax.device_put(tiled.reshape(-1, rows_b, *lanes))
+            hop_lane = jax.device_put(
+                hops[0] if depth == 1 else hops, NamedSharding(mesh, P())
+            )
+        with self._tracer.timed("fetch.wave.assemble"):
+            shards = []
+            for k, device in enumerate(devices):
+                shard, keys = send_stack_shard(
+                    shard_rows[k], device, depth, rows_b, b_elems, dtype
+                )
+                for key in keys:
+                    self._program_key_seen(("send-gather", device.id, key))
+                shards.append(shard)
+            lead = n * rows_b if depth == 1 else n * depth
+            stack = jax.make_array_from_single_device_arrays(
+                (lead, *shards[0].shape[1:]), NamedSharding(mesh, P("x")),
+                shards,
+            )
+        self._m_device_rows.inc(sum(len(rows) for rows in shard_rows))
+        if depth == 1:
+            self._mover_dispatched("pallas_wave_pull")
+            return ("single", remote_copy.pallas_wave_pull(hop_lane, stack))
         self._mover_dispatched("pallas_pipelined_wave_pull")
         return (
             "pipelined",
-            remote_copy.pallas_pipelined_wave_pull(hops, sharded, depth),
+            remote_copy.pallas_pipelined_wave_pull(hop_lane, stack, depth),
         )
 
     def _consume_entry(
@@ -815,7 +928,7 @@ class ShuffleScheduleCompiler:
                 waiting.extend(obj if isinstance(obj, list) else [obj])
             with self._tracer.timed("fetch.wave.wait"):
                 remote_copy.emulated_wave_wait(waiting)
-                stacked_devs = self._landed_stacks(entry)
+                landed = self._landed_shard(entry)
         except Exception:
             if entry.landed is not None:
                 # a Pallas epoch that failed to land is a device fault:
@@ -858,8 +971,7 @@ class ShuffleScheduleCompiler:
                     rows=len(live), bytes=nbytes,
                 ), self._tracer.timed("fetch.wave.adopt"):
                     out, failed = self._adopt_wave(
-                        wave,
-                        stacked_devs[d] if stacked_devs is not None else None,
+                        wave, landed, d * wave.rows_b,
                         dtype, fused, fusable_pids - unfusable,
                         stacked_host=entry.stacked_hosts[d],
                         row_arrs=entry.row_arrs[d],
@@ -884,26 +996,31 @@ class ShuffleScheduleCompiler:
     # runs plans one at a time per endpoint; set before the wave loop)
     _schedule_label = "ring"
 
-    def _landed_stacks(self, entry: _InflightWave):
-        """Per-wave landed device stacks of a Pallas entry, read from
-        this executor's own shard of the kernel's output (None on the
-        emulated path, whose rows adopt from the fast-lane arrays and
-        the host assembly directly)."""
+    def _landed_shard(self, entry: _InflightWave):
+        """This executor's own shard of a Pallas entry's landed output —
+        ``[rows_b, *lanes]``, or ``[depth, rows_b, *lanes]`` for a
+        pipelined entry; wave d's row i is slot ``d * rows_b + i`` —
+        or None on the emulated path, whose rows adopt from the
+        fast-lane arrays and the host assembly directly."""
         if entry.landed is None:
             return None
-        kind, obj = entry.landed
-        mine = next(
+        _, obj = entry.landed
+        return next(
             s.data for s in obj.addressable_shards
             if s.device == self._dev.device
         )
-        waves = [mine] if kind == "single" else list(mine)
-        # back to [rows, bucket] (one on-device relayout per wave)
-        return [
-            w.reshape(wave.rows_b, wave.bucket_elems)
-            for w, wave in zip(waves, entry.waves)
-        ]
 
-    def _adopt_wave(self, wave, stacked_dev, dtype, fused, fusable_pids,
+    def _take_row(self, landed, slot: int, wave: CollectiveWave,
+                  class_elems: int, dtype):
+        """One landed row cut to its slab class (``_row_take_program``);
+        a class wider than the bucket keeps the bucket, and
+        ``put_array`` pads the rest."""
+        key = ("take", landed.size // wave.bucket_elems, wave.bucket_elems,
+               min(class_elems, wave.bucket_elems), np.dtype(dtype).name)
+        self._program_key_seen(key)
+        return _row_take_program(*key[1:])(landed, slot)
+
+    def _adopt_wave(self, wave, landed, slot0, dtype, fused, fusable_pids,
                     stacked_host=None, row_arrs=None, row_views=None):
         """Adopt a landed wave into arena slabs: fused partitions land
         as one merged slab; everything else lands per block. Returns
@@ -915,8 +1032,9 @@ class ShuffleScheduleCompiler:
         classes match by construction); fused CPU rows concatenate
         from zero-copy views of the still-pinned sources (one copy,
         not assembly + copy); assembled rows stage their exact payload
-        through the compile-free ``stage_view`` path; TPU rows slice
-        the landed device stack. Fused compaction runs the cached
+        through the compile-free ``stage_view`` path; TPU rows take
+        their slab whole from the landed device shard (``landed``, the
+        wave's rows from slot ``slot0``). Fused compaction runs the cached
         device gather when the wave is TPU-resident, and a plain numpy
         concatenate off-TPU (a device gather program is pure overhead
         on the single-core harness)."""
@@ -950,13 +1068,17 @@ class ShuffleScheduleCompiler:
                      for i, r in enumerate(wave.rows) if r.live]
                     or [np.empty(0, dtype=dtype)]
                 )
-            elif need and stacked_dev is not None:
+            elif need and landed is not None:
                 key = ("compact", wave.rows_b, wave.bucket_elems,
                        np.dtype(dtype).name)
                 self._program_key_seen(key)
                 prog = _compaction_program(
                     wave.rows_b, wave.bucket_elems, np.dtype(dtype).name
                 )
+                # the wave's rows as [rows, bucket] (an on-device relayout)
+                stacked_dev = landed.reshape(-1, wave.bucket_elems)[
+                    slot0 : slot0 + wave.rows_b
+                ]
                 flat = prog(stacked_dev, starts_e, ends_e)
 
         i = 0
@@ -1016,14 +1138,17 @@ class ShuffleScheduleCompiler:
                             dev = self._dev.stage_view(
                                 row_views[i + k], nbytes, dtype,
                             )
-                        elif stacked_dev is not None:
-                            rowv = stacked_dev[i + k, : r.elems]
+                        elif landed is not None:
                             dev = self._dev.get(nbytes)
                             try:
-                                dev = dev.put_array(rowv)
+                                dev = dev.put_array(self._take_row(
+                                    landed, slot0 + i + k, wave,
+                                    dev.capacity // itemsize, dtype,
+                                ))
                             except Exception:
                                 dev.free()
                                 raise
+                            dev.length = nbytes
                         else:
                             # assembled row: exact payload through the
                             # compile-free staging path

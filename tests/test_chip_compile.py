@@ -96,6 +96,67 @@ def test_wave_programs_compile(described_devices, n, depth, rows,
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes < V5E_HBM
 
 
+@pytest.mark.parametrize(
+    "class_elems, stack_shape",
+    [
+        (1 << 23, (2, 1 << 17, 128)),  # 8-reducer wave: a 32 MiB slab's row
+        (1 << 24, (2, 1 << 17, 128)),  # and a 64 MiB slab's
+        (1 << 19, (2, 1 << 12, 128)),  # 200-reducer wave: 2 MiB slabs
+        (1 << 22, (2, 4, 1 << 15, 128)),  # pipelined entry
+    ],
+)
+def test_send_gather_compiles(topo, class_elems, stack_shape):
+    """One row of a wave's send stack, gathered on one chip from its
+    source slab: written in place into the donated stack (no scratch
+    the size of a row), and no op carries the movers' ``wave_pull``
+    name."""
+    from jax.sharding import SingleDeviceSharding
+
+    from sparkrdma_tpu.shuffle.collective import _send_gather_program
+
+    one = SingleDeviceSharding(topo.devices[0])
+    prog = _send_gather_program.__wrapped__(stack_shape, "uint32")
+    compiled = prog.lower(
+        _sds(stack_shape, jnp.uint32, one),
+        _sds((class_elems,), jnp.uint32, one),
+        _sds((3,), jnp.int32, one),
+    ).compile()
+    assert "wave_pull" not in compiled.as_text()
+    mem = compiled.memory_analysis()
+    stack_bytes = int(np.prod(stack_shape)) * 4
+    assert mem.output_size_in_bytes == stack_bytes
+    assert mem.alias_size_in_bytes == stack_bytes
+    assert mem.temp_size_in_bytes < 4 << 20
+
+
+@pytest.mark.parametrize(
+    "landed, bucket_elems, take_elems",
+    [
+        ((2, 1 << 17, 128), 1 << 24, 1 << 23),  # a 32 MiB slab from a 64 MiB row
+        ((2, 1, 1 << 17, 128), 1 << 24, 1 << 24),  # pipelined, class = bucket
+        ((2, 1 << 12, 128), 1 << 19, 1 << 19),  # 200-reducer wave row
+    ],
+)
+def test_row_take_compiles(topo, landed, bucket_elems, take_elems):
+    """Adoption of a landed row: one copy of the slab's class out of
+    the landed shard, with no scratch the size of the shard."""
+    from jax.sharding import SingleDeviceSharding
+
+    from sparkrdma_tpu.shuffle.collective import _row_take_program
+
+    one = SingleDeviceSharding(topo.devices[0])
+    slots = int(np.prod(landed)) // bucket_elems
+    prog = _row_take_program.__wrapped__(
+        slots, bucket_elems, take_elems, "uint32"
+    )
+    compiled = prog.lower(
+        _sds(landed, jnp.uint32, one), _sds((), jnp.int32, one)
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes == take_elems * 4
+    assert mem.temp_size_in_bytes < 4 << 20
+
+
 def test_neighbor_pull_compiles(described_devices):
     from sparkrdma_tpu.ops import remote_copy
 

@@ -593,6 +593,185 @@ def test_planner_lane_balanced_cuts():
 # ----------------------------------------------------------------------
 # the TPU mesh path: Pallas movers, no silent transfer-engine fallback
 # ----------------------------------------------------------------------
+def _host_send_stack(rows, depth, rows_b, bucket_elems, dtype):
+    """The host assembly the device gather replaced: a zeroed
+    [depth * rows_b, bucket] stack, each row's payload copied in from
+    its slab read back whole, shaped into the movers' lanes."""
+    from sparkrdma_tpu.ops import remote_copy
+
+    stack = np.zeros((depth * rows_b, bucket_elems), dtype=dtype)
+    for slot, (src, off, n) in rows.items():
+        stack[slot, :n] = np.asarray(src)[off : off + n]
+    lanes = remote_copy.wave_row_shape(bucket_elems)
+    shape = (rows_b, *lanes) if depth == 1 else (depth, rows_b, *lanes)
+    return stack.reshape(shape)
+
+
+# (depth, rows_b, bucket, {slot: (slab elems, offset, length)}); every
+# slab is filled to its end, so a tail left unzeroed shows
+_GATHER_CASES = {
+    "uneven_lengths": (1, 4, 1024, {0: (1024, 0, 1024), 1: (1024, 0, 700),
+                                    2: (1024, 0, 3), 3: (1024, 0, 513)}),
+    "arena_offset": (1, 2, 1024, {0: (2048, 1000, 1024),
+                                  1: (4096, 3000, 1000)}),
+    "mixed_classes": (1, 4, 2048, {0: (512, 0, 512), 1: (2048, 0, 2000),
+                                   2: (8192, 5000, 2048)}),
+    "short_row_tail_zeroed": (1, 2, 1024, {0: (1024, 0, 1), 1: (1024, 0, 0)}),
+    "pipelined_depth2": (2, 2, 1024, {0: (1024, 0, 900), 1: (2048, 24, 1024),
+                                      3: (512, 0, 256)}),
+}
+
+
+def _gather_rows(spec, dtype, seed):
+    import jax
+
+    rng = np.random.default_rng(seed)
+    info = np.iinfo(dtype)
+    return {
+        slot: (jax.device_put(
+            rng.integers(1, info.max, size, dtype=dtype, endpoint=True)),
+            off, n)
+        for slot, (size, off, n) in spec.items()
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_GATHER_CASES))
+@pytest.mark.parametrize("dtype", [np.uint32, np.uint8])
+def test_send_gather_matches_host_assembly(case, dtype):
+    """The device send-stack gather lays out exactly the stack the host
+    assembly built: each row's payload from its slab offset, the tail
+    past its length zeroed, in the movers' lane layout."""
+    import jax
+
+    from sparkrdma_tpu.shuffle.collective import send_stack_shard
+
+    depth, rows_b, bucket, spec = _GATHER_CASES[case]
+    rows = _gather_rows(spec, dtype, seed=len(case))
+    shard, keys = send_stack_shard(rows, jax.devices()[0], depth, rows_b,
+                                   bucket, dtype)
+    assert {k[0] for k in keys} == {size for size, _, _ in spec.values()}
+    want = _host_send_stack(rows, depth, rows_b, bucket, dtype)
+    assert shard.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(shard), want)
+
+
+def _gather_programs(stack_shape, dtype=np.uint32):
+    from sparkrdma_tpu.shuffle import collective
+
+    return collective._send_gather_program(
+        stack_shape, np.dtype(dtype).name)._cache_size()
+
+
+def test_send_gather_compiles_once_per_slab_classes():
+    """Lengths and offsets are runtime operands: waves whose slabs share
+    their classes reuse one program, whatever their blocks' lengths."""
+    import jax
+
+    from sparkrdma_tpu.shuffle import collective
+
+    collective._send_gather_program.cache_clear()
+    dev = jax.devices()[0]
+    programs = set()
+    for seed, (off0, n0, off1, n1) in enumerate(
+            [(0, 1024, 0, 7), (512, 300, 1024, 1024), (3, 1021, 77, 900)]):
+        spec = {0: (2048, off0, n0), 1: (4096, off1, n1)}
+        rows = _gather_rows(spec, np.uint32, seed)
+        shard, keys = collective.send_stack_shard(rows, dev, 1, 2, 1024,
+                                                  np.uint32)
+        np.testing.assert_array_equal(
+            np.asarray(shard),
+            _host_send_stack(rows, 1, 2, 1024, np.uint32))
+        programs |= keys
+    assert {k[0] for k in programs} == {2048, 4096}
+    assert collective._send_gather_program.cache_info().currsize == 1
+    assert _gather_programs(shard.shape) == 2
+
+
+def test_send_gather_programs_bounded_by_classes_in_wide_mixed_waves():
+    """Waves of many rows whose slab classes mix in a different order
+    every time compile one program per class, not one per combination
+    of classes over the row slots."""
+    import jax
+
+    from sparkrdma_tpu.shuffle import collective
+
+    collective._send_gather_program.cache_clear()
+    rng = np.random.default_rng(11)
+    classes, rows_b, bucket = (512, 2048, 8192), 12, 2048
+    for seed in range(6):
+        spec = {}
+        for slot in rng.permutation(rows_b)[: rows_b - seed % 3]:
+            size = int(rng.choice(classes))
+            n = int(rng.integers(0, min(size, bucket) + 1))
+            spec[int(slot)] = (size, int(rng.integers(0, size - n + 1)), n)
+        rows = _gather_rows(spec, np.uint32, seed)
+        shard, _ = collective.send_stack_shard(
+            rows, jax.devices()[0], 1, rows_b, bucket, np.uint32)
+        np.testing.assert_array_equal(
+            np.asarray(shard),
+            _host_send_stack(rows, 1, rows_b, bucket, np.uint32))
+    assert _gather_programs(shard.shape) == len(classes)
+
+
+@pytest.mark.parametrize(
+    "wave_bytes, depth, fused",
+    [("64m", 1, True), ("128k", 2, False)],
+    ids=["one_fused_wave", "pipelined_waves"],
+)
+def test_tpu_mesh_device_gathered_waves_land_byte_identical(
+        cluster, monkeypatch, wave_bytes, depth, fused):
+    """The TPU mesh path end to end on a one-device mesh, an identity
+    mover standing in for the Pallas kernels: the send stack gathered on
+    the device and its landed rows adopted whole (per block, or fused
+    per partition) give the host path's bytes, every row counted as
+    device-assembled and none host-assembled."""
+    from sparkrdma_tpu.ops import remote_copy
+
+    conf, io_map, io_red = cluster
+    conf.set("tpu.shuffle.collective.autoTune", "false")
+    conf.set("tpu.shuffle.collective.waveBytes", wave_bytes)
+    conf.set("tpu.shuffle.collective.pipelineDepth", str(depth))
+    data = _publish_shards(io_map, seed=83)
+    movers = []
+
+    def mover(_hops, sharded, *d):
+        movers.append(d)
+        return sharded
+
+    monkeypatch.setattr(remote_copy, "is_tpu_mesh", lambda: True)
+    monkeypatch.setattr(remote_copy, "mesh_device_count", lambda: 1)
+    monkeypatch.setattr(remote_copy, "pallas_wave_pull", mover)
+    monkeypatch.setattr(remote_copy, "pallas_pipelined_wave_pull", mover)
+    reg = get_registry()
+    before = reg.snapshot()
+    got = io_red.fetch_device_blocks(91, 0, 3, timeout_s=30, fused=fused)
+    c = reg.delta(before)["counters"]
+    try:
+        for p in range(3):
+            slabs = [bytes(b.read(0, b.capacity)) for b in got[p]]
+            if fused:
+                # one slab: the partition's 3 equal-length blocks,
+                # concatenated in merge order
+                (slab,) = slabs
+                n = BLOCK + p
+                assert got[p][0].length == 3 * n
+                assert sorted(slab[k * n : (k + 1) * n] for k in range(3)) \
+                    == sorted(a.tobytes() for a in data[p])
+                continue
+            lengths = [b.length for b in got[p]]
+            assert sorted(s[:n] for s, n in zip(slabs, lengths)) == sorted(
+                a.tobytes() for a in data[p])
+            # past its block a slab holds zeros, as the pad gave before
+            assert not any(any(s[n:]) for s, n in zip(slabs, lengths))
+    finally:
+        for bufs in got.values():
+            for b in bufs:
+                b.free()
+    assert c["collective.device_assembled_rows{role=cs-red}"] == 9
+    assert c.get("collective.assembly_bytes{role=cs-red}", 0) == 0
+    assert (depth,) in movers if depth > 1 else movers
+
+
 def _clear_wave_programs():
     from sparkrdma_tpu.ops import remote_copy
 

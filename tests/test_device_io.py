@@ -376,3 +376,24 @@ def test_unpublish_releases_registered_buffers(cluster):
         io0.unpublish(2)
     finally:
         io0.stop()
+
+
+def test_unpublish_hands_idle_arena_slabs_back(cluster):
+    """Once a shuffle is unpublished and no other slab of the executor is
+    live, its arena slabs do not stay pooled: the HBM they held is free
+    for whatever runs next."""
+    conf, driver, ex0, ex1 = cluster
+    handle = BaseShuffleHandle(shuffle_id=3, num_maps=1,
+                               partitioner=HashPartitioner(2))
+    driver.register_shuffle(handle)
+    io0 = DeviceShuffleIO(ex0)
+    try:
+        io0.publish_device_blocks(3, {
+            p: jnp.arange(20_000 + p, dtype=jnp.uint8) for p in range(2)})
+        assert io0.device_buffers.in_use_bytes > 0
+        io0.unpublish(3)
+        assert io0.device_buffers.in_use_bytes == 0
+        assert all(s["pooled"] == 0
+                   for s in io0.device_buffers.stats().values())
+    finally:
+        io0.stop()

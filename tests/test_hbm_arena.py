@@ -189,6 +189,7 @@ def test_climb_after_free_charges_nothing(tmp_path):
 
 def test_pool_reuse_same_class():
     mgr = DeviceBufferManager()
+    live = mgr.get(1)  # keeps the pool from being released
     a = mgr.get(20_000)
     h = a.handle
     a.free()
@@ -199,6 +200,36 @@ def test_pool_reuse_same_class():
     assert stats[cls]["total_alloc"] == 1
     assert stats[cls]["total_gets"] == 2
     b.free()
+    live.free()
+    mgr.stop()
+
+
+def test_idle_pool_released_once_no_slab_is_live():
+    """Freed slabs stay pooled while another slab is live; the free of
+    the last live slab hands every idle slab's device array back, all
+    but the preallocated ones, and the next get allocates anew."""
+    mgr = DeviceBufferManager(prealloc=1, prealloc_size=20_000)
+    pre = _size_class(20_000)
+    assert mgr.stats()[pre] == {"total_alloc": 1, "total_gets": 1,
+                                "pooled": 1}
+    live = mgr.stage_bytes(b"\x07" * 20_000)  # takes the preallocated slab
+    idle = [mgr.get(n) for n in (20_000, 40_000, 40_000)]
+    arrays = [b.array for b in idle]
+    for b in idle:
+        b.free()
+    assert mgr.stats()[_size_class(40_000)]["pooled"] == 2
+    assert not any(a.is_deleted() for a in arrays)
+    assert live.read() == b"\x07" * 20_000
+    live.free()
+    assert all(a.is_deleted() for a in arrays[1:])
+    assert {c: s["pooled"] for c, s in mgr.stats().items()} == {
+        pre: 1, _size_class(40_000): 0}
+    again = mgr.get(40_000)
+    assert again.handle not in {b.handle for b in idle}
+    assert mgr.stats()[_size_class(40_000)]["total_alloc"] == 3
+    kept = mgr.get(20_000)  # the preallocated count stays pooled
+    assert not kept.array.is_deleted()
+    assert mgr.stats()[pre]["total_alloc"] == 2
     mgr.stop()
 
 
@@ -304,13 +335,15 @@ def test_spill_of_freed_pooled_buffer_is_a_noop():
 
     mgr = DeviceBufferManager(max_bytes=4 * MIN_BLOCK_SIZE)
     try:
+        live = mgr.get(1)  # keeps the freed slab pooled
         buf = mgr.stage_bytes(b"y" * 100)
-        assert mgr.in_use_bytes == MIN_BLOCK_SIZE
+        assert mgr.in_use_bytes == 2 * MIN_BLOCK_SIZE
         buf.free()  # pooled: array kept, budget released, handle removed
-        assert mgr.in_use_bytes == 0
+        assert mgr.in_use_bytes == MIN_BLOCK_SIZE
         # the raced victim pick fires AFTER the free
         buf.spill_to_host()
-        assert mgr.in_use_bytes == 0, "pooled slab's budget released twice"
+        assert mgr.in_use_bytes == MIN_BLOCK_SIZE, (
+            "pooled slab's budget released twice")
         assert mgr.host_bytes == 0
         assert buf.array is not None and not buf.spilled, (
             "pooled slab was demoted to the host tier"
@@ -320,6 +353,7 @@ def test_spill_of_freed_pooled_buffer_is_a_noop():
         assert buf2 is buf  # LIFO pool reuse
         assert bytes(buf2.read(0, 200)) == b"z" * 200
         buf2.free()
+        live.free()
         assert mgr.in_use_bytes == 0
     finally:
         mgr.stop()

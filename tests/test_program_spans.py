@@ -242,24 +242,43 @@ def test_slab_counters_sum_payload_and_class_bytes():
 
 def test_tpu_wave_assembly_times_its_transfer_and_counts_its_bytes(
         monkeypatch):
-    """The TPU mesh's host assembly, with an identity mover standing in
-    for the Pallas kernels on a one-device mesh: ``fetch.wave.h2d`` times
-    the send stack's transfer, and ``collective.assembly_bytes`` counts
-    each source slab read back whole plus each stack put back."""
+    """The TPU mesh path's send stack is gathered on the device from
+    the pinned source slabs, with an identity mover standing in for the
+    Pallas kernels on a one-device mesh: the rows land byte-identical,
+    no source slab is read back to the host, ``collective.assembly_bytes``
+    stays 0, ``collective.device_assembled_rows`` counts every row moved,
+    and ``fetch.wave.h2d`` times only the hop lane's transfer."""
+    import jax
+
     from sparkrdma_tpu.ops import remote_copy
     from sparkrdma_tpu.ops.hbm_arena import _size_class
+    from sparkrdma_tpu.shuffle import collective
     from sparkrdma_tpu.shuffle.device_io import DeviceShuffleIO
 
     sent = []
 
     def mover(_hops, sharded, *_depth):
-        sent.append(sharded.nbytes)
+        sent.append(sharded)
         return sharded
 
     monkeypatch.setattr(remote_copy, "is_tpu_mesh", lambda: True)
     monkeypatch.setattr(remote_copy, "mesh_device_count", lambda: 1)
     monkeypatch.setattr(remote_copy, "pallas_wave_pull", mover)
     monkeypatch.setattr(remote_copy, "pallas_pipelined_wave_pull", mover)
+    readbacks = []
+
+    class RecordingNumpy:
+        """numpy for the schedule compiler, noting each array it reads
+        back to the host"""
+
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        def asarray(self, a, *args, **kwargs):
+            if isinstance(a, jax.Array):
+                readbacks.append(a.shape)
+            return np.asarray(a, *args, **kwargs)
+
     conf = TpuShuffleConf({"tpu.shuffle.transport": "python"})
     driver = TpuShuffleManager(conf, is_driver=True)
     ex_map = TpuShuffleManager(conf, is_driver=False, executor_id="pt-map")
@@ -267,17 +286,17 @@ def test_tpu_wave_assembly_times_its_transfer_and_counts_its_bytes(
     driver.register_shuffle(BaseShuffleHandle(
         shuffle_id=SID + 1, num_maps=1, partitioner=HashPartitioner(3)))
     io_map, io_red = DeviceShuffleIO(ex_map), DeviceShuffleIO(ex_red)
-    assembled = get_registry().counter("collective.assembly_bytes",
-                                       role="pt-red")
-    h2d = get_registry().histogram("fetch.wave.h2d_ms")
     try:
         rng = np.random.default_rng(3)
         data = {p: rng.integers(0, 256, (64 << 10) + p, np.uint8)
                 for p in range(3)}
         io_map.publish_staged(SID + 1, io_map.stage_device_blocks(
             SID + 1, data))
-        a0, h0 = assembled.value, h2d.snapshot()["count"]
-        blocks = io_red.fetch_host_blocks(SID + 1, 0, 3)
+        before = get_registry().snapshot()
+        with monkeypatch.context() as m:
+            m.setattr(collective, "np", RecordingNumpy())
+            blocks = io_red.fetch_host_blocks(SID + 1, 0, 3)
+        delta = get_registry().delta(before)
         for p, bl in blocks.items():
             (hb,) = bl
             dev = io_red.stage_host_block(hb)
@@ -289,8 +308,11 @@ def test_tpu_wave_assembly_times_its_transfer_and_counts_its_bytes(
         ex_red.stop()
         ex_map.stop()
         driver.stop()
-    assert sent and h2d.snapshot()["count"] - h0 == len(sent)
-    # each source slab is read back whole, at its size class
-    slabs = sum(_size_class(len(d)) for d in data.values())
-    assert slabs == (64 << 10) + 2 * (128 << 10)
-    assert assembled.value - a0 == slabs + sum(sent)
+    c, hist = delta["counters"], _hist_counts(delta)
+    assert c.get('collective.assembly_bytes{role=pt-red}', 0) == 0
+    assert c['collective.device_assembled_rows{role=pt-red}'] == 3
+    assert c['device_fetch.plane.pulls{role=pt-red}'] == 3
+    assert sent and hist["fetch.wave.h2d_ms"] == len(sent)
+    assert {_size_class(len(d)) for d in data.values()} == {
+        64 << 10, 128 << 10}
+    assert readbacks == []
