@@ -72,6 +72,9 @@ METRIC_FAMILIES: Dict[str, Tuple[str, frozenset]] = {
     "collective.wave_overlap_ms": ("counter", _L({"role"})),
     "collective.assembly_bytes": ("counter", _L({"role"})),
     "collective.device_assembled_rows": ("counter", _L({"role"})),
+    "collective.ici_payload_bytes": ("counter", _L({"role"})),
+    "collective.ici_moved_bytes": ("counter", _L({"role"})),
+    "collective.wave_mesh_bytes": ("counter", _L({"role"})),
     "collective.autotune_adjustments": ("counter", _L({"role"})),
     "collective.tuned_wave_bytes": ("gauge", _L({"role"})),
     # critical-path attribution (obs/critpath.py)

@@ -17,11 +17,13 @@ path. Two movers are provided behind one call:
   the wave mover wherever ``is_tpu_mesh()`` is false (the CPU mesh of
   the tier-1 tests).
 
-The wave programs take a per-row HOP lane: device ``k`` sends row ``i``
-to device ``(k + hop[i]) % n``, so every device sends and receives
-exactly once per row (a rotation, never a fan-in that would leave a
-receive semaphore unsignalled and hang the mesh). A zero hop is a
-local DMA on each device.
+On one chip a wave is ``rows`` local DMAs. On an n > 1 mesh
+(``pallas_mesh_wave_pull``) each row slot names the chip that holds its
+source slab, the row of that chip's send shard, and a hop: only that
+chip starts the slot's DMA, toward the chip ``hop`` steps right, and
+only that chip waits on the receive. No chip sends a zero slot, and the
+send shards hold only their own chip's rows. The receive buffer is an
+operand donated back in, so a wave allocates no receive shards.
 
 The planner (shuffle/device_fetch.py) decides per block whether a
 mover applies; this module only moves bytes, and a mover failure
@@ -151,51 +153,23 @@ def wave_row_shape(bucket_elems: int):
     return (bucket_elems // _LANES, _LANES)
 
 
-def _hop_copy(src, dst, send_sem, recv_sem, hop, axis_size: int):
-    """Start/wait pair moving ``src`` into ``dst`` on the device ``hop``
-    steps right along the ``x`` axis. Every device runs the same hop
-    for a given row, so each one sends once and receives once: the
-    copy is a rotation of the row around the ring. Hop 0 is a local
-    DMA (one semaphore); a one-device mesh compiles only that."""
-    from jax.experimental import pallas as pl
+def _local_copy(src, dst, sem):
+    """Start/wait pair of one row's local DMA: a one-chip wave."""
     from jax.experimental.pallas import tpu as pltpu
 
-    local = pltpu.make_async_copy(src, dst, recv_sem)
-    if axis_size == 1:
-        return local.start, local.wait
-    target = jax.lax.rem(jax.lax.axis_index("x") + hop, axis_size)
-    remote = pltpu.make_async_remote_copy(
-        src_ref=src,
-        dst_ref=dst,
-        send_sem=send_sem,
-        recv_sem=recv_sem,
-        device_id=(target,),
-        device_id_type=pltpu.DeviceIdType.MESH,
-    )
-
-    def start():
-        pl.when(hop == 0)(local.start)
-        pl.when(hop != 0)(remote.start)
-
-    def wait():
-        pl.when(hop == 0)(local.wait)
-        pl.when(hop != 0)(remote.wait)
-
-    return start, wait
+    copy = pltpu.make_async_copy(src, dst, sem)
+    return copy.start, copy.wait
 
 
 @functools.lru_cache(maxsize=64)
-def _wave_pull_program(axis_size: int, rows: int, bucket_elems: int,
-                       dtype_str: str):
-    """Jitted shard_map'd Pallas program moving a whole fetch WAVE in
-    one kernel epoch: ``rows`` DMAs started together, waited together —
-    the batched multi-block pull the per-block
-    ``_neighbor_pull_program`` is the building block for. Row *i*'s hop
-    rides in a scalar-prefetch lane (the WR list's per-entry rkey
-    analogue), so one executable serves every wave of the same
-    (rows, bucket) class regardless of which peers it names.
+def _wave_pull_program(rows: int, bucket_elems: int, dtype_str: str):
+    """Jitted shard_map'd Pallas program moving a whole fetch WAVE on
+    one chip in one kernel epoch: ``rows`` local DMAs started together,
+    waited together. The hop lane rides in scalar prefetch, all zeros
+    on one chip, so one executable serves every wave of the same
+    (rows, bucket) class.
 
-    Cached per (mesh size, bucketed rows, bucket elems, dtype) — the
+    Cached per (bucketed rows, bucket elems, dtype) — the
     shuffle-schedule compiler buckets both axes so ragged stages reuse
     these executables (DESIGN.md §22)."""
     from jax.experimental import pallas as pl
@@ -205,10 +179,7 @@ def _wave_pull_program(axis_size: int, rows: int, bucket_elems: int,
 
     def kernel(hops, src_ref, dst_ref, send_sem, recv_sem):
         def copy(i):
-            return _hop_copy(
-                src_ref.at[i], dst_ref.at[i], send_sem.at[i],
-                recv_sem.at[i], hops[i], axis_size,
-            )
+            return _local_copy(src_ref.at[i], dst_ref.at[i], recv_sem.at[i])
 
         # every DMA in flight before the first wait: the epoch's wall
         # is max(row latency), not sum — the whole point of the wave
@@ -233,7 +204,7 @@ def _wave_pull_program(axis_size: int, rows: int, bucket_elems: int,
         name="pallas_wave_pull",
     )
 
-    mesh = Mesh(jax.devices()[:axis_size], ("x",))
+    mesh = Mesh(jax.devices()[:1], ("x",))
     f = shard_map(
         pull, mesh=mesh, in_specs=(P(), P("x")), out_specs=P("x"),
         check_vma=False,
@@ -241,19 +212,17 @@ def _wave_pull_program(axis_size: int, rows: int, bucket_elems: int,
     return jax.jit(f)
 
 
-def pallas_wave_pull(hops, stacked_sharded):
-    """Run one wave's batched pull over a [n*rows, *wave_row_shape(b)]
-    array sharded row-block-wise over the mesh; ``hops`` is the int32
-    per-row lane (device ``k``'s row ``i`` lands on device
-    ``(k + hops[i]) % n``). TPU meshes only — the schedule compiler
-    gates on ``is_tpu_mesh()`` and uses the emulated halves otherwise."""
+def pallas_wave_pull(hops, stacked):
+    """Run one wave's batched pull on a one-chip mesh over a
+    [rows, *wave_row_shape(b)] stack; ``hops`` is the int32 per-row
+    lane, all zeros. TPU only — the schedule compiler gates on
+    ``is_tpu_mesh()`` and uses the emulated halves otherwise."""
     if not is_tpu_mesh():
         raise RuntimeError("pallas_wave_pull requires a TPU mesh")
-    n = mesh_device_count()
-    rows = stacked_sharded.shape[0] // n
-    bucket = stacked_sharded.shape[1] * stacked_sharded.shape[2]
-    prog = _wave_pull_program(n, rows, bucket, str(stacked_sharded.dtype))
-    return prog(hops, stacked_sharded)
+    rows = stacked.shape[0]
+    bucket = stacked.shape[1] * stacked.shape[2]
+    prog = _wave_pull_program(rows, bucket, str(stacked.dtype))
+    return prog(hops, stacked)
 
 
 @functools.lru_cache(maxsize=1)
@@ -305,8 +274,8 @@ def emulated_wave_pull(stacked_host, dst_device):
 
 
 @functools.lru_cache(maxsize=64)
-def _pipelined_wave_pull_program(axis_size: int, depth: int, rows: int,
-                                 bucket_elems: int, dtype_str: str):
+def _pipelined_wave_pull_program(depth: int, rows: int, bucket_elems: int,
+                                 dtype_str: str):
     """Depth-aware double-buffered wave program: ``depth`` waves of
     ``rows`` DMAs each, with wave d+1's DMAs STARTED before wave d's
     wait loop runs — so the interconnect always has a wave in flight
@@ -317,7 +286,7 @@ def _pipelined_wave_pull_program(axis_size: int, depth: int, rows: int,
 
     The caller groups consecutive same-(rows, bucket) waves up to the
     ``collective.pipelineDepth`` knob; ragged neighbors run the
-    single-wave program. Cached per (mesh size, depth, rows class,
+    single-wave program. One chip; cached per (depth, rows class,
     bucket class, dtype) like every other wave executable."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -328,9 +297,8 @@ def _pipelined_wave_pull_program(axis_size: int, depth: int, rows: int,
         send_sems, recv_sems = sems[:depth], sems[depth:]
 
         def copy(d, i):
-            return _hop_copy(
-                src_ref.at[d, i], dst_ref.at[d, i], send_sems[d].at[i],
-                recv_sems[d].at[i], hops[d, i], axis_size,
+            return _local_copy(
+                src_ref.at[d, i], dst_ref.at[d, i], recv_sems[d].at[i]
             )
 
         def start_wave(d):
@@ -371,7 +339,7 @@ def _pipelined_wave_pull_program(axis_size: int, depth: int, rows: int,
         name="pallas_pipelined_wave_pull",
     )
 
-    mesh = Mesh(jax.devices()[:axis_size], ("x",))
+    mesh = Mesh(jax.devices()[:1], ("x",))
     f = shard_map(
         pull, mesh=mesh, in_specs=(P(), P("x")), out_specs=P("x"),
         check_vma=False,
@@ -379,18 +347,139 @@ def _pipelined_wave_pull_program(axis_size: int, depth: int, rows: int,
     return jax.jit(f)
 
 
-def pallas_pipelined_wave_pull(hops, stacked_sharded, depth: int):
+def pallas_pipelined_wave_pull(hops, stacked, depth: int):
     """Run ``depth`` same-class waves as one double-buffered kernel
-    epoch over a [n*depth, rows, *wave_row_shape(b)] array sharded over
-    the mesh; ``hops`` is the [depth, rows] int32 hop lane. TPU meshes
+    epoch on a one-chip mesh over a [depth, rows, *wave_row_shape(b)]
+    stack; ``hops`` is the [depth, rows] int32 hop lane, all zeros. TPU
     only — the schedule compiler gates on ``is_tpu_mesh()`` and uses
     the emulated issue/wait halves otherwise."""
     if not is_tpu_mesh():
         raise RuntimeError("pallas_pipelined_wave_pull requires a TPU mesh")
-    n = mesh_device_count()
-    rows = stacked_sharded.shape[1]
-    bucket = stacked_sharded.shape[2] * stacked_sharded.shape[3]
+    rows = stacked.shape[1]
+    bucket = stacked.shape[2] * stacked.shape[3]
     prog = _pipelined_wave_pull_program(
-        n, depth, rows, bucket, str(stacked_sharded.dtype)
+        depth, rows, bucket, str(stacked.dtype)
     )
-    return prog(hops, stacked_sharded)
+    return prog(hops, stacked)
+
+
+@functools.lru_cache(maxsize=64)
+def _mesh_wave_pull_program(axis_size: int, depth: int, send_rows: int,
+                            rows: int, bucket_elems: int, dtype_str: str):
+    """Jitted shard_map'd Pallas program moving ``depth`` same-class
+    waves of ``rows`` slots across an ``axis_size``-chip mesh in one
+    kernel epoch, wave d+1's DMAs started before wave d's waits, as in
+    ``_pipelined_wave_pull_program``.
+
+    The scalar-prefetch lane holds three runs of ``depth * rows``
+    int32s: slot j's source chip (-1: the slot carries no row), its row
+    in that chip's ``send_rows``-row send shard, and its hop. Only the
+    source chip starts slot j's DMA, local at hop 0 and remote toward
+    ``(source + hop) % n`` otherwise; only the receiving chip waits on
+    the receive, so no chip moves a slot it does not hold. A barrier on
+    entry keeps a chip that left the previous epoch early from landing
+    a DMA on a chip still inside it. The receive buffer, ``depth *
+    rows`` slots on every chip, comes in as an operand aliased to the
+    output and donated: slots that receive nothing keep what they held.
+
+    Cached per (mesh size, depth, send rows class, rows class, bucket
+    class, dtype): no block length, offset or source chip is part of
+    the key."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    dtype = jnp.dtype(dtype_str)
+    slots = depth * rows
+
+    def kernel(lane, src_ref, _recv_in, dst_ref, send_sems, recv_sems):
+        me = jax.lax.axis_index("x")
+        barrier = pltpu.get_barrier_semaphore()
+        for k in range(1, axis_size):
+            pltpu.semaphore_signal(
+                barrier, 1, device_id=(jax.lax.rem(me + k, axis_size),),
+                device_id_type=pltpu.DeviceIdType.MESH,
+            )
+        pltpu.semaphore_wait(barrier, axis_size - 1)
+
+        def dma(j):
+            source, hop = lane[j], lane[2 * slots + j]
+            target = jax.lax.rem(source + hop, axis_size)
+            src = src_ref.at[lane[slots + j]]
+            local = pltpu.make_async_copy(src, dst_ref.at[j], recv_sems.at[j])
+            remote = pltpu.make_async_remote_copy(
+                src_ref=src,
+                dst_ref=dst_ref.at[j],
+                send_sem=send_sems.at[j],
+                recv_sem=recv_sems.at[j],
+                device_id=(target,),
+                device_id_type=pltpu.DeviceIdType.MESH,
+            )
+            sends, away = source == me, hop != 0
+            receives = (source >= 0) & (target == me) & away
+            return sends & ~away, sends & away, receives, local, remote
+
+        def start(j):
+            here, away, _, local, remote = dma(j)
+            pl.when(here)(local.start)
+            pl.when(away)(remote.start)
+
+        def wait(j):
+            here, away, receives, local, remote = dma(j)
+            pl.when(here)(local.wait)
+            pl.when(away)(remote.wait_send)
+            pl.when(receives)(remote.wait_recv)
+
+        def each_row(fn, d):
+            jax.lax.fori_loop(d * rows, (d + 1) * rows,
+                              lambda j, c: (fn(j), c)[1], 0)
+
+        each_row(start, 0)
+        for d in range(1, depth):
+            each_row(start, d)
+            each_row(wait, d - 1)
+        each_row(wait, depth - 1)
+
+    any_space = pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        in_specs=[any_space, any_space],
+        out_specs=any_space,
+        scratch_shapes=[pltpu.SemaphoreType.DMA((slots,))] * 2,
+    )
+    pull = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(
+            (slots, *wave_row_shape(bucket_elems)), dtype
+        ),
+        grid_spec=grid_spec,
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(collective_id=0),
+        name="pallas_wave_pull" if depth == 1
+        else "pallas_pipelined_wave_pull",
+    )
+
+    mesh = Mesh(jax.devices()[:axis_size], ("x",))
+    f = shard_map(
+        pull, mesh=mesh, in_specs=(P(), P("x"), P("x")), out_specs=P("x"),
+        check_vma=False,
+    )
+    return jax.jit(f, donate_argnums=2)
+
+
+def pallas_mesh_wave_pull(lane, send, recv, depth: int):
+    """Run ``depth`` same-class waves as one kernel epoch on an n > 1
+    mesh (``_mesh_wave_pull_program``). ``send`` is
+    [n * send_rows, *wave_row_shape(b)] and ``recv`` is
+    [n * depth * rows, *wave_row_shape(b)], both sharded row-block-wise
+    over the mesh; ``recv`` is donated and comes back as the result,
+    slot ``d * rows + i`` holding wave d's row i on its receiving chip.
+    TPU meshes only."""
+    if not is_tpu_mesh():
+        raise RuntimeError("pallas_mesh_wave_pull requires a TPU mesh")
+    n = mesh_device_count()
+    bucket = send.shape[1] * send.shape[2]
+    prog = _mesh_wave_pull_program(
+        n, depth, send.shape[0] // n, recv.shape[0] // (n * depth), bucket,
+        str(send.dtype),
+    )
+    return prog(lane, send, recv)

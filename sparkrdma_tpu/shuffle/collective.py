@@ -18,15 +18,17 @@ concurrently with the last via the caller's ``drain`` callback.
 
 Movers, by regime:
 
-- TPU mesh: ``ops/remote_copy.pallas_wave_pull`` — one Pallas kernel
-  epoch issuing ``rows`` DMAs together (start all, wait all), a per-row
-  hop lane in scalar prefetch so one executable serves any peer set.
-  Consecutive same-class waves coalesce into the depth-aware
-  ``pallas_pipelined_wave_pull`` program — one DMA-semaphore array per
-  in-flight wave, wave d+1 started before wave d drains. The send
-  stack the kernel reads is gathered on the device from the pinned
-  source slabs (``_send_gather_program``): each device lays out the
-  rows whose slab it holds, so no payload byte crosses to the host.
+- TPU mesh: one Pallas kernel epoch issuing a wave's DMAs together
+  (start all, wait all). Consecutive same-class waves coalesce into one
+  depth-aware epoch, wave d+1 started before wave d drains. On one chip
+  (``pallas_wave_pull`` / ``pallas_pipelined_wave_pull``) every row is
+  a local DMA. On an n > 1 mesh (``pallas_mesh_wave_pull``) a per-slot
+  lane names the chip holding each row: only that chip sends it, only
+  the receiving chip waits, each chip's send shard holds only its own
+  rows, and the receive buffer is pooled per (slots, bucket) class and
+  donated back in. The send stack is gathered on the device from the
+  pinned source slabs (``_send_gather_program``), so no payload byte
+  crosses to the host.
 - Everywhere else (the CPU mesh): the emulated mover's
   ISSUE/CONSUME halves (``emulated_row_pull_start`` /
   ``emulated_wave_wait``) — per-row pulls started together without
@@ -72,7 +74,7 @@ from __future__ import annotations
 import functools
 import logging
 import time
-from collections import deque
+from collections import defaultdict, deque
 from contextlib import ExitStack
 from typing import Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -293,7 +295,8 @@ class _InflightWave:
     land; the pipeline bounds the held set to ``depth`` entries."""
 
     __slots__ = ("waves", "pins", "t0", "dead", "all_dead", "row_arrs",
-                 "row_views", "stacked_hosts", "landed", "nbytes", "live")
+                 "row_views", "stacked_hosts", "landed", "recv_key",
+                 "nbytes", "live")
 
     def __init__(self, waves: List[CollectiveWave], pins: ExitStack,
                  t0: float):
@@ -314,6 +317,9 @@ class _InflightWave:
         # TPU in-flight kernel result: ("single"|"pipelined", async
         # sharded output)
         self.landed = None
+        # n > 1 mesh: the receive pool class the landed output goes
+        # back to once adopted
+        self.recv_key = None
         self.nbytes = 0
         self.live = 0
 
@@ -337,6 +343,10 @@ class ShuffleScheduleCompiler:
         # this counts resolutions for the compile-churn metrics)
         self._seen_programs: set = set()
         self._cache_lock = named_lock("collective.compiler")
+        # n > 1 mesh: free receive buffers by (slots, bucket, dtype),
+        # each [n * slots, *lanes] sharded over the mesh
+        self._recv_free: Dict[Tuple, List[object]] = defaultdict(list)
+        self._recv_lock = named_lock("collective.recv_pool")
         self._tuner = WaveAutoTuner(conf, executor_id)
         reg = get_registry()
         role = executor_id
@@ -362,6 +372,18 @@ class ShuffleScheduleCompiler:
         # TPU mesh rows laid into the send stack on the device
         self._m_device_rows = reg.counter(
             "collective.device_assembled_rows", role=role
+        )
+        # per TPU epoch: payload of the rows that cross chips, the
+        # bucket bytes their DMAs carry, and the HBM the epoch newly
+        # allocates across the mesh (send and receive shards)
+        self._m_ici_payload = reg.counter(
+            "collective.ici_payload_bytes", role=role
+        )
+        self._m_ici_moved = reg.counter(
+            "collective.ici_moved_bytes", role=role
+        )
+        self._m_mesh_bytes = reg.counter(
+            "collective.wave_mesh_bytes", role=role
         )
         # the device-fetch plane's counters stay the one source of truth
         # for "blocks that moved HBM->HBM" vs "device offers declined":
@@ -735,7 +757,7 @@ class ShuffleScheduleCompiler:
                 entry.all_dead = True
                 return entry
             if tpu:
-                entry.landed = self._dispatch_pallas(waves, sources, dtype)
+                self._dispatch_pallas(entry, sources, dtype)
             else:
                 self._mover_dispatched("emulated")
             if len(waves) > 1:
@@ -845,36 +867,31 @@ class ShuffleScheduleCompiler:
             stacked[i, : row.elems] = host[off : off + row.elems]
         return arrs, views, stacked
 
-    def _dispatch_pallas(self, waves: List[CollectiveWave],
+    def _dispatch_pallas(self, entry: _InflightWave,
                          sources: List[Dict[int, DeviceBuffer]], dtype):
         """START the entry's DMAs as one kernel epoch (the depth-aware
         double-buffered program when the entry carries a same-class
-        run) WITHOUT waiting; consume slices the landed result per
-        wave. Each device's shard of the send layout holds the rows
-        whose source slab it holds, gathered there; the per-row hop
-        lane makes this executor's device receive row i from that chip.
-        A mover failure raises."""
+        run) WITHOUT waiting, into ``entry.landed``; consume slices the
+        landed result per wave. The send stack is gathered on the
+        device from the pinned source slabs. A mover failure raises."""
         import jax
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         devices = jax.devices()[: remote_copy.mesh_device_count()]
-        n = len(devices)
-        index = {d.id: k for k, d in enumerate(devices)}
-        dst = index[self._dev.device.id]
+        if len(devices) > 1:
+            return self._dispatch_mesh(entry, sources, dtype, devices)
+        waves = entry.waves
         itemsize = np.dtype(dtype).itemsize
         depth, rows_b = len(waves), waves[0].rows_b
         b_elems = waves[0].bucket_elems
+        # one chip: every row is a local DMA, so every hop is 0
         hops = np.zeros((depth, rows_b), dtype=np.int32)
-        # per mesh device: row slot -> (source array, offset, length)
-        shard_rows: List[Dict[int, Tuple[object, int, int]]] = [
-            {} for _ in devices
-        ]
+        # row slot -> (source array, offset, length)
+        rows: Dict[int, Tuple[object, int, int]] = {}
         for d, (wave, srcs) in enumerate(zip(waves, sources)):
             for i, src in srcs.items():
                 row = wave.rows[i]
-                k = index[src.device.id]
-                hops[d, i] = (dst - k) % n
-                shard_rows[k][d * rows_b + i] = (
+                rows[d * rows_b + i] = (
                     src.array, row.loc.block.arena_offset // itemsize,
                     row.elems,
                 )
@@ -884,28 +901,135 @@ class ShuffleScheduleCompiler:
                 hops[0] if depth == 1 else hops, NamedSharding(mesh, P())
             )
         with self._tracer.timed("fetch.wave.assemble"):
-            shards = []
-            for k, device in enumerate(devices):
-                shard, keys = send_stack_shard(
-                    shard_rows[k], device, depth, rows_b, b_elems, dtype
-                )
-                for key in keys:
-                    self._program_key_seen(("send-gather", device.id, key))
-                shards.append(shard)
-            lead = n * rows_b if depth == 1 else n * depth
+            shard = self._send_shard(rows, devices[0], depth, rows_b,
+                                     b_elems, dtype)
             stack = jax.make_array_from_single_device_arrays(
-                (lead, *shards[0].shape[1:]), NamedSharding(mesh, P("x")),
-                shards,
+                shard.shape, NamedSharding(mesh, P("x")), [shard]
             )
-        self._m_device_rows.inc(sum(len(rows) for rows in shard_rows))
+        self._m_device_rows.inc(len(rows))
+        # the send stack and the kernel's output, each a stack's bytes
+        self._m_mesh_bytes.inc(2 * stack.nbytes)
         if depth == 1:
             self._mover_dispatched("pallas_wave_pull")
-            return ("single", remote_copy.pallas_wave_pull(hop_lane, stack))
+            entry.landed = (
+                "single", remote_copy.pallas_wave_pull(hop_lane, stack)
+            )
+            return
         self._mover_dispatched("pallas_pipelined_wave_pull")
-        return (
+        entry.landed = (
             "pipelined",
             remote_copy.pallas_pipelined_wave_pull(hop_lane, stack, depth),
         )
+
+    def _send_shard(self, rows, device, depth: int, rows_b: int,
+                    b_elems: int, dtype):
+        """One chip's send shard (``send_stack_shard``), its gather
+        programs counted."""
+        shard, keys = send_stack_shard(rows, device, depth, rows_b,
+                                       b_elems, dtype)
+        for key in keys:
+            self._program_key_seen(("send-gather", device.id, key))
+        return shard
+
+    def _dispatch_mesh(self, entry: _InflightWave,
+                       sources: List[Dict[int, DeviceBuffer]], dtype,
+                       devices) -> None:
+        """``_dispatch_pallas`` on an n > 1 mesh. Slot ``d * rows_b +
+        i`` carries wave d's row i. Each chip's send shard holds only
+        the rows whose source slab it holds, as many rows as the
+        busiest chip sends in the entry (bucketed); the lane tells the
+        kernel which chip sends each slot, from which shard row, and
+        the hop to this executor's chip. The receive buffer comes from
+        the pool and goes back to it once the entry is adopted."""
+        import jax
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+        waves = entry.waves
+        n = len(devices)
+        index = {d.id: k for k, d in enumerate(devices)}
+        dst = index[self._dev.device.id]
+        itemsize = np.dtype(dtype).itemsize
+        depth, rows_b = len(waves), waves[0].rows_b
+        b_elems = waves[0].bucket_elems
+        slots = depth * rows_b
+        # per slot: source chip (-1: no row), row of its send shard, hop
+        lane = np.zeros((3, slots), dtype=np.int32)
+        lane[0] = -1
+        # per mesh chip: send shard row -> (source array, offset, length)
+        shard_rows: List[Dict[int, Tuple[object, int, int]]] = [
+            {} for _ in devices
+        ]
+        payload = crossing = 0
+        for d, (wave, srcs) in enumerate(zip(waves, sources)):
+            for i, src in srcs.items():
+                row = wave.rows[i]
+                k = index[src.device.id]
+                hop = (dst - k) % n
+                lane[:, d * rows_b + i] = (k, len(shard_rows[k]), hop)
+                shard_rows[k][len(shard_rows[k])] = (
+                    src.array, row.loc.block.arena_offset // itemsize,
+                    row.elems,
+                )
+                if hop:
+                    payload += row.elems * itemsize
+                    crossing += 1
+        send_rows = round_rows(max(len(r) for r in shard_rows))
+        mesh = Mesh(devices, ("x",))
+        sharded = NamedSharding(mesh, P("x"))
+        lanes = remote_copy.wave_row_shape(b_elems)
+        with self._tracer.timed("fetch.wave.h2d"):
+            lane_dev = jax.device_put(lane.reshape(-1),
+                                      NamedSharding(mesh, P()))
+        with self._tracer.timed("fetch.wave.assemble"):
+            shards = [
+                self._send_shard(shard_rows[k], device, 1, send_rows,
+                                 b_elems, dtype)
+                for k, device in enumerate(devices)
+            ]
+            stack = jax.make_array_from_single_device_arrays(
+                (n * send_rows, *lanes), sharded, shards
+            )
+            entry.recv_key = (slots, b_elems, np.dtype(dtype).name)
+            recv = self._take_recv(entry.recv_key, sharded)
+        self._m_device_rows.inc(sum(len(rows) for rows in shard_rows))
+        self._m_ici_payload.inc(payload)
+        self._m_ici_moved.inc(crossing * b_elems * itemsize)
+        self._m_mesh_bytes.inc(stack.nbytes)
+        single = depth == 1
+        self._mover_dispatched(
+            "pallas_wave_pull" if single else "pallas_pipelined_wave_pull"
+        )
+        entry.landed = (
+            "single" if single else "pipelined",
+            remote_copy.pallas_mesh_wave_pull(lane_dev, stack, recv, depth),
+        )
+
+    def _take_recv(self, key: Tuple, sharding):
+        """A free receive buffer of class ``key`` (slots, bucket elems,
+        dtype) from the pool, or a new one of ``slots`` rows on every
+        chip of ``sharding``'s mesh, counted in
+        ``collective.wave_mesh_bytes``."""
+        import jax.numpy as jnp
+
+        with self._recv_lock:
+            free = self._recv_free[key]
+            if free:
+                return free.pop()
+        slots, b_elems, dtype_name = key
+        shape = (sharding.mesh.size * slots,
+                 *remote_copy.wave_row_shape(b_elems))
+        recv = jnp.zeros(shape, dtype_name, device=sharding)
+        self._m_mesh_bytes.inc(recv.nbytes)
+        return recv
+
+    def release_receive_buffers(self) -> None:
+        """Free the pooled receive buffers; buffers of entries still in
+        flight return to the pool when adopted."""
+        with self._recv_lock:
+            free = [a for arrs in self._recv_free.values() for a in arrs]
+            self._recv_free.clear()
+        for arr in free:
+            arr.delete()
 
     def _consume_entry(
         self, entry: _InflightWave, shuffle_id: int, dtype, fused: bool,
@@ -984,6 +1108,11 @@ class ShuffleScheduleCompiler:
                     schedule=self._schedule_label,
                 ).observe((now - entry.t0) * 1e3)
                 stats["wave_ms"] += (now - entry.t0) * 1e3
+            if entry.recv_key is not None:
+                # every row taken from it is dispatched: the next
+                # epoch of its class may write it again
+                with self._recv_lock:
+                    self._recv_free[entry.recv_key].append(entry.landed[1])
         finally:
             entry.close()
         consume_ms = (time.perf_counter() - t0) * 1e3

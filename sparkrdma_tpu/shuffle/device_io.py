@@ -1001,7 +1001,8 @@ class DeviceShuffleIO:
 
     def unpublish(self, shuffle_id: int) -> None:
         """Release the registered buffers serving a shuffle's blocks,
-        and the arena copies the device plane advertised. A puller
+        the arena copies the device plane advertised, and the wave
+        receive buffers this endpoint's fetches pooled. A puller
         racing this free sees the handle gone (or the slab recycled)
         at its residency re-check and degrades to host fetch — which
         then also finds the host buffer gone only if the whole shuffle
@@ -1013,6 +1014,7 @@ class DeviceShuffleIO:
             self._manager.buffer_manager.put(buf)
         for abuf in arena:
             abuf.free()
+        self._collective.release_receive_buffers()
 
     def stop(self) -> None:
         with self._lock:
@@ -1021,5 +1023,6 @@ class DeviceShuffleIO:
             )
         for sid in shuffles:
             self.unpublish(sid)
+        self._collective.release_receive_buffers()
         unregister_arena(self._manager.executor_id, self._dev)
         self._dev.stop()

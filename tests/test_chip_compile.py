@@ -66,24 +66,38 @@ def _sds(shape, dtype, sharding):
         (1, 1, 1, 1 << 25),   # one-chip e2e: a 128 MiB row, local DMA
         (1, 2, 1, 1 << 25),
         (4, 1, 8, 256),       # smallest bucket class (1 KiB of uint32)
+        (4, 1, 4, 1 << 20),   # 64-reducer class-D wave: 4 rows of 4 MiB
     ],
 )
 def test_wave_programs_compile(described_devices, n, depth, rows,
                                bucket_elems):
+    """The wave movers for the chip. On a 4-chip mesh each chip's send
+    shard holds its own rows (``rows // n`` here) and the receive
+    buffer is donated into the output: the program allocates no
+    receive shard of its own."""
     from sparkrdma_tpu.ops import remote_copy
 
     mesh = Mesh(np.array(described_devices[:n]), ("x",))
     rep, sh = NamedSharding(mesh, P()), NamedSharding(mesh, P("x"))
     lanes = remote_copy.wave_row_shape(bucket_elems)
-    if depth == 1:
+    if n > 1:
+        send_rows = rows // n
+        prog = remote_copy._mesh_wave_pull_program.__wrapped__(
+            n, depth, send_rows, rows, bucket_elems, "uint32"
+        )
+        slots = depth * rows
+        args = (_sds((3 * slots,), jnp.int32, rep),
+                _sds((n * send_rows, *lanes), jnp.uint32, sh),
+                _sds((n * slots, *lanes), jnp.uint32, sh))
+    elif depth == 1:
         prog = remote_copy._wave_pull_program.__wrapped__(
-            n, rows, bucket_elems, "uint32"
+            rows, bucket_elems, "uint32"
         )
         args = (_sds((rows,), jnp.int32, rep),
                 _sds((n * rows, *lanes), jnp.uint32, sh))
     else:
         prog = remote_copy._pipelined_wave_pull_program.__wrapped__(
-            n, depth, rows, bucket_elems, "uint32"
+            depth, rows, bucket_elems, "uint32"
         )
         args = (_sds((depth, rows), jnp.int32, rep),
                 _sds((n * depth, rows, *lanes), jnp.uint32, sh))
@@ -94,6 +108,10 @@ def test_wave_programs_compile(described_devices, n, depth, rows,
     assert not re.search(r"\sfusion\(", hlo)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes < V5E_HBM
+    if n > 1:
+        recv_bytes = depth * rows * bucket_elems * 4
+        assert mem.output_size_in_bytes == recv_bytes
+        assert mem.alias_size_in_bytes == recv_bytes
 
 
 @pytest.mark.parametrize(

@@ -800,25 +800,83 @@ def test_tpu_mesh_pallas_mover_failure_raises(cluster, monkeypatch, depth):
     monkeypatch.setattr(remote_copy, "is_tpu_mesh", lambda: True)
     monkeypatch.setattr(remote_copy, "pallas_wave_pull", broken)
     monkeypatch.setattr(remote_copy, "pallas_pipelined_wave_pull", broken)
+    monkeypatch.setattr(remote_copy, "pallas_mesh_wave_pull", broken)
     with pytest.raises(RuntimeError, match="injected: pallas mover"):
         io_red.fetch_host_blocks(91, 0, 3, timeout_s=30)
     assert degrades.value == d0, "a TPU mover failure must not degrade"
     assert not io_map.device_buffers._pins
 
 
-def test_pallas_wave_fetch_four_devices_interpreted(monkeypatch):
+SENTINEL_WORD = 0xA5A5A5A5
+
+
+def _check_mesh_epoch(lane, send, landed, depth, n, held, sentinel):
+    """One n > 1 epoch, read back: each chip's send shard holds only rows
+    of blocks resident on it (the rest zero), as many rows as the
+    busiest chip sends, bucketed; each slot landed on its receiving chip
+    only, every other chip's slot still holding the sentinel. Returns
+    the cross-chip rows' (payload, bucket) bytes."""
+    from sparkrdma_tpu.ops.exchange import round_rows
+
+    def shards(arr):
+        by_dev = {s.device.id: np.asarray(s.data) for s in
+                  arr.addressable_shards}
+        return [by_dev[d] for d in sorted(by_dev)]
+
+    lane = np.asarray(lane).reshape(3, -1)
+    sends, lands = shards(send), shards(landed)
+    slots = lane.shape[1]
+    assert lands[0].shape[0] == slots and slots % depth == 0
+    counts = [int(np.sum(lane[0] == k)) for k in range(n)]
+    assert sends[0].shape[0] == round_rows(max(counts))
+    payload = moved = 0
+    for k in range(n):
+        rows = sends[k].reshape(sends[k].shape[0], -1)
+        mine = {int(lane[1, j]) for j in range(slots) if lane[0, j] == k}
+        assert mine == set(range(counts[k]))
+        for r in range(rows.shape[0]):
+            if r in mine:
+                assert rows[r].tobytes() in held[k]
+            else:
+                assert not rows[r].any()
+    for j in range(slots):
+        src, row, hop = (int(v) for v in lane[:, j])
+        for k in range(n):
+            got = lands[k][j].reshape(-1)
+            if src >= 0 and (src + hop) % n == k:
+                want = sends[src][row].reshape(-1)
+                np.testing.assert_array_equal(got, want)
+            else:
+                assert (got == sentinel).all(), (j, k)
+        if src >= 0 and hop:
+            payload += len(sends[src][row].tobytes())
+            moved += sends[src][row].nbytes
+    return payload, moved
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_pallas_wave_fetch_four_devices_interpreted(monkeypatch, depth):
     """chip_smoke.py's --chips 4 wave phase, rehearsed on the CPU mesh:
     the real Pallas wave programs in TPU interpret mode (remote DMAs and
     semaphores simulated across devices), four executors with arenas
-    on four devices, depth 1 and 2 — byte-identical to the host path,
-    carried by the Pallas movers only."""
+    on four devices, at depth 1 and 2 — byte-identical to the host
+    path, carried by the Pallas movers only. Every epoch sends only
+    held rows: each chip's send shard holds the rows resident on it and
+    no more, no slot lands anywhere but its receiving chip (a sentinel
+    receive buffer shows it), ``collective.ici_payload_bytes`` is the
+    cross-chip rows' lengths and ``ici_moved_bytes`` their buckets, and
+    each executor allocates one receive buffer, reused by its later
+    epochs."""
     import importlib.util
     import os
 
     import jax
+    import jax.numpy as jnp
     from jax.experimental.pallas import tpu as pltpu
 
     from sparkrdma_tpu.ops import remote_copy
+    from sparkrdma_tpu.ops.exchange import round_bucket
+    from sparkrdma_tpu.shuffle.device_io import DeviceShuffleIO
 
     spec = importlib.util.spec_from_file_location(
         "chip_smoke",
@@ -827,20 +885,125 @@ def test_pallas_wave_fetch_four_devices_interpreted(monkeypatch):
     chip_smoke = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(chip_smoke)
 
+    n, block_keys = 4, 1 << 12
+    held = {k: set() for k in range(n)}
+    publish = DeviceShuffleIO.publish_device_blocks
+
+    def recording_publish(io, sid, parts, *a, **k):
+        dev = io.device_buffers.device.id
+        held[dev].update(np.asarray(v).tobytes() for v in parts.values())
+        return publish(io, sid, parts, *a, **k)
+
+    epochs, allocated = [], []
+    mesh_pull = remote_copy.pallas_mesh_wave_pull
+
+    def checked_pull(lane, send, recv, d):
+        # the pooled buffer is laid out as the send stack is
+        assert recv.sharding.is_equivalent_to(send.sharding, recv.ndim)
+        # a sentinel receive buffer in place of the pooled one
+        sentinel = jnp.full(recv.shape, SENTINEL_WORD, recv.dtype,
+                            device=recv.sharding)
+        landed = mesh_pull(lane, send, sentinel, d)
+        epochs.append(_check_mesh_epoch(lane, send, landed, d, n, held,
+                                        SENTINEL_WORD))
+        allocated.append((send.nbytes, recv.nbytes))
+        return landed
+
+    monkeypatch.setattr(DeviceShuffleIO, "publish_device_blocks",
+                        recording_publish)
     monkeypatch.setattr(remote_copy, "is_tpu_mesh", lambda: True)
     # a 4-device mesh, as on the 4-chip host (the interpreter's
     # cross-device rendezvous stalls on the 8-device farm's thread pool)
-    monkeypatch.setattr(remote_copy, "mesh_device_count", lambda: 4)
+    monkeypatch.setattr(remote_copy, "mesh_device_count", lambda: n)
+    monkeypatch.setattr(remote_copy, "pallas_mesh_wave_pull", checked_pull)
     _clear_wave_programs()
+    reg = get_registry()
+    before = reg.snapshot()
     pltpu.set_tpu_interpret_mode(pltpu.InterpretParams())
     try:
         rec = chip_smoke.phase_wave_fetch(
-            jax.devices()[:4], block_keys=1 << 12, transport="python"
+            jax.devices()[:n], block_keys=block_keys, transport="python",
+            depths=(depth,),
         )
     finally:
         pltpu.set_tpu_interpret_mode(None)
         _clear_wave_programs()
-    assert rec["arena_devices"] == [d.id for d in jax.devices()[:4]]
-    assert rec["depth1"]["movers"]["pallas_wave_pull"] > 0
-    assert rec["depth2"]["movers"]["pallas_pipelined_wave_pull"] > 0
-    assert rec["depth1"]["blocks_pulled"] == 32
+    c = reg.delta(before)["counters"]
+
+    def total(name):
+        return sum(v for key, v in c.items() if key.split("{")[0] == name)
+
+    mover = {1: "pallas_wave_pull", 2: "pallas_pipelined_wave_pull"}[depth]
+    assert rec["arena_devices"] == [d.id for d in jax.devices()[:n]]
+    assert rec[f"depth{depth}"]["movers"][mover] > 0
+    reducers, block_bytes = rec["reducers"], rec["block_bytes"]
+    assert rec[f"depth{depth}"]["blocks_pulled"] == n * reducers
+    # every reducer takes one block from each chip, n - 1 of them remote
+    crossing = reducers * (n - 1)
+    assert epochs and sum(p for p, _ in epochs) == crossing * block_bytes
+    assert total("collective.ici_payload_bytes") == crossing * block_bytes
+    assert total("collective.ici_moved_bytes") == sum(m for _, m in epochs)
+    assert total("collective.ici_moved_bytes") <= (
+        crossing * round_bucket(block_bytes))
+    # each epoch's send shards, and one receive buffer per executor:
+    # its later waves reuse it
+    (recv_bytes,) = {r for _, r in allocated}
+    assert total("collective.wave_mesh_bytes") == (
+        sum(s for s, _ in allocated) + n * recv_bytes)
+    if depth == 1:
+        # two waves per executor went through one buffer
+        assert len(allocated) == 2 * n
+
+
+def test_one_chip_wave_keeps_its_program_and_stack_shape(cluster,
+                                                        monkeypatch):
+    """On a one-device mesh the wave path is the one-chip path it was:
+    the one-chip movers get a hop lane of zeros and a [rows_b, *lanes]
+    stack over the one-device mesh, the compiler resolves the same
+    (rows class, bucket class, dtype) wave key, and no byte counts as
+    crossing chips."""
+    from jax.sharding import NamedSharding
+
+    from sparkrdma_tpu.ops import remote_copy
+    from sparkrdma_tpu.ops.exchange import round_bucket, round_rows
+
+    conf, io_map, io_red = cluster
+    conf.set("tpu.shuffle.collective.autoTune", "false")
+    conf.set("tpu.shuffle.collective.pipelineDepth", "1")
+    _publish_shards(io_map, seed=61)
+    calls = []
+
+    def mover(hops, stack, *d):
+        calls.append((np.asarray(hops), stack, d))
+        return stack
+
+    def no_mesh(*_a, **_k):
+        raise AssertionError("the mesh mover ran on one device")
+
+    monkeypatch.setattr(remote_copy, "is_tpu_mesh", lambda: True)
+    monkeypatch.setattr(remote_copy, "mesh_device_count", lambda: 1)
+    monkeypatch.setattr(remote_copy, "pallas_wave_pull", mover)
+    monkeypatch.setattr(remote_copy, "pallas_mesh_wave_pull", no_mesh)
+    compiler = io_red._collective
+    compiler._seen_programs.clear()
+    reg = get_registry()
+    before = reg.snapshot()
+    got = io_red.fetch_device_blocks(91, 0, 3, timeout_s=30)
+    c = reg.delta(before)["counters"]
+    for bufs in got.values():
+        for b in bufs:
+            b.free()
+    rows_b, bucket = round_rows(9), round_bucket(BLOCK + 2)
+    lanes = remote_copy.wave_row_shape(bucket)
+    ((hops, stack, depth),) = calls
+    assert depth == () and hops.shape == (rows_b,) and not hops.any()
+    assert stack.shape == (rows_b, *lanes)
+    assert isinstance(stack.sharding, NamedSharding)
+    assert stack.sharding.mesh.devices.size == 1
+    assert ("wave", rows_b, bucket, "uint8") in compiler._seen_programs
+    assert {k[0] for k in compiler._seen_programs} == {
+        "wave", "send-gather", "take"}
+    assert c.get("collective.ici_payload_bytes{role=cs-red}", 0) == 0
+    assert c.get("collective.ici_moved_bytes{role=cs-red}", 0) == 0
+    # the send stack and the kernel's output
+    assert c["collective.wave_mesh_bytes{role=cs-red}"] == 2 * stack.nbytes
