@@ -35,6 +35,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from sparkrdma_tpu.obs import get_tracer
+from sparkrdma_tpu.ops.hbm_arena import DeviceReadback
 from sparkrdma_tpu.ops.sort import (
     device_sort,
     merge_received,
@@ -111,6 +112,9 @@ class MapShardSorter:
 
         Returns ``(sorted_keys [n], bounds [num_reducers + 1])`` with
         reducer r's keys at ``sorted_keys[bounds[r]:bounds[r + 1]]``.
+        ``sorted_keys`` is a ``DeviceReadback``: it and its slices name
+        the sorted device array, so staging cuts their arena slabs on
+        the device (``DeviceShuffleIO.stage_device_blocks``).
         """
         timed = self._tracer.timed
         n = len(keys)
@@ -127,9 +131,13 @@ class MapShardSorter:
             s, cuts = self._step(
                 dev, jnp.asarray(edges, jnp.uint32), jnp.int32(n)
             )
+            # the unsorted copy goes once the sort has read it, not at
+            # return: the sorted one outlives this call until staging
+            # has cut its blocks
+            del dev
             jax.block_until_ready((s, cuts))
         with timed("map.sort.d2h"):
-            local = np.asarray(s)[:n]
+            local = DeviceReadback.of(np.asarray(s), s, n)
             bounds = np.concatenate(
                 [[0], np.asarray(cuts, dtype=np.int64), [n]]
             )

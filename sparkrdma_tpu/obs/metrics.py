@@ -135,6 +135,10 @@ METRIC_FAMILIES: Dict[str, Tuple[str, frozenset]] = {
     "map.stage.copy_ms": ("histogram", _L()),
     "map.stage.checksum_ms": ("histogram", _L()),
     "map.stage.arena_ms": ("histogram", _L()),
+    # map blocks staged into the HBM arena, and those of them cut on the
+    # device from the map sort's output (shuffle/device_io.py)
+    "map.stage.arena_blocks": ("counter", _L({"role"})),
+    "map.stage.device_cut_blocks": ("counter", _L({"role"})),
     "fetch.resolve_ms": ("histogram", _L()),
     "fetch.plan_ms": ("histogram", _L()),
     "fetch.wave.assemble_ms": ("histogram", _L()),

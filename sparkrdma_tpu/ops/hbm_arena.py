@@ -25,12 +25,13 @@ RdmaShuffleBlockResolver.scala:38-47) via ``hbm.maxBytes``.
 from __future__ import annotations
 
 import contextlib
+import functools
 import logging
 import os
 import tempfile
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -62,6 +63,127 @@ def _size_class(nbytes: int) -> int:
     """Round up to a power of two, floored at MIN_BLOCK_SIZE."""
     n = max(nbytes, MIN_BLOCK_SIZE)
     return 1 << (n - 1).bit_length()
+
+
+def _memory_owner(arr: np.ndarray) -> np.ndarray:
+    """The ndarray at the end of ``arr``'s base chain."""
+    while isinstance(arr.base, np.ndarray):
+        arr = arr.base
+    return arr
+
+
+class _ReadbackSource:
+    """The 1-D device array a host readback was read from: its first
+    ``n`` elements are the readback's, starting at host address ``ptr``
+    of the memory ``owner`` holds."""
+
+    __slots__ = ("array", "owner", "ptr", "n")
+
+    def __init__(self, array, owner: np.ndarray, ptr: int, n: int):
+        self.array = array
+        self.owner = owner
+        self.ptr = ptr
+        self.n = n
+
+
+class DeviceReadback(np.ndarray):
+    """A host array read back from a 1-D device array, which it and
+    every basic slice of it still name: a slab of such a slice can be
+    cut on the device (``DeviceBufferManager.stage_device``) instead of
+    crossing back from the host. Arrays derived in any other way carry
+    the name too, but own other memory, so ``device_window`` declines
+    them."""
+
+    def __array_finalize__(self, obj):
+        self._source = getattr(obj, "_source", None)
+
+    @classmethod
+    def of(cls, host: np.ndarray, array, n: int) -> "DeviceReadback":
+        """``host[:n]``, naming ``array`` (whose first ``n`` elements it
+        holds) as its device source."""
+        out = host[:n].view(cls)
+        out._source = _ReadbackSource(
+            array, _memory_owner(out), out.__array_interface__["data"][0], n
+        )
+        return out
+
+
+def device_window(arr) -> Optional[Tuple[object, int, int, Optional[_ReadbackSource]]]:
+    """Where ``arr``'s elements already sit on a device: ``(1-D device
+    array, element offset, element count, readback source)``, or None.
+    A 1-D ``jax.Array`` is its own window (no readback source); a
+    ``DeviceReadback`` slice is one only while it is C-contiguous, lies
+    in the memory the readback was read into, and its source still
+    holds the device array."""
+    if isinstance(arr, jax.Array):
+        return (arr, 0, arr.shape[0], None) if arr.ndim == 1 else None
+    src = getattr(arr, "_source", None)
+    if not isinstance(src, _ReadbackSource):
+        return None
+    dev = src.array
+    if (dev is None or arr.ndim != 1 or arr.dtype != dev.dtype
+            or not arr.flags.c_contiguous
+            or _memory_owner(arr) is not src.owner):
+        return None
+    off, rem = divmod(arr.__array_interface__["data"][0] - src.ptr,
+                      arr.itemsize)
+    if rem or off < 0 or off + arr.shape[0] > src.n:
+        return None
+    return dev, off, arr.shape[0], src
+
+
+_CUT_LOCKS: Dict[object, object] = {}
+_CUT_LOCKS_GUARD = threading.Lock()
+
+
+def device_cut_lock(device):
+    """The process's one lock for ``device`` under which a map task's
+    blocks are cut from its sorted array (``stage_device``). Executors
+    sharing a chip take turns, so no two of them hold their sorted
+    arrays beside their new slabs at once: the chip's peak would carry
+    both."""
+    with _CUT_LOCKS_GUARD:
+        lock = _CUT_LOCKS.get(device)
+        if lock is None:
+            lock = _CUT_LOCKS[device] = named_lock("hbm.device_cut")
+        return lock
+
+
+@functools.lru_cache(maxsize=64)
+def _cut_program(src_elems: int, class_elems: int, dtype_str: str):
+    """Jitted cut of one arena slab from a 1-D device array: the first
+    ``len`` elements are ``src[off:off + len]``, the tail zero, as
+    ``stage_view``'s host pad leaves it. ``(off, len)`` is a runtime
+    operand, so one executable serves every block of a source length,
+    slab class and dtype. Returns the plain program and one that takes
+    a pooled slab of the output's shape, donated, to write into."""
+    dtype = jnp.dtype(dtype_str)
+    span = max(src_elems, class_elems)
+
+    def arena_cut(src, meta):
+        off, n = meta[0], meta[1]
+        if src_elems < class_elems:
+            # a source shorter than the class: at most one class copied
+            src = jnp.pad(src, (0, class_elems - src_elems))
+        # dynamic_slice clamps its start to keep the window inside the
+        # source, so a block near the end reads from a clamped start
+        # and is shifted to the front; the window never reads past it
+        start = jnp.minimum(off, span - class_elems)
+        win = jax.lax.dynamic_slice(src, (start,), (class_elems,))
+        win = jax.lax.dynamic_slice(
+            jnp.concatenate([win, jnp.zeros_like(win)]), (off - start,),
+            (class_elems,),
+        )
+        col = jnp.arange(class_elems, dtype=jnp.int32)
+        return jnp.where(col < n, win, jnp.zeros((), dtype))
+
+    def arena_cut_into(slab, src, meta):
+        del slab  # donated: the cut lands in its memory
+        return arena_cut(src, meta)
+
+    return jax.jit(arena_cut), jax.jit(
+        arena_cut_into, donate_argnums=0, keep_unused=True
+    )
 
 
 class DeviceBuffer:
@@ -265,6 +387,19 @@ class DeviceBuffer:
             m._unpin(self.handle)
         m._touch(self)
         return self
+
+    def _refill(self, fill) -> None:
+        """Replace the contents with ``fill(old array)``, pinned and
+        tier-locked like ``put_array``; ``fill`` consumes the old
+        array."""
+        m = self._manager
+        m._pin(self.handle)
+        try:
+            with self._tier_lock:
+                self._climb_locked()
+                self.array = fill(self.array)
+        finally:
+            m._unpin(self.handle)
 
     def read(self, offset: int = 0, length: Optional[int] = None) -> bytes:
         """Readback of BYTES ``[offset, offset+length)`` from whichever
@@ -641,21 +776,29 @@ class DeviceBufferManager:
         its own worker thread, until its earlier slabs are put back
         (capacity is charged for the get→put lifetime, so spilling a
         slab to host does NOT un-block its tenant)."""
+        return self._get(nbytes, None)
+
+    def _get(self, nbytes: int, fill) -> DeviceBuffer:
+        """``get``, with the slab's array made by ``fill`` where given
+        (see ``_get_slab``)."""
         broker = _quota.broker("hbm")
         if broker is None:
-            return self._get_slab(nbytes, None)
+            return self._get_slab(nbytes, None, fill)
         tenant = current_tenant()
         cls = _size_class(nbytes)
         broker.charge(tenant, cls)
         try:
-            buf = self._get_slab(nbytes, tenant)
+            buf = self._get_slab(nbytes, tenant, fill)
         except BaseException:
             broker.release(tenant, cls)
             raise
         buf._quota_tag = (broker, tenant, cls)
         return buf
 
-    def _get_slab(self, nbytes: int, tenant) -> DeviceBuffer:
+    def _get_slab(self, nbytes: int, tenant, fill=None) -> DeviceBuffer:
+        """A slab of ``nbytes``' class. ``fill(old)`` makes its array:
+        ``old`` is a pooled slab's array, which ``fill`` consumes, or
+        None, and then no zero slab is built first."""
         cls = _size_class(nbytes)
         with self._lock:
             if self._stopped:
@@ -677,6 +820,8 @@ class DeviceBufferManager:
             # the pooled slab re-enters the budget: spill LRU others if
             # that pushed us over the cap
             self._make_room(0, {pooled.handle})
+            if fill is not None:
+                pooled._refill(fill)
             _M_SLAB_PAYLOAD.inc(nbytes)
             _M_SLAB_BYTES.inc(cls)
             return pooled
@@ -693,7 +838,12 @@ class DeviceBufferManager:
             self._allocating += 1
         _G_IN_USE.add(cls)
         try:
-            arr = jax.device_put(jnp.zeros((cls,), dtype=jnp.uint8), self.device)
+            if fill is not None:
+                arr = fill(None)
+            else:
+                arr = jax.device_put(
+                    jnp.zeros((cls,), dtype=jnp.uint8), self.device
+                )
             buf = DeviceBuffer(handle, cls, arr, self)
             buf.length = nbytes
             buf.tenant = tenant
@@ -835,6 +985,32 @@ class DeviceBufferManager:
         # the transfer must be complete before this returns
         jax.block_until_ready(buf.array)
         return buf
+
+    def stage_device(self, src, elem_offset: int, elem_len: int) -> DeviceBuffer:
+        """Pool + stage ``src[elem_offset:elem_offset + elem_len]`` of a
+        1-D device array on this manager's device, cut by one program on
+        the device (``_cut_program``): no host pad, no host-to-device
+        transfer, no zero slab. The slab holds the block typed as
+        ``src`` and zero past it, as ``stage_view`` leaves it; budget,
+        quota, pool and slab counters are ``get``'s. A pooled slab of
+        the output's shape and dtype is donated into the program, any
+        other is deleted. The cut is dispatched, not waited on: callers
+        wait once for a batch."""
+        dtype = np.dtype(src.dtype)
+        nbytes = elem_len * dtype.itemsize
+        class_elems = _size_class(nbytes) // dtype.itemsize
+        cut, cut_into = _cut_program(src.shape[0], class_elems, dtype.name)
+        meta = np.array([elem_offset, elem_len], dtype=np.int32)
+
+        def fill(old):
+            if old is None:
+                return cut(src, meta)
+            if old.shape == (class_elems,) and old.dtype == dtype:
+                return cut_into(old, src, meta)
+            old.delete()
+            return cut(src, meta)
+
+        return self._get(nbytes, fill)
 
     # ------------------------------------------------------------------
     @property

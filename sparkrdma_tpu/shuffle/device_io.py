@@ -15,12 +15,14 @@ partition block is one pooled registered buffer whose
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import queue
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from sparkrdma_tpu.locations import BlockLocation, PartitionLocation
@@ -29,6 +31,8 @@ from sparkrdma_tpu.ops.hbm_arena import (
     DeviceBuffer,
     DeviceBufferManager,
     _size_class,
+    device_cut_lock,
+    device_window,
 )
 from sparkrdma_tpu.shuffle.collective import ShuffleScheduleCompiler
 from sparkrdma_tpu.shuffle.device_fetch import (
@@ -208,6 +212,15 @@ class DeviceShuffleIO:
             conf, self._dev, manager.executor_id, tracer=self._tracer,
         )
         self._lock = threading.Lock()
+        reg = get_registry()
+        # map blocks put into the arena, and those of them cut on the
+        # device from the map sort's own output
+        self._m_arena_blocks = reg.counter(
+            "map.stage.arena_blocks", role=manager.executor_id
+        )
+        self._m_device_cut = reg.counter(
+            "map.stage.device_cut_blocks", role=manager.executor_id
+        )
 
     @property
     def device_buffers(self) -> DeviceBufferManager:
@@ -231,7 +244,12 @@ class DeviceShuffleIO:
         (``BlockLocation.FORMAT_*``). Device-staged bytes already carry
         their layout in the array dtype, so columnar-encoded payloads
         (DESIGN.md §25) advertise ``FORMAT_COLUMNAR`` here and reducers
-        consume them pickle-free straight off the arena."""
+        consume them pickle-free straight off the arena.
+
+        A block whose elements already sit on this endpoint's device (a
+        1-D ``jax.Array``, or a slice of the map sort's ``DeviceReadback``)
+        gets its arena slab cut there (``_cut_on_device``); every other
+        block crosses from its host copy (``stage_view``)."""
         mgr = self._manager
         conf = mgr.conf
         dev_plane = conf.device_fetch_enabled
@@ -240,6 +258,7 @@ class DeviceShuffleIO:
         locs: List[PartitionLocation] = []
         staged = []
         arena_staged: List[DeviceBuffer] = []
+        cut = self._cut_on_device(partitions, dev_min) if dev_plane else {}
         for pid, arr in partitions.items():
             # HBM -> registered memory in ONE host copy: the device
             # readback lands in a host array and its bytes move straight
@@ -274,15 +293,19 @@ class DeviceShuffleIO:
                 # pulls it HBM->HBM (device_fetch.py) while the host
                 # triple above stays the durable fallback. Best-effort —
                 # arena pressure (MemoryError) just skips the extension.
-                try:
-                    with timed("map.stage.arena"):
-                        abuf = self._dev.stage_view(
-                            host.reshape(-1).view(np.uint8), nbytes,
-                            dtype=host.dtype,
-                        )
-                except MemoryError:
-                    abuf = None
+                if pid in cut:
+                    abuf = cut[pid]
+                else:
+                    try:
+                        with timed("map.stage.arena"):
+                            abuf = self._dev.stage_view(
+                                host.reshape(-1).view(np.uint8), nbytes,
+                                dtype=host.dtype,
+                            )
+                    except MemoryError:
+                        abuf = None
                 if abuf is not None:
+                    self._m_arena_blocks.inc()
                     arena_staged.append(abuf)
                     block = BlockLocation(
                         0, nbytes, buf.mkey,
@@ -300,6 +323,50 @@ class DeviceShuffleIO:
             self._published.setdefault(shuffle_id, []).extend(staged)
             self._arena_published.setdefault(shuffle_id, []).extend(arena_staged)
         return locs
+
+    def _cut_on_device(self, partitions, dev_min: int) -> Dict[int, Optional[DeviceBuffer]]:
+        """Arena slabs of the blocks whose elements already sit on this
+        endpoint's device, at or above ``dev_min`` bytes, cut there
+        (``DeviceBufferManager.stage_device``): pid -> slab, or None
+        where the arena had no room. One ``map.stage.arena`` span per
+        block: the first also waits for the device's cut lock
+        (``device_cut_lock``), the last waits, once, for every cut. A
+        map sort's device array is let go, before the lock is, once the
+        block ending its valid elements is cut."""
+        device = self._dev.device
+        todo = []
+        for pid, arr in partitions.items():
+            win = device_window(arr)
+            if win is None:
+                continue
+            src, off, n, source = win
+            if n * src.dtype.itemsize >= dev_min and src.devices() == {device}:
+                todo.append((pid, src, off, n, source))
+        cut: Dict[int, Optional[DeviceBuffer]] = {}
+        if not todo:
+            return cut
+        timed = self._tracer.timed
+        with contextlib.ExitStack() as held:
+            for i, (pid, src, off, n, source) in enumerate(todo):
+                with timed("map.stage.arena"):
+                    if i == 0:
+                        held.enter_context(device_cut_lock(device))
+                    try:
+                        cut[pid] = self._dev.stage_device(src, off, n)
+                    except MemoryError:
+                        cut[pid] = None
+                    if i < len(todo) - 1:
+                        continue
+                    jax.block_until_ready([
+                        b.array for b in cut.values()
+                        if b is not None and b.array is not None
+                        and not b.array.is_deleted()
+                    ])
+                    for _, _, off, n, source in todo:
+                        if source is not None and off + n == source.n:
+                            source.array = None
+        self._m_device_cut.inc(sum(b is not None for b in cut.values()))
+        return cut
 
     def publish_staged(
         self,

@@ -175,6 +175,38 @@ def test_row_take_compiles(topo, landed, bucket_elems, take_elems):
     assert mem.temp_size_in_bytes < 4 << 20
 
 
+@pytest.mark.parametrize(
+    "class_elems",
+    [
+        1 << 23,  # 8-reducer map blocks: 32 MiB slabs
+        1 << 24,  # and 64 MiB, the last one's class past the source's end
+        1 << 19,  # 200-reducer map blocks: 2 MiB slabs
+        1 << 20,  # 64-reducer class-D map blocks: 4 MiB slabs
+    ],
+)
+def test_arena_cut_compiles(topo, class_elems):
+    """One map block's arena slab, cut on one chip from a map task's
+    2^26-key sorted array: no scratch the size of the slab or the
+    source, and a donated pooled slab is written in place."""
+    from jax.sharding import SingleDeviceSharding
+
+    from sparkrdma_tpu.ops.hbm_arena import _cut_program
+
+    one = SingleDeviceSharding(topo.devices[0])
+    cut, cut_into = _cut_program.__wrapped__(1 << 26, class_elems, "uint32")
+    src = _sds((1 << 26,), jnp.uint32, one)
+    meta = _sds((2,), jnp.int32, one)
+    slab_bytes = class_elems * 4
+    mem = cut.lower(src, meta).compile().memory_analysis()
+    assert mem.output_size_in_bytes == slab_bytes
+    assert mem.temp_size_in_bytes < 4 << 20
+    mem = cut_into.lower(
+        _sds((class_elems,), jnp.uint32, one), src, meta
+    ).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == slab_bytes
+    assert mem.temp_size_in_bytes < 4 << 20
+
+
 def test_neighbor_pull_compiles(described_devices):
     from sparkrdma_tpu.ops import remote_copy
 
