@@ -29,11 +29,10 @@ Usage:
                                                         #   -> BENCH_r08.json
 
 The ``--stage-ab`` mode (DESIGN.md §22) measures one whole reduce
-stage four ways on an in-process cluster — per-block device pull
+stage three ways on an in-process cluster — per-block device pull
 (collective compiler off), compiled collective waves (pipeline depth
-1), double-buffered pipelined waves (depth 2, wave_overlap_ms > 0
-asserted), and fused fetch+merge — asserts all four land
-byte-identical partitions, and
+1), and double-buffered pipelined waves (depth 2, wave_overlap_ms > 0
+asserted) — asserts all three land byte-identical partitions, and
 reports each against the exchange-loopback roofline measured on the
 SAME mesh in the same process (``*_roofline_fraction`` fields)."""
 
@@ -139,7 +138,7 @@ def run_child(e: int, num_slices: int, blocks, reps: int) -> None:
 
 
 # ----------------------------------------------------------------------
-# child: stage-level schedule A/B (per-block vs collective vs fused)
+# child: stage-level schedule A/B (per-block vs collective vs pipelined)
 # ----------------------------------------------------------------------
 def run_stage_ab_child(nblocks: int, block_bytes: int, reps: int) -> None:
     import numpy as np
@@ -184,9 +183,9 @@ def run_stage_ab_child(nblocks: int, block_bytes: int, reps: int) -> None:
             p: sorted(a.tobytes() for a in want[p]) for p in range(num_parts)
         }
 
-        def fetch(mode):
+        def fetch():
             got = io_red.fetch_device_blocks(
-                61, 0, num_parts, timeout_s=120, fused=(mode == "fused")
+                61, 0, num_parts, timeout_s=120
             )
             for bufs in got.values():
                 for b in bufs:
@@ -202,19 +201,8 @@ def run_stage_ab_child(nblocks: int, block_bytes: int, reps: int) -> None:
 
         def verify(mode, got):
             for p in range(num_parts):
-                if mode == "fused":
-                    # one merged slab per pid: pin content by length +
-                    # per-block membership (order is the merge order)
-                    assert len(got[p]) == 1, f"{mode}: pid {p} not fused"
-                    blob = bytes(got[p][0].read(0, got[p][0].length))
-                    assert len(blob) == shards * block_bytes
-                    for a in want[p]:
-                        assert a.tobytes() in blob, f"{mode}: pid {p} corrupt"
-                else:
-                    have = sorted(
-                        bytes(b.read(0, b.length)) for b in got[p]
-                    )
-                    assert have == want_sets[p], f"{mode}: pid {p} corrupt"
+                have = sorted(bytes(b.read(0, b.length)) for b in got[p])
+                assert have == want_sets[p], f"{mode}: pid {p} corrupt"
 
         # mode matrix is the A/B: the tuner would re-cut budgets
         # between reps and blur it, so it sits this bench out
@@ -241,14 +229,14 @@ def run_stage_ab_child(nblocks: int, block_bytes: int, reps: int) -> None:
                 "tpu.shuffle.collective.waveBytes",
                 str(pipelined_cut) if mode == "pipelined" else "64m",
             )
-            warm = fetch(mode)  # warmup: compile + correctness gate
+            warm = fetch()  # warmup: compile + correctness gate
             verify(mode, warm)
             free(warm)
             o0 = overlap_c.value
             times = []
             for _ in range(reps):
                 t0 = time.perf_counter()
-                got = fetch(mode)
+                got = fetch()
                 times.append(time.perf_counter() - t0)
                 free(got)
             med = statistics.median(times)
@@ -262,7 +250,7 @@ def run_stage_ab_child(nblocks: int, block_bytes: int, reps: int) -> None:
 
         modes = {
             m: run_mode(m)
-            for m in ("per_block", "collective", "pipelined", "fused")
+            for m in ("per_block", "collective", "pipelined")
         }
         conf.set("tpu.shuffle.collective.enabled", "true")
         # the pipelining A/B proof: depth 1 cannot overlap by
@@ -304,7 +292,6 @@ def run_stage_ab_child(nblocks: int, block_bytes: int, reps: int) -> None:
             "per_block_pull": modes["per_block"],
             "compiled_collective": modes["collective"],
             "pipelined_collective": modes["pipelined"],
-            "fused_fetch_merge": modes["fused"],
             "pipeline_depth": 2,
             "pipelined_wave_bytes": pipelined_cut,
             "pipelined_overlap_ms": modes["pipelined"]["overlap_ms"],
@@ -315,17 +302,11 @@ def run_stage_ab_child(nblocks: int, block_bytes: int, reps: int) -> None:
             "pipelined_roofline_fraction": round(
                 modes["pipelined"]["gbps_cpu_only"] / roof_gbps, 4
             ),
-            "fused_roofline_fraction": round(
-                modes["fused"]["gbps_cpu_only"] / roof_gbps, 4
-            ),
             "collective_speedup_vs_per_block": round(
                 modes["collective"]["gbps_cpu_only"] / max(per_block, 1e-9), 3
             ),
             "pipelined_speedup_vs_per_block": round(
                 modes["pipelined"]["gbps_cpu_only"] / max(per_block, 1e-9), 3
-            ),
-            "fused_speedup_vs_per_block": round(
-                modes["fused"]["gbps_cpu_only"] / max(per_block, 1e-9), 3
             ),
             "byte_identical_across_paths": True,
             "note": (
@@ -333,7 +314,7 @@ def run_stage_ab_child(nblocks: int, block_bytes: int, reps: int) -> None:
                 "issue/DMA latency here, so the amortization the "
                 "collective exists for (BENCH_r05's ~20x exchange-vs-"
                 "host gap) cannot show in the speedup column on this "
-                "rig. What transfers: byte identity across all four "
+                "rig. What transfers: byte identity across all three "
                 "paths, the depth-2 overlap counter going positive "
                 "while depth 1 stays zero, the roofline fractions vs "
                 "the same-mesh exchange, and the compile-once "
@@ -479,7 +460,7 @@ def main() -> None:
     ap.add_argument(
         "--stage-ab", action="store_true",
         help="stage-level schedule A/B (per-block vs collective vs "
-             "pipelined vs fused, DESIGN.md §22) -> BENCH_r08.json",
+             "pipelined, DESIGN.md §22) -> BENCH_r08.json",
     )
     ap.add_argument(
         "--stage-out", default=os.path.join(ROOT, "BENCH_r08.json"))
@@ -507,7 +488,7 @@ def main() -> None:
             "label": (
                 "Stage-level schedule A/B on the 8-virtual-device CPU "
                 "mesh: per-block device pull vs compiled collective vs "
-                "double-buffered pipelined waves vs fused fetch+merge, "
+                "double-buffered pipelined waves, "
                 "byte-identity asserted per mode, depth-2 overlap "
                 "counter asserted positive, roofline = exchange "
                 "loopback on the same mesh."

@@ -296,8 +296,7 @@ def phase_terasort_e2e(n_keys: int = TERASORT_KEYS, executors: int = EXECUTORS,
             "device_fetch.plane.fallbacks", role=role)
         out["mover_dispatches"] = {
             m: counter_total("collective.mover_dispatches", role=role, mover=m)
-            for m in ("pallas_wave_pull", "pallas_pipelined_wave_pull",
-                      "emulated")
+            for m in ("pallas_wave_pull", "pallas_pipelined_wave_pull")
         }
         if out["device_fetch_fallbacks"] != 0:
             raise SystemExit("chip_smoke: device fetch plane fell back")
@@ -425,7 +424,7 @@ def phase_wave_fetch(devices, block_keys: int = WAVE_BLOCK_KEYS,
         for depth in depths:
             conf.set("tpu.shuffle.collective.pipelineDepth", str(depth))
             before = {m: counter_total("collective.mover_dispatches", mover=m)
-                      for m in ("emulated", *movers.values())}
+                      for m in movers.values()}
             falls0 = counter_total("device_fetch.plane.fallbacks")
             t0 = time.perf_counter()
             dev, pulled = fetch_all()
@@ -439,7 +438,7 @@ def phase_wave_fetch(devices, block_keys: int = WAVE_BLOCK_KEYS,
             if pulled != n * reducers or falls:
                 raise SystemExit(f"chip_smoke: depth {depth}: {pulled} "
                                  f"blocks pulled, {falls} fallbacks")
-            if ran[movers[depth]] == 0 or ran["emulated"] or any(
+            if ran[movers[depth]] == 0 or any(
                 ran[m] for d, m in movers.items() if d != depth
             ):
                 raise SystemExit(f"chip_smoke: depth {depth} movers {ran}")

@@ -60,7 +60,6 @@ METRIC_FAMILIES: Dict[str, Tuple[str, frozenset]] = {
     "collective.mover_dispatches": ("counter", _L({"role", "mover"})),
     "collective.blocks": ("counter", _L({"role"})),
     "collective.bytes": ("counter", _L({"role"})),
-    "collective.fused_merges": ("counter", _L({"role"})),
     "collective.degrades": ("counter", _L({"role"})),
     "collective.compiles": ("counter", _L({"role"})),
     "collective.cache_hits": ("counter", _L({"role"})),
@@ -70,7 +69,6 @@ METRIC_FAMILIES: Dict[str, Tuple[str, frozenset]] = {
     "collective.wave_dispatch_ms": ("histogram", _L({"role", "schedule"})),
     "collective.wave_inflight": ("histogram", _L({"role"})),
     "collective.wave_overlap_ms": ("counter", _L({"role"})),
-    "collective.assembly_bytes": ("counter", _L({"role"})),
     "collective.device_assembled_rows": ("counter", _L({"role"})),
     "collective.ici_payload_bytes": ("counter", _L({"role"})),
     "collective.ici_moved_bytes": ("counter", _L({"role"})),

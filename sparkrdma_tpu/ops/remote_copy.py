@@ -3,7 +3,7 @@
 This is the truest analogue of the reference's IBV_WR_RDMA_READ
 (RdmaChannel.java:360-393): the destination device *pulls* a source
 device's HBM slab over the interconnect with no host CPU in the data
-path. Two movers are provided behind one call:
+path. The movers:
 
 - ``pallas_neighbor_pull``: a Pallas ``make_async_remote_copy`` kernel
   over ICI (SNIPPETS.md [1]-[3] pattern) — each device DMAs its
@@ -13,9 +13,13 @@ path. Two movers are provided behind one call:
   ``shard_map`` exactly as the guide prescribes. TPU meshes only.
 - ``emulated_pull``: ``jax.device_put`` of the source array onto the
   destination device — the same copy expressed through XLA's transfer
-  engine. It is the per-block planner's mover on every platform, and
-  the wave mover wherever ``is_tpu_mesh()`` is false (the CPU mesh of
-  the tier-1 tests).
+  engine. It is the per-block planner's mover on every platform.
+- The wave movers, one entry point each: ``pallas_wave_pull`` and
+  ``pallas_pipelined_wave_pull`` on one chip, ``pallas_mesh_wave_pull``
+  on an n > 1 mesh. This module is the only place that knows the
+  platform: on a TPU each runs its Pallas program, elsewhere a plain
+  jnp program with the same contract, so the schedule compiler runs
+  one wave path on every backend.
 
 On one chip a wave is ``rows`` local DMAs. On an n > 1 mesh
 (``pallas_mesh_wave_pull``) each row slot names the chip that holds its
@@ -215,62 +219,18 @@ def _wave_pull_program(rows: int, bucket_elems: int, dtype_str: str):
 def pallas_wave_pull(hops, stacked):
     """Run one wave's batched pull on a one-chip mesh over a
     [rows, *wave_row_shape(b)] stack; ``hops`` is the int32 per-row
-    lane, all zeros. TPU only — the schedule compiler gates on
-    ``is_tpu_mesh()`` and uses the emulated halves otherwise."""
+    lane, all zeros. Returns a fresh array holding the stack: the Pallas
+    program on a TPU, ``_stack_copy`` elsewhere."""
     if not is_tpu_mesh():
-        raise RuntimeError("pallas_wave_pull requires a TPU mesh")
+        return _stack_copy(stacked)
+    return _wave_kernel(hops, stacked)
+
+
+def _wave_kernel(hops, stacked):
     rows = stacked.shape[0]
     bucket = stacked.shape[1] * stacked.shape[2]
     prog = _wave_pull_program(rows, bucket, str(stacked.dtype))
     return prog(hops, stacked)
-
-
-@functools.lru_cache(maxsize=1)
-def _same_device_copy_program():
-    """Jitted buffer copy for the same-device pull case: unlike
-    ``device_put`` (which may alias, see ``emulated_pull``) the jit
-    output is always a fresh buffer, and unlike the forced host round
-    trip it stays on-device AND dispatches asynchronously — the issue
-    half of the pipelined emulated mover. One jit object; XLA caches
-    one tiny executable per slab class."""
-    return jax.jit(jnp.copy)
-
-
-def emulated_row_pull_start(src_array, dst_device):
-    """START one row's pull without waiting — the emulated analogue of
-    ``make_async_remote_copy(...).start()``. Returns the in-flight
-    array; the wave's consume half waits on it (``emulated_wave_wait``)
-    before adopting. Same-device sources go through a jitted copy (an
-    independent buffer the source arena's later spill cannot delete);
-    cross-device sources ride the transfer engine."""
-    if dst_device in src_array.devices():
-        return _same_device_copy_program()(src_array)
-    return jax.device_put(src_array, dst_device)
-
-
-def emulated_wave_issue(stacked_host, dst_device):
-    """ISSUE an assembled [rows, bucket] stack toward the destination
-    without waiting: the transfer engine reads the host assembly while
-    the caller moves on to the next wave (or consumes the previous
-    one). The recv-semaphore wait lives in ``emulated_wave_wait``."""
-    return jax.device_put(stacked_host, dst_device)
-
-
-def emulated_wave_wait(inflight):
-    """Wait for issued transfers to land — the emulated recv-semaphore
-    wait. Accepts a single array or any pytree/list of them (one wave's
-    row pulls wait together, like the kernel's wait-all loop)."""
-    jax.block_until_ready(inflight)
-    return inflight
-
-
-def emulated_wave_pull(stacked_host, dst_device):
-    """Off-TPU wave mover: land an assembled [rows, bucket] stack on
-    the destination in ONE transfer-engine dispatch — the emulated
-    counterpart of one batched-DMA kernel epoch. Kept as the
-    issue+wait composition; the pipelined schedule compiler calls the
-    halves separately so wave N+1's issue overlaps wave N's merge."""
-    return emulated_wave_wait(emulated_wave_issue(stacked_host, dst_device))
 
 
 @functools.lru_cache(maxsize=64)
@@ -350,11 +310,15 @@ def _pipelined_wave_pull_program(depth: int, rows: int, bucket_elems: int,
 def pallas_pipelined_wave_pull(hops, stacked, depth: int):
     """Run ``depth`` same-class waves as one double-buffered kernel
     epoch on a one-chip mesh over a [depth, rows, *wave_row_shape(b)]
-    stack; ``hops`` is the [depth, rows] int32 hop lane, all zeros. TPU
-    only — the schedule compiler gates on ``is_tpu_mesh()`` and uses
-    the emulated issue/wait halves otherwise."""
+    stack; ``hops`` is the [depth, rows] int32 hop lane, all zeros.
+    Returns a fresh array holding the stack: the Pallas program on a
+    TPU, ``_stack_copy`` elsewhere."""
     if not is_tpu_mesh():
-        raise RuntimeError("pallas_pipelined_wave_pull requires a TPU mesh")
+        return _stack_copy(stacked)
+    return _pipelined_wave_kernel(hops, stacked, depth)
+
+
+def _pipelined_wave_kernel(hops, stacked, depth: int):
     rows = stacked.shape[1]
     bucket = stacked.shape[2] * stacked.shape[3]
     prog = _pipelined_wave_pull_program(
@@ -468,14 +432,19 @@ def _mesh_wave_pull_program(axis_size: int, depth: int, send_rows: int,
 
 def pallas_mesh_wave_pull(lane, send, recv, depth: int):
     """Run ``depth`` same-class waves as one kernel epoch on an n > 1
-    mesh (``_mesh_wave_pull_program``). ``send`` is
-    [n * send_rows, *wave_row_shape(b)] and ``recv`` is
-    [n * depth * rows, *wave_row_shape(b)], both sharded row-block-wise
-    over the mesh; ``recv`` is donated and comes back as the result,
-    slot ``d * rows + i`` holding wave d's row i on its receiving chip.
-    TPU meshes only."""
+    mesh. ``send`` is [n * send_rows, *wave_row_shape(b)] and ``recv``
+    is [n * depth * rows, *wave_row_shape(b)], both sharded
+    row-block-wise over the mesh; ``recv`` is donated and comes back as
+    the result, slot ``d * rows + i`` holding wave d's row i on its
+    receiving chip. The Pallas program (``_mesh_wave_pull_program``) on
+    a TPU, ``_mesh_stand_in_program`` elsewhere."""
     if not is_tpu_mesh():
-        raise RuntimeError("pallas_mesh_wave_pull requires a TPU mesh")
+        prog = _mesh_stand_in_program(mesh_device_count(), recv.sharding)
+        return prog(lane, send, recv)
+    return _mesh_wave_kernel(lane, send, recv, depth)
+
+
+def _mesh_wave_kernel(lane, send, recv, depth: int):
     n = mesh_device_count()
     bucket = send.shape[1] * send.shape[2]
     prog = _mesh_wave_pull_program(
@@ -483,3 +452,28 @@ def pallas_mesh_wave_pull(lane, send, recv, depth: int):
         str(send.dtype),
     )
     return prog(lane, send, recv)
+
+
+# Off a TPU the wave movers keep the kernels' contract in plain jnp: the
+# one-chip movers land a fresh array holding the stack, and the mesh
+# mover writes each slot's row into its receiving chip's slot of the
+# donated receive buffer, leaving every other slot as it was.
+_stack_copy = jax.jit(jnp.copy)
+
+
+@functools.lru_cache(maxsize=64)
+def _mesh_stand_in_program(axis_size: int, sharding):
+    def mesh_wave_stand_in(lane, send, recv):
+        source, row, hop = lane.reshape(3, -1)
+        slots = source.shape[0]
+        lanes = send.shape[1:]
+        sent = send.reshape(axis_size, -1, *lanes)[jnp.maximum(source, 0),
+                                                   row]
+        land = recv.reshape(axis_size, slots, *lanes)
+        target, slot = (source + hop) % axis_size, jnp.arange(slots)
+        keep = land[target, slot]
+        rows = jnp.where((source >= 0)[:, None, None], sent, keep)
+        return land.at[target, slot].set(rows).reshape(recv.shape)
+
+    return jax.jit(mesh_wave_stand_in, donate_argnums=2,
+                   out_shardings=sharding)

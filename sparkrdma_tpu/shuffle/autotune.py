@@ -21,8 +21,8 @@ Choices persist per (shuffle, stage-shape) signature in the compiler's
 tuner instance, so the SECOND identical stage of a job already runs
 with the adjusted cut — the first knob the system tunes from its own
 attribution data. The tuned budget never drops below the stage's
-largest partition group: fusion requires a partition's rows to share
-one wave, and a tuner must never change result shapes.
+largest partition group: wave formation keeps a partition's rows in
+one wave, and a tuner must never change how a stage is cut into them.
 
 Stdlib + numpy only (the compiler imports this on every platform).
 """
@@ -63,7 +63,7 @@ class WaveReport:
                  depth: int, dispatch_ms: float, wave_ms: float,
                  overlap_ms: float):
         self.stage_bytes = stage_bytes
-        # largest single partition group (bucketed) — the fusion floor
+        # largest single partition group (bucketed) — the group floor
         self.min_group_bytes = min_group_bytes
         self.waves = waves
         self.depth = depth
@@ -76,7 +76,7 @@ class WaveAutoTuner:
     """Per-compiler controller: observe a stage, choose the next cut.
 
     Deterministic and convergent by construction: the chosen budget is
-    a pure function of (stage bytes, depth, fusion floor), so the
+    a pure function of (stage bytes, depth, group floor), so the
     second observation of the same signature computes the same choice
     and the controller goes quiet (no oscillation)."""
 
@@ -160,8 +160,8 @@ class WaveAutoTuner:
         else:
             return None  # already in band — hold
         budget = round_bucket(max(1, ideal))
-        # never cut below the fusion floor (a partition's rows must
-        # share one wave) nor above the operator's configured cap
+        # never cut below the group floor (a partition's rows share
+        # one wave) nor above the operator's configured cap
         budget = max(budget, report.min_group_bytes)
         budget = min(budget, configured)
         # and never below the smallest legal knob value

@@ -16,37 +16,20 @@ a wave's worth of in-flight transfer. The host-plane passthrough reads
 overlap with both ends — issued before the first wave, drained
 concurrently with the last via the caller's ``drain`` callback.
 
-Movers, by regime:
-
-- TPU mesh: one Pallas kernel epoch issuing a wave's DMAs together
-  (start all, wait all). Consecutive same-class waves coalesce into one
-  depth-aware epoch, wave d+1 started before wave d drains. On one chip
-  (``pallas_wave_pull`` / ``pallas_pipelined_wave_pull``) every row is
-  a local DMA. On an n > 1 mesh (``pallas_mesh_wave_pull``) a per-slot
-  lane names the chip holding each row: only that chip sends it, only
-  the receiving chip waits, each chip's send shard holds only its own
-  rows, and the receive buffer is pooled per (slots, bucket) class and
-  donated back in. The send stack is gathered on the device from the
-  pinned source slabs (``_send_gather_program``), so no payload byte
-  crosses to the host.
-- Everywhere else (the CPU mesh): the emulated mover's
-  ISSUE/CONSUME halves (``emulated_row_pull_start`` /
-  ``emulated_wave_wait``) — per-row pulls started together without
-  waiting, landed slabs adopted directly (the same single-copy
-  semantics as the per-block planner, batched, async, and overlapped
-  across waves), which is why the compiled schedule beats the
-  per-block pull loop even on the CPU mesh. Rows the fast lane cannot
-  carry (nonzero arena offset, class mismatch, fused partitions that
-  merge host-side) ride an assembled host stack and land through the
-  compile-free ``stage_view`` path.
-
-Fusion: a partition whose every block rides in one wave can merge in
-the same epoch — a cached compaction program gathers the wave's valid
-prefixes into one contiguous slab, so the partition lands as ONE
-merged device buffer (concatenated in deterministic source order,
-composing with the merged-cover contract of shuffle/merge.py) with no
-intermediate HBM round trip. Fusion changes the result SHAPE (one
-buffer per partition), so callers opt in per fetch.
+Movers: one Pallas kernel epoch issues a wave's DMAs together (start
+all, wait all). Consecutive same-class waves coalesce into one
+depth-aware epoch, wave d+1 started before wave d drains. On one chip
+(``pallas_wave_pull`` / ``pallas_pipelined_wave_pull``) every row is a
+local DMA. On an n > 1 mesh (``pallas_mesh_wave_pull``) a per-slot lane
+names the chip holding each row: only that chip sends it, only the
+receiving chip waits, each chip's send shard holds only its own rows,
+and the receive buffer is pooled per (slots, bucket) class and donated
+back in. The send stack is gathered on the device from the pinned
+source slabs (``_send_gather_program``), so no payload byte crosses to
+the host, and each landed row is taken whole into an arena slab
+(``_row_take_program``). Off a TPU, ``ops/remote_copy.py`` swaps each
+mover kernel for a jnp program with the same contract; this module
+runs the same path on every backend.
 
 Self-tuning: the compiler's :class:`~sparkrdma_tpu.shuffle.autotune.
 WaveAutoTuner` re-derives the effective ``collective.waveBytes`` per
@@ -54,8 +37,7 @@ WaveAutoTuner` re-derives the effective ``collective.waveBytes`` per
 the job's TimeBreakdown and profiler gap frames — the second identical
 stage of a job already runs with the adjusted cut.
 
-Degrade ladder (byte-identical; on a TPU mesh a mover failure raises
-instead, since the Pallas movers are the path under test there):
+Degrade ladder (byte-identical where a row degrades):
 
 | condition                                   | outcome             |
 |---------------------------------------------|---------------------|
@@ -63,8 +45,7 @@ instead, since the Pallas movers are the path under test there):
 | < ``collective.minBlocks`` device blocks     | per-block planner   |
 | block fails eligibility (size/dtype/arena)   | per-block planner   |
 | slab evicted/spilled between plan and pin    | host triple, degrade++ |
-| emulated mover fails (issue OR landing)     | host triple, degrade++ |
-| Pallas mover fails (TPU mesh)               | raises              |
+| mover fails to dispatch or to land           | raises, pins closed |
 | row adoption fails mid-pipeline              | host triple, degrade++ |
 | abort unwinds with waves in flight           | pins closed, rows degrade |
 """
@@ -85,11 +66,7 @@ from sparkrdma_tpu.locations import PartitionLocation
 from sparkrdma_tpu.obs import get_registry, get_tracer
 from sparkrdma_tpu.ops import remote_copy
 from sparkrdma_tpu.ops.exchange import round_bucket, round_rows
-from sparkrdma_tpu.ops.hbm_arena import (
-    DeviceBuffer,
-    DeviceBufferManager,
-    _size_class,
-)
+from sparkrdma_tpu.ops.hbm_arena import DeviceBuffer, DeviceBufferManager
 from sparkrdma_tpu.shuffle.autotune import (
     WaveAutoTuner,
     WaveReport,
@@ -101,44 +78,14 @@ logger = logging.getLogger(__name__)
 
 
 def merge_order_key(loc: PartitionLocation) -> Tuple:
-    """Deterministic within-partition merge order — the order fused
-    slabs concatenate in, and the order tests/benches sort per-block
-    results into when comparing against a fused result."""
+    """Deterministic within-partition order of a stage's wave rows, by
+    source."""
     return (
         loc.manager_id.executor_id,
         loc.block.mkey,
         loc.block.address,
         loc.block.arena_handle,
     )
-
-
-@functools.lru_cache(maxsize=64)
-def _compaction_program(rows_b: int, bucket_elems: int, dtype_str: str):
-    """Jitted fetch->merge compaction: gather every row's valid prefix
-    of a landed [rows_b, bucket_elems] wave into one contiguous flat
-    lane — the merge half of the fused epoch. Pure gather math (no
-    dynamic shapes): position j belongs to the row whose element span
-    covers it, looked up against the inclusive end-offsets lane. On
-    TPU, XLA keeps the gather in the same HBM residency as the landed
-    wave — fetch to merged slab with no host round trip.
-
-    Cached per (rows class, bucket class, dtype); rows and buckets are
-    both power-of-two bucketed upstream, so ragged stages reuse these
-    executables."""
-    import jax
-    import jax.numpy as jnp
-
-    jnp.dtype(dtype_str)  # validate the cache key up front
-    total = rows_b * bucket_elems
-
-    def fn(stacked, starts, ends):
-        j = jnp.arange(total, dtype=jnp.int32)
-        row = jnp.searchsorted(ends, j, side="right")
-        row = jnp.minimum(row, rows_b - 1)
-        col = jnp.clip(j - starts[row], 0, bucket_elems - 1)
-        return stacked[row, col]
-
-    return jax.jit(fn)
 
 
 @functools.lru_cache(maxsize=64)
@@ -255,18 +202,16 @@ class CollectivePlan:
     ``sig``/``stage_bytes``/``max_group_bytes`` feed the wave
     self-tuner after execution (None/0 when the compiler declined)."""
 
-    __slots__ = ("schedule", "waves", "passthrough", "fusable_pids",
-                 "device_blocks", "sig", "stage_bytes", "max_group_bytes")
+    __slots__ = ("schedule", "waves", "passthrough", "device_blocks", "sig",
+                 "stage_bytes", "max_group_bytes")
 
     def __init__(self, schedule: str, waves: List[CollectiveWave],
-                 passthrough: List[PartitionLocation],
-                 fusable_pids: frozenset, device_blocks: int,
+                 passthrough: List[PartitionLocation], device_blocks: int,
                  sig: Optional[Tuple] = None, stage_bytes: int = 0,
                  max_group_bytes: int = 0):
         self.schedule = schedule
         self.waves = waves
         self.passthrough = passthrough
-        self.fusable_pids = fusable_pids
         self.device_blocks = device_blocks
         self.sig = sig
         self.stage_bytes = stage_bytes
@@ -274,29 +219,23 @@ class CollectivePlan:
 
 
 class CollectiveResult:
-    """One landed slab: a single block, or a fused per-partition merge
-    (``fused`` — ``locs`` then lists every covered block in merge
-    order and ``dev.length`` is their summed payload)."""
+    """One landed block: its location and the arena slab holding it."""
 
-    __slots__ = ("pid", "dev", "locs", "fused")
+    __slots__ = ("loc", "dev")
 
-    def __init__(self, pid: int, dev: DeviceBuffer,
-                 locs: List[PartitionLocation], fused: bool):
-        self.pid = pid
+    def __init__(self, loc: PartitionLocation, dev: DeviceBuffer):
+        self.loc = loc
         self.dev = dev
-        self.locs = locs
-        self.fused = fused
 
 
 class _InflightWave:
-    """One pipeline entry: a wave (or a same-class TPU kernel run of
-    them) whose transfers are airborne. Pins stay held from issue to
-    consume — the source slabs must survive until the recv semaphores
-    land; the pipeline bounds the held set to ``depth`` entries."""
+    """One pipeline entry: a wave (or a same-class kernel run of them)
+    whose transfers are airborne. Pins stay held from issue to consume
+    — the source slabs must survive until the recv semaphores land; the
+    pipeline bounds the held set to ``depth`` entries."""
 
-    __slots__ = ("waves", "pins", "t0", "dead", "all_dead", "row_arrs",
-                 "row_views", "stacked_hosts", "landed", "recv_key",
-                 "nbytes", "live")
+    __slots__ = ("waves", "pins", "t0", "dead", "all_dead", "landed",
+                 "recv_key", "nbytes", "live")
 
     def __init__(self, waves: List[CollectiveWave], pins: ExitStack,
                  t0: float):
@@ -305,17 +244,7 @@ class _InflightWave:
         self.t0 = t0
         self.dead: List[_Row] = []
         self.all_dead = False
-        # per wave: fast-lane in-flight arrays (row index -> array)
-        self.row_arrs: List[Dict[int, object]] = []
-        # per wave: zero-copy host views of pinned sources (fused CPU
-        # rows — the merge concatenates straight from these, skipping
-        # the stacked-assembly copy; valid only while pins are held)
-        self.row_views: List[Dict[int, np.ndarray]] = []
-        # per wave: assembled host stack (None when every row rode the
-        # fast lane or a view, and on a TPU mesh)
-        self.stacked_hosts: List[Optional[np.ndarray]] = []
-        # TPU in-flight kernel result: ("single"|"pipelined", async
-        # sharded output)
+        # the mover epoch's in-flight output, sharded over the mesh
         self.landed = None
         # n > 1 mesh: the receive pool class the landed output goes
         # back to once adopted
@@ -353,7 +282,6 @@ class ShuffleScheduleCompiler:
         self._m_plans = reg.counter("collective.plans", role=role)
         self._m_blocks = reg.counter("collective.blocks", role=role)
         self._m_bytes = reg.counter("collective.bytes", role=role)
-        self._m_fused = reg.counter("collective.fused_merges", role=role)
         self._m_degrades = reg.counter("collective.degrades", role=role)
         self._m_compiles = reg.counter("collective.compiles", role=role)
         self._m_cache_hits = reg.counter("collective.cache_hits", role=role)
@@ -364,16 +292,11 @@ class ShuffleScheduleCompiler:
         self._m_inflight = reg.histogram(
             "collective.wave_inflight", role=role
         )
-        # host bytes the wave assembly moves: source slabs read back
-        # (off a TPU mesh; on one the send stack is gathered on device)
-        self._m_assembly_bytes = reg.counter(
-            "collective.assembly_bytes", role=role
-        )
-        # TPU mesh rows laid into the send stack on the device
+        # rows laid into the send stack on the device
         self._m_device_rows = reg.counter(
             "collective.device_assembled_rows", role=role
         )
-        # per TPU epoch: payload of the rows that cross chips, the
+        # per epoch: payload of the rows that cross chips, the
         # bucket bytes their DMAs carry, and the HBM the epoch newly
         # allocates across the mesh (send and receive shards)
         self._m_ici_payload = reg.counter(
@@ -415,15 +338,11 @@ class ShuffleScheduleCompiler:
         conf = self._conf
         itemsize = np.dtype(dtype).itemsize
         if not conf.collective_enabled or not conf.device_fetch_enabled:
-            return CollectivePlan("off", [], list(locations), frozenset(), 0)
+            return CollectivePlan("off", [], list(locations), 0)
         min_bytes = conf.device_fetch_min_block_bytes
         eligible: List[PartitionLocation] = []
         passthrough: List[PartitionLocation] = []
-        per_pid_total: Dict[int, int] = {}
         for loc in locations:
-            per_pid_total[loc.partition_id] = (
-                per_pid_total.get(loc.partition_id, 0) + 1
-            )
             b = loc.block
             if (
                 b.has_device
@@ -438,20 +357,16 @@ class ShuffleScheduleCompiler:
         if len(eligible) < conf.collective_min_blocks:
             # too small a stage for a wave: the per-block planner keeps
             # the whole set (it may still pull the stragglers one by one)
-            return CollectivePlan(
-                "off", [], list(locations), frozenset(), 0
-            )
+            return CollectivePlan("off", [], list(locations), 0)
 
-        # merge order: partition-major so a fused pid's rows are
-        # contiguous, source-ordered within the partition
+        # merge order: partition-major so a pid's rows are contiguous,
+        # source-ordered within the partition
         eligible.sort(key=lambda loc: (loc.partition_id, merge_order_key(loc)))
-        per_pid_eligible: Dict[int, int] = {}
         per_pid_bytes: Dict[int, int] = {}
         stage_bytes = 0
         max_len = 0
         for loc in eligible:
             pid = loc.partition_id
-            per_pid_eligible[pid] = per_pid_eligible.get(pid, 0) + 1
             bucketed = round_bucket(loc.block.length)
             per_pid_bytes[pid] = per_pid_bytes.get(pid, 0) + bucketed
             stage_bytes += bucketed
@@ -464,9 +379,9 @@ class ShuffleScheduleCompiler:
             schedule = "a2a" if len(lanes) > 2 else "ring"
 
         # the self-tuned cut: a stage shape the tuner has observed runs
-        # with its adjusted budget (never below the fusion floor — a
-        # partition's rows must share one wave — and never above the
-        # operator's configured cap)
+        # with its adjusted budget (never below the largest partition
+        # group — a partition's rows share one wave — and never above
+        # the operator's configured cap)
         sig = stage_signature(
             schedule, len(lanes), round_rows(len(eligible)),
             round_bucket(max_len), np.dtype(dtype).name,
@@ -476,11 +391,10 @@ class ShuffleScheduleCompiler:
         if tuned:
             wave_budget = min(max(tuned, max_group_bytes), wave_budget)
 
-        # wave formation: pid-group granularity (fusion needs a pid's
-        # rows in ONE wave), split only when a single pid alone
-        # overflows the wave budget (that pid becomes unfusable)
+        # wave formation: pid-group granularity (a pid's rows ride ONE
+        # wave), split only when a single pid alone overflows the wave
+        # budget
         waves: List[CollectiveWave] = []
-        fusable: set = set()
         cur_rows: List[_Row] = []
         cur_max_len = 0
 
@@ -507,7 +421,7 @@ class ShuffleScheduleCompiler:
             group_bytes = per_pid_bytes[pid]
             if group_bytes > wave_budget and len(group) > 1:
                 # oversized pid: seal what we have, stream the pid
-                # through dedicated waves, leave it unfusable
+                # through dedicated waves
                 seal()
                 for loc in group:
                     cur_rows.append(_Row(loc, loc.block.length // itemsize))
@@ -525,12 +439,6 @@ class ShuffleScheduleCompiler:
                 for loc in group:
                     cur_rows.append(_Row(loc, loc.block.length // itemsize))
                 cur_max_len = max(cur_max_len, group_max)
-                # fusable iff every one of the pid's published blocks
-                # made it into the schedule (full device cover, the
-                # merged-cover rule of shuffle/merge.py) and they share
-                # this wave
-                if per_pid_eligible[pid] == per_pid_total[pid]:
-                    fusable.add(pid)
             i = j
         seal()
 
@@ -544,9 +452,8 @@ class ShuffleScheduleCompiler:
             waves.sort(key=lambda w: lane_index[w.lane])
         self._m_plan_ms.observe((time.perf_counter() - t0) * 1e3)
         return CollectivePlan(
-            schedule, waves, passthrough, frozenset(fusable), len(eligible),
-            sig=sig, stage_bytes=stage_bytes,
-            max_group_bytes=max_group_bytes,
+            schedule, waves, passthrough, len(eligible), sig=sig,
+            stage_bytes=stage_bytes, max_group_bytes=max_group_bytes,
         )
 
     # ------------------------------------------------------------------
@@ -557,11 +464,10 @@ class ShuffleScheduleCompiler:
         shuffle_id: int,
         plan: CollectivePlan,
         dtype=np.uint8,
-        fused: bool = False,
         drain=None,
     ) -> Tuple[List[CollectiveResult], List[PartitionLocation]]:
         """Run the compiled schedule as a double-buffered pipeline;
-        returns ``(results, degraded)``.
+        returns ``(results, degraded)``, one result per landed block.
 
         Up to ``collective.pipelineDepth`` entries stay in flight:
         entry N+1's transfers are DISPATCHED before entry N's rows are
@@ -572,17 +478,14 @@ class ShuffleScheduleCompiler:
         are in flight rather than after the last one.
 
         ``degraded`` lists every scheduled block that missed (evicted
-        mid-stage, stale coordinates, mover failure, adoption failure)
-        — the caller host-fetches them; with fusion on, a miss also
-        unfuses its partition (the survivors land per block, the host
-        fills the gap), so the byte content of the stage is identical
-        on every path. Per-entry failures raise only from a Pallas
-        mover on a TPU mesh; if an exception DOES unwind (that, or
-        ``drain``), every in-flight entry's pins are closed on the way
-        out — no slab or pin outlives the stage."""
+        mid-stage, stale coordinates, adoption failure) — the caller
+        host-fetches them, so the byte content of the stage is
+        identical on every path. A mover that fails to dispatch or to
+        land raises; if an exception unwinds (that, or ``drain``),
+        every in-flight entry's pins are closed on the way out — no
+        slab or pin outlives the stage."""
         if not plan.waves:
             return [], []
-        fused = bool(fused) and self._conf.collective_fused_merge
         depth = max(1, self._conf.collective_pipeline_depth)
         self._schedule_label = plan.schedule
         reg = get_registry()
@@ -595,25 +498,19 @@ class ShuffleScheduleCompiler:
             schedule=plan.schedule, waves=len(plan.waves),
             blocks=plan.device_blocks, depth=depth,
         ):
-            # pids that lose a row to degradation must not fuse: the
-            # host path refills per block, so survivors stay per block
-            unfusable: set = set()
             inflight: Deque[_InflightWave] = deque()
 
             def _degrade_rows(rows: List[_Row]) -> None:
                 if not rows:
                     return
-                for row in rows:
-                    degraded.append(row.loc)
-                    unfusable.add(row.loc.partition_id)
+                degraded.extend(row.loc for row in rows)
                 self._m_degrades.inc(len(rows))
                 self._m_plane_fallbacks.inc(len(rows))
 
             def _consume_next() -> None:
                 entry = inflight.popleft()
                 self._consume_entry(
-                    entry, shuffle_id, dtype, fused, plan.fusable_pids,
-                    unfusable, results, _degrade_rows, reg,
+                    entry, shuffle_id, dtype, results, _degrade_rows, reg,
                     overlapped=bool(inflight), stats=stats,
                 )
                 if drain is not None:
@@ -624,16 +521,9 @@ class ShuffleScheduleCompiler:
                     while len(inflight) >= depth:
                         _consume_next()
                     entry = self._issue_entry(
-                        shuffle_id, group, dtype, fused,
-                        plan.fusable_pids, reg,
-                        overlapped=bool(inflight), stats=stats,
+                        group, dtype, reg, overlapped=bool(inflight),
+                        stats=stats,
                     )
-                    if entry is None:
-                        # whole-entry mover failure: every row degrades
-                        _degrade_rows(
-                            [r for w in group for r in w.rows]
-                        )
-                        continue
                     _degrade_rows(entry.dead)
                     if entry.all_dead:
                         continue
@@ -675,8 +565,7 @@ class ShuffleScheduleCompiler:
     def _mover_dispatched(self, mover: str) -> None:
         """Count one pipeline entry's dispatch by the mover that ran it
         (``collective.mover_dispatches{mover}``): the on-chip smoke
-        asserts from this that the Pallas movers, not the transfer
-        engine, carried a TPU mesh's waves."""
+        asserts from this that the Pallas movers carried the waves."""
         get_registry().counter(
             "collective.mover_dispatches", role=self._executor_id,
             mover=mover,
@@ -693,12 +582,10 @@ class ShuffleScheduleCompiler:
     def _coalesce(
         self, waves: List[CollectiveWave], depth: int
     ) -> List[List[CollectiveWave]]:
-        """Group consecutive same-class waves into depth-aware kernel
-        runs. TPU only: the run becomes ONE ``pallas_pipelined_wave_
-        pull`` epoch with a DMA-semaphore array per in-flight wave. Off
-        TPU every wave is its own pipeline entry — the overlap happens
-        at the host level (issue N+1 while N merges)."""
-        if depth <= 1 or not remote_copy.is_tpu_mesh():
+        """Group consecutive same-class waves, up to ``depth`` of them,
+        into one pipeline entry: the run becomes ONE pipelined mover
+        epoch with a DMA-semaphore array per in-flight wave."""
+        if depth <= 1:
             return [[w] for w in waves]
         groups: List[List[CollectiveWave]] = []
         i = 0
@@ -716,39 +603,27 @@ class ShuffleScheduleCompiler:
         return groups
 
     def _issue_entry(
-        self, shuffle_id: int, waves: List[CollectiveWave], dtype,
-        fused: bool, fusable_pids: frozenset, reg, overlapped: bool,
+        self, waves: List[CollectiveWave], dtype, reg, overlapped: bool,
         stats: Dict[str, float],
-    ) -> Optional[_InflightWave]:
-        """Pin, assemble, and DISPATCH one pipeline entry without
-        waiting — the issue half of the double buffer. Rows that fail
-        the under-pin residency re-check come back in ``entry.dead``
-        (the caller degrades them); an emulated mover failure returns
-        None and the whole entry degrades, while on a TPU mesh any
-        failure raises. The entry's pins stay held until its consume:
-        the source slabs must outlive the in-flight DMAs."""
+    ) -> _InflightWave:
+        """Pin and DISPATCH one pipeline entry without waiting — the
+        issue half of the double buffer. Rows that fail the under-pin
+        residency re-check come back in ``entry.dead`` (the caller
+        degrades them); a mover failure raises, pins released. The
+        entry's pins stay held until its consume: the source slabs must
+        outlive the in-flight DMAs."""
         t0 = time.perf_counter()
         itemsize = np.dtype(dtype).itemsize
-        tpu = remote_copy.is_tpu_mesh()
         pins = ExitStack()
         entry = _InflightWave(waves, pins, t0)
-        # TPU mesh, per wave: row index -> pinned source slab
+        # per wave: row index -> pinned source slab
         sources: List[Dict[int, DeviceBuffer]] = []
         try:
             for wave in waves:
+                # the send stack is gathered on the device at dispatch:
+                # here the sources are only pinned
                 with self._tracer.timed("fetch.wave.assemble"):
-                    if tpu:
-                        # the send stack is gathered on the device at
-                        # dispatch: here the sources are only pinned
-                        sources.append(self._pin_rows(wave, entry, dtype))
-                        arrs, views, stacked = {}, {}, None
-                    else:
-                        arrs, views, stacked = self._assemble_wave(
-                            wave, entry, dtype, fused, fusable_pids
-                        )
-                entry.row_arrs.append(arrs)
-                entry.row_views.append(views)
-                entry.stacked_hosts.append(stacked)
+                    sources.append(self._pin_rows(wave, entry, dtype))
             live_rows = [r for w in waves for r in w.rows if r.live]
             if not live_rows:
                 # every row died at the pin: nothing to move; the
@@ -756,10 +631,7 @@ class ShuffleScheduleCompiler:
                 pins.close()
                 entry.all_dead = True
                 return entry
-            if tpu:
-                self._dispatch_pallas(entry, sources, dtype)
-            else:
-                self._mover_dispatched("emulated")
+            self._dispatch_pallas(entry, sources, dtype)
             if len(waves) > 1:
                 key = ("wave-pipe", len(waves), waves[0].rows_b,
                        waves[0].bucket_elems, np.dtype(dtype).name)
@@ -770,13 +642,10 @@ class ShuffleScheduleCompiler:
                            np.dtype(dtype).name)
                     self._program_key_seen(key)
         except Exception:
+            # the movers ARE the path: a failure is a fault to surface,
+            # not a row to degrade
             pins.close()
-            if tpu:
-                # on a TPU mesh the Pallas movers ARE the path: a failure
-                # there is a bug to surface, not a row to degrade
-                raise
-            logger.exception("collective wave issue failed; degrading to host")
-            return None
+            raise
         entry.live = len(live_rows)
         entry.nbytes = sum(r.elems * itemsize for r in live_rows)
         dispatch_ms = (time.perf_counter() - t0) * 1e3
@@ -818,62 +687,13 @@ class ShuffleScheduleCompiler:
             srcs[i] = src
         return srcs
 
-    def _assemble_wave(self, wave: CollectiveWave, entry: _InflightWave,
-                       dtype, fused: bool, fusable_pids: frozenset):
-        """Off a TPU mesh: pin one wave's source slabs and lay out its
-        rows: returns ``(arrs, views, stacked)`` — fast-lane pulls
-        started, zero-copy views of fused rows, and the host stack the
-        other rows are copied into."""
-        itemsize = np.dtype(dtype).itemsize
-        stacked: Optional[np.ndarray] = None
-        arrs: Dict[int, object] = {}
-        views: Dict[int, np.ndarray] = {}
-        for i, src in self._pin_rows(wave, entry, dtype).items():
-            row = wave.rows[i]
-            blk = row.loc.block
-            fuse_row = fused and row.loc.partition_id in fusable_pids
-            if (
-                not fuse_row
-                and blk.arena_offset == 0
-                and src.array.nbytes == _size_class(blk.length)
-            ):
-                # fast lane: START the row's pull now (async;
-                # same-device sources go through a jitted copy,
-                # cross-device through the transfer engine) and
-                # adopt the landed slab whole at consume — the
-                # per-block planner's single-copy semantics,
-                # batched and overlapped
-                arrs[i] = remote_copy.emulated_row_pull_start(
-                    src.array, self._dev.device
-                )
-                continue
-            host = np.asarray(src.array).view(dtype)
-            self._m_assembly_bytes.inc(host.nbytes)
-            off = blk.arena_offset // itemsize
-            if fuse_row:
-                # fused row: hold a zero-copy view of the pinned
-                # source — the merge at consume concatenates
-                # straight from it, skipping the stacked-assembly
-                # copy (the pin stays held through adoption, so the
-                # view stays valid)
-                views[i] = host[off : off + row.elems]
-                continue
-            # the emulated gather: source HBM -> host lane of the
-            # assembled stack, for offset/class-mismatched rows
-            if stacked is None:
-                stacked = np.zeros(
-                    (wave.rows_b, wave.bucket_elems), dtype=dtype
-                )
-            stacked[i, : row.elems] = host[off : off + row.elems]
-        return arrs, views, stacked
-
     def _dispatch_pallas(self, entry: _InflightWave,
                          sources: List[Dict[int, DeviceBuffer]], dtype):
-        """START the entry's DMAs as one kernel epoch (the depth-aware
+        """START the entry's DMAs as one mover epoch (the depth-aware
         double-buffered program when the entry carries a same-class
-        run) WITHOUT waiting, into ``entry.landed``; consume slices the
-        landed result per wave. The send stack is gathered on the
-        device from the pinned source slabs. A mover failure raises."""
+        run) WITHOUT waiting, into ``entry.landed``; consume takes the
+        landed rows per wave. The send stack is gathered on the device
+        from the pinned source slabs. A mover failure raises."""
         import jax
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -911,14 +731,11 @@ class ShuffleScheduleCompiler:
         self._m_mesh_bytes.inc(2 * stack.nbytes)
         if depth == 1:
             self._mover_dispatched("pallas_wave_pull")
-            entry.landed = (
-                "single", remote_copy.pallas_wave_pull(hop_lane, stack)
-            )
+            entry.landed = remote_copy.pallas_wave_pull(hop_lane, stack)
             return
         self._mover_dispatched("pallas_pipelined_wave_pull")
-        entry.landed = (
-            "pipelined",
-            remote_copy.pallas_pipelined_wave_pull(hop_lane, stack, depth),
+        entry.landed = remote_copy.pallas_pipelined_wave_pull(
+            hop_lane, stack, depth
         )
 
     def _send_shard(self, rows, device, depth: int, rows_b: int,
@@ -999,9 +816,8 @@ class ShuffleScheduleCompiler:
         self._mover_dispatched(
             "pallas_wave_pull" if single else "pallas_pipelined_wave_pull"
         )
-        entry.landed = (
-            "single" if single else "pipelined",
-            remote_copy.pallas_mesh_wave_pull(lane_dev, stack, recv, depth),
+        entry.landed = remote_copy.pallas_mesh_wave_pull(
+            lane_dev, stack, recv, depth
         )
 
     def _take_recv(self, key: Tuple, sharding):
@@ -1032,44 +848,26 @@ class ShuffleScheduleCompiler:
             arr.delete()
 
     def _consume_entry(
-        self, entry: _InflightWave, shuffle_id: int, dtype, fused: bool,
-        fusable_pids: frozenset, unfusable: set, results, _degrade_rows,
-        reg, overlapped: bool, stats: Dict[str, float],
+        self, entry: _InflightWave, shuffle_id: int, dtype, results,
+        _degrade_rows, reg, overlapped: bool, stats: Dict[str, float],
     ) -> None:
         """Wait for one entry's transfers (the recv-semaphore wait),
-        release its pins, and adopt its rows into arena slabs. An
-        emulated landing failure degrades the entry and an adoption
-        failure degrades the affected rows — the pipeline keeps
-        flowing; a Pallas landing failure raises."""
+        adopt its rows into arena slabs, and release its pins. A landing
+        failure raises, pins released; an adoption failure degrades the
+        affected row and the pipeline keeps flowing."""
+        import jax
+
         t0 = time.perf_counter()
         role = self._executor_id
         try:
-            waiting: List[object] = [
-                a for arrs in entry.row_arrs for a in arrs.values()
-            ]
-            if entry.landed is not None:
-                _, obj = entry.landed
-                waiting.extend(obj if isinstance(obj, list) else [obj])
             with self._tracer.timed("fetch.wave.wait"):
-                remote_copy.emulated_wave_wait(waiting)
+                jax.block_until_ready(entry.landed)
                 landed = self._landed_shard(entry)
         except Exception:
-            if entry.landed is not None:
-                # a Pallas epoch that failed to land is a device fault:
-                # surface it (the pins still release)
-                entry.close()
-                raise
-            logger.exception(
-                "collective wave landing failed; degrading to host"
-            )
+            # an epoch that failed to land is a device fault: surface it
+            # (the pins still release)
             entry.close()
-            _degrade_rows(
-                [r for w in entry.waves for r in w.rows if r.live]
-            )
-            return
-        # pins stay held through adoption: the fused merge reads
-        # zero-copy views of the source slabs (the finally releases
-        # them even if an adopt body throws)
+            raise
         itemsize = np.dtype(dtype).itemsize
         now = time.perf_counter()
         try:
@@ -1095,11 +893,7 @@ class ShuffleScheduleCompiler:
                     rows=len(live), bytes=nbytes,
                 ), self._tracer.timed("fetch.wave.adopt"):
                     out, failed = self._adopt_wave(
-                        wave, landed, d * wave.rows_b,
-                        dtype, fused, fusable_pids - unfusable,
-                        stacked_host=entry.stacked_hosts[d],
-                        row_arrs=entry.row_arrs[d],
-                        row_views=entry.row_views[d],
+                        wave, landed, d * wave.rows_b, dtype
                     )
                 results.extend(out)
                 _degrade_rows(failed)
@@ -1112,7 +906,7 @@ class ShuffleScheduleCompiler:
                 # every row taken from it is dispatched: the next
                 # epoch of its class may write it again
                 with self._recv_lock:
-                    self._recv_free[entry.recv_key].append(entry.landed[1])
+                    self._recv_free[entry.recv_key].append(entry.landed)
         finally:
             entry.close()
         consume_ms = (time.perf_counter() - t0) * 1e3
@@ -1126,16 +920,11 @@ class ShuffleScheduleCompiler:
     _schedule_label = "ring"
 
     def _landed_shard(self, entry: _InflightWave):
-        """This executor's own shard of a Pallas entry's landed output —
+        """This executor's own shard of an entry's landed output —
         ``[rows_b, *lanes]``, or ``[depth, rows_b, *lanes]`` for a
-        pipelined entry; wave d's row i is slot ``d * rows_b + i`` —
-        or None on the emulated path, whose rows adopt from the
-        fast-lane arrays and the host assembly directly."""
-        if entry.landed is None:
-            return None
-        _, obj = entry.landed
+        pipelined entry; wave d's row i is slot ``d * rows_b + i``."""
         return next(
-            s.data for s in obj.addressable_shards
+            s.data for s in entry.landed.addressable_shards
             if s.device == self._dev.device
         )
 
@@ -1149,150 +938,36 @@ class ShuffleScheduleCompiler:
         self._program_key_seen(key)
         return _row_take_program(*key[1:])(landed, slot)
 
-    def _adopt_wave(self, wave, landed, slot0, dtype, fused, fusable_pids,
-                    stacked_host=None, row_arrs=None, row_views=None):
-        """Adopt a landed wave into arena slabs: fused partitions land
-        as one merged slab; everything else lands per block. Returns
-        ``(results, failed_rows)`` — adoption failures degrade their
-        rows instead of unwinding the pipeline.
-
-        Row sources, one merge order: fast-lane rows adopt their
-        landed slab-class array whole (``put_array``, no pad program —
-        classes match by construction); fused CPU rows concatenate
-        from zero-copy views of the still-pinned sources (one copy,
-        not assembly + copy); assembled rows stage their exact payload
-        through the compile-free ``stage_view`` path; TPU rows take
-        their slab whole from the landed device shard (``landed``, the
-        wave's rows from slot ``slot0``). Fused compaction runs the cached
-        device gather when the wave is TPU-resident, and a plain numpy
-        concatenate off-TPU (a device gather program is pure overhead
-        on the single-core harness)."""
+    def _adopt_wave(self, wave, landed, slot0, dtype):
+        """Adopt a landed wave into arena slabs, one per live row, each
+        taken whole from this executor's landed shard (``landed``, the
+        wave's rows from slot ``slot0``). Returns ``(results,
+        failed_rows)`` — an adoption failure degrades its row instead of
+        unwinding the pipeline."""
         itemsize = np.dtype(dtype).itemsize
-        row_arrs = row_arrs or {}
-        row_views = row_views or {}
         out: List[CollectiveResult] = []
         failed: List[_Row] = []
-        flat = None
-        starts_e = None
-        if fused:
-            # per-row element offsets (host-known lengths), feeding the
-            # cached compaction gather
-            counts = np.array(
-                [r.elems if r.live else 0 for r in wave.rows]
-                + [0] * (wave.rows_b - len(wave.rows)),
-                dtype=np.int32,
-            )
-            ends_e = np.cumsum(counts, dtype=np.int32)
-            starts_e = ends_e - counts
-            need = any(
-                r.live and r.loc.partition_id in fusable_pids
-                for r in wave.rows
-            )
-            if need and not remote_copy.is_tpu_mesh() and (
-                row_views or stacked_host is not None
-            ):
-                flat = np.concatenate(
-                    [row_views[i] if i in row_views
-                     else stacked_host[i, : r.elems]
-                     for i, r in enumerate(wave.rows) if r.live]
-                    or [np.empty(0, dtype=dtype)]
-                )
-            elif need and landed is not None:
-                key = ("compact", wave.rows_b, wave.bucket_elems,
-                       np.dtype(dtype).name)
-                self._program_key_seen(key)
-                prog = _compaction_program(
-                    wave.rows_b, wave.bucket_elems, np.dtype(dtype).name
-                )
-                # the wave's rows as [rows, bucket] (an on-device relayout)
-                stacked_dev = landed.reshape(-1, wave.bucket_elems)[
-                    slot0 : slot0 + wave.rows_b
-                ]
-                flat = prog(stacked_dev, starts_e, ends_e)
-
-        i = 0
-        n = len(wave.rows)
-        while i < n:
-            row = wave.rows[i]
-            pid = row.loc.partition_id
-            j = i
-            while j < n and wave.rows[j].loc.partition_id == pid:
-                j += 1
-            group = [r for r in wave.rows[i:j] if r.live]
-            if not group:
-                i = j
+        for i, row in enumerate(wave.rows):
+            if not row.live:
                 continue
+            nbytes = row.elems * itemsize
             try:
-                if fused and flat is not None and pid in fusable_pids:
-                    lo = int(starts_e[i])
-                    hi = lo + sum(r.elems for r in group)
-                    seg = flat[lo:hi]
-                    if isinstance(seg, np.ndarray):
-                        # host-compacted: the merged slab moves in ONE
-                        # put (a class-exact segment adopts with no
-                        # pad program and no second copy)
-                        import jax
-
-                        seg = jax.device_put(seg, self._dev.device)
-                    dev = self._dev.get(seg.size * itemsize)
-                    try:
-                        dev = dev.put_array(seg)
-                    except Exception:
-                        dev.free()
-                        raise
-                    out.append(CollectiveResult(
-                        pid, dev, [r.loc for r in group], True
+                dev = self._dev.get(nbytes)
+                try:
+                    dev = dev.put_array(self._take_row(
+                        landed, slot0 + i, wave, dev.capacity // itemsize,
+                        dtype,
                     ))
-                    self._m_fused.inc()
-                else:
-                    for k, r in enumerate(wave.rows[i:j]):
-                        if not r.live:
-                            continue
-                        nbytes = r.elems * itemsize
-                        if (i + k) in row_arrs:
-                            # fast lane: the landed slab-class array
-                            # swaps in whole (classes match — no pad
-                            # program, no second transfer)
-                            dev = self._dev.get(nbytes)
-                            try:
-                                dev = dev.put_array(row_arrs[i + k])
-                            except Exception:
-                                dev.free()
-                                raise
-                            dev.length = nbytes
-                        elif (i + k) in row_views:
-                            # fused-pid row whose partition unfused
-                            # mid-stage: stage its zero-copy source
-                            # view (pins are still held)
-                            dev = self._dev.stage_view(
-                                row_views[i + k], nbytes, dtype,
-                            )
-                        elif landed is not None:
-                            dev = self._dev.get(nbytes)
-                            try:
-                                dev = dev.put_array(self._take_row(
-                                    landed, slot0 + i + k, wave,
-                                    dev.capacity // itemsize, dtype,
-                                ))
-                            except Exception:
-                                dev.free()
-                                raise
-                            dev.length = nbytes
-                        else:
-                            # assembled row: exact payload through the
-                            # compile-free staging path
-                            dev = self._dev.stage_view(
-                                stacked_host[i + k, : r.elems],
-                                nbytes, dtype,
-                            )
-                        out.append(
-                            CollectiveResult(pid, dev, [r.loc], False)
-                        )
+                except Exception:
+                    dev.free()
+                    raise
             except Exception:
                 logger.exception(
-                    "wave adoption failed for partition %d; degrading", pid
+                    "wave adoption failed for partition %d; degrading",
+                    row.loc.partition_id,
                 )
-                failed.extend(group)
-            i = j
+                failed.append(row)
+                continue
+            dev.length = nbytes
+            out.append(CollectiveResult(row.loc, dev))
         return out, failed
-

@@ -472,7 +472,6 @@ class DeviceShuffleIO:
         end_partition: int,
         dtype=np.uint8,
         timeout_s: Optional[float] = None,
-        fused: bool = False,
     ) -> Dict[int, List[DeviceBuffer]]:
         """Pull every block of ``[start, end)`` into HBM slabs.
 
@@ -501,12 +500,7 @@ class DeviceShuffleIO:
         Device-resident blocks route through the whole-stage schedule
         compiler (shuffle/collective.py, DESIGN.md §22): the host READs
         for the non-device remainder are issued FIRST, then the
-        compiled DMA waves run while those reads are in flight. With
-        ``fused=True`` a partition fully covered by one wave lands as
-        ONE merged slab (its blocks concatenated in deterministic
-        source order) — callers opt in because it changes the result
-        shape; the ``collective.fusedMerge`` knob is the global
-        off-switch."""
+        compiled DMA waves run while those reads are in flight."""
         mgr = self._manager
         conf = mgr.conf
         if timeout_s is None:
@@ -693,12 +687,12 @@ class DeviceShuffleIO:
             # and the drain callback consumes landed READs between
             # pipeline entries (before the waves finish)
             results, degraded = self._collective.execute(
-                shuffle_id, cplan, dtype, fused=fused, drain=_drain_ready
+                shuffle_id, cplan, dtype, drain=_drain_ready
             )
             for r in results:
-                out.setdefault(r.pid, []).append(r.dev)
-            # rows the waves lost (evicted mid-stage, emulated mover
-            # failure) re-issue through the host path: byte-identical
+                out.setdefault(r.loc.partition_id, []).append(r.dev)
+            # rows the waves lost (evicted mid-stage, adoption failure)
+            # re-issue through the host path: byte-identical
             for loc in degraded:
                 _issue(loc, allow_pull=False)
 
@@ -806,9 +800,8 @@ class DeviceShuffleIO:
         out: Dict[int, List[HostBlock]] = {}
         my_id = mgr.executor_id
         locations = self._apply_merged_plan(locations, my_id)
-        # whole-stage compile, UNFUSED: the split-phase pipeline's
-        # verify/stage seams are per block, so every wave row comes
-        # back as its own DevicePulledBlock
+        # whole-stage compile: every wave row comes back as its own
+        # DevicePulledBlock through the per-block verify/stage seams
         with self._tracer.timed("fetch.plan"):
             cplan = self._collective.plan(locations, dtype)
         pending: List[Optional[Tuple]] = []
@@ -896,11 +889,11 @@ class DeviceShuffleIO:
             # drain callback consumes landed READs between pipeline
             # entries
             results, degraded = self._collective.execute(
-                shuffle_id, cplan, dtype, fused=False, drain=_drain_ready
+                shuffle_id, cplan, dtype, drain=_drain_ready
             )
             for r in results:
-                out.setdefault(r.pid, []).append(
-                    DevicePulledBlock(shuffle_id, r.locs[0], r.dev)
+                out.setdefault(r.loc.partition_id, []).append(
+                    DevicePulledBlock(shuffle_id, r.loc, r.dev)
                 )
             for loc in degraded:
                 _issue(loc, allow_pull=False)
@@ -992,9 +985,9 @@ class DeviceShuffleIO:
         """Decode-stage integrity gate (runs on a decode-pool worker):
         validate ``hb`` against its published checksum. A mismatch
         earns one synchronous same-source refetch, then
-        FetchFailedError — the same ladder as the fused path, moved off
-        the transport thread so refetches stall one group's decode, not
-        every group's fetch. Returns the verified handle (possibly a
+        FetchFailedError — the same ladder as ``fetch_device_blocks``,
+        moved off the transport thread so refetches stall one group's
+        decode, not every group's fetch. Returns the verified handle (possibly a
         fresh one; the failed one is released). The ``stage`` fault
         seam (``stage=decode``) fires here, modeling corruption that
         happens AFTER the wire delivered intact bytes."""

@@ -148,7 +148,6 @@ DECLARED_KNOBS: Dict[str, str] = {
     "collective.minBlocks": "device blocks needed to engage the compiler",
     "collective.schedule": "collective schedule: auto|ring|a2a",
     "collective.waveBytes": "max payload bytes per DMA wave",
-    "collective.fusedMerge": "allow fetch+merge fusion in one epoch",
     "collective.laneBalance": "planner balances DMA lanes, not just bytes",
     "collective.pipelineDepth": "in-flight DMA waves in the double-buffered pipeline",
     "collective.autoTune": "attribution-driven per-stage waveBytes self-tuning",
@@ -914,16 +913,6 @@ class TpuShuffleConf:
         return self._bytes("collective.waveBytes", "64m", 1 << 16, 1 << 33)
 
     @property
-    def collective_fused_merge(self) -> bool:
-        """Allow fetch->merge fusion: a partition whose every block
-        arrives in one wave lands as ONE merged slab (concatenated in
-        deterministic source order) with no intermediate HBM round
-        trip. Fusion changes the *shape* of the result (one buffer per
-        partition instead of per block), so callers opt in per fetch;
-        this knob is the global off-switch."""
-        return self._bool("collective.fusedMerge", True)
-
-    @property
     def collective_lane_balance(self) -> bool:
         """Adaptive planner balances per-lane (source executor) DMA
         bytes, not just totals: a partition concentrated in one lane
@@ -951,7 +940,7 @@ class TpuShuffleConf:
         dispatch-bound stage coarsens. The tuned choice is remembered,
         so the second identical stage of a job already runs tuned.
         Never shrinks a wave below the stage's largest partition group
-        (fusion needs a partition's rows in ONE wave)."""
+        (a partition's rows ride ONE wave)."""
         return self._bool("collective.autoTune", True)
 
     @property

@@ -1,7 +1,8 @@
 """Whole-stage collective shuffle (DESIGN.md §22): schedule selection,
-compiled-vs-per-block byte identity, fetch+merge fusion, mid-stage
-degrade, and lane-balanced reduce cuts — all on the emulated
-``JAX_PLATFORMS=cpu`` topology tier-1 runs on."""
+compiled-vs-per-block byte identity, mid-stage degrade, the wave
+movers, and lane-balanced reduce cuts — on the ``JAX_PLATFORMS=cpu``
+topology tier-1 runs on, through the same wave path as the chip (the
+movers' jnp stand-ins in place of the Pallas kernels)."""
 
 import numpy as np
 import pytest
@@ -146,12 +147,11 @@ def test_wave_formation_buckets_and_pid_grouping(cluster):
             for k in range(2)
         ]
         plan = comp.plan(locs)
-        assert plan.fusable_pids == frozenset({0, 1, 2})
         (wave,) = plan.waves
         assert wave.rows_b == round_rows(6)
         longest = max(loc.block.length for loc in locs)
         assert wave.bucket_elems == round_bucket(longest)
-        # pid groups are contiguous in the wave (fusion precondition)
+        # pid groups are contiguous in the wave, in merge order
         pids = [r.loc.partition_id for r in wave.rows]
         assert pids == sorted(pids)
 
@@ -212,74 +212,11 @@ def test_collective_vs_per_block_vs_host_byte_identity(cluster):
     assert via_host == want
 
 
-def test_fused_merge_matches_host_triple(cluster):
-    """fused=True lands ONE merged slab per fully-covered partition,
-    equal to the unfused wave rows concatenated in merge order — and
-    the underlying block multiset matches the host triple exactly."""
-    conf, io_map, io_red = cluster
-    data = _publish_shards(io_map, seed=61)
-    fused_c = _counter("collective.fused_merges", "cs-red")
-    f0 = fused_c.value
-
-    unfused = io_red.fetch_device_blocks(91, 0, 3, timeout_s=30)
-    try:
-        # wave-row order IS the deterministic merge order
-        expect = {
-            p: b"".join(bytes(b.read(0, b.length)) for b in unfused[p])
-            for p in range(3)
-        }
-        multiset = {
-            p: sorted(bytes(b.read(0, b.length)) for b in unfused[p])
-            for p in range(3)
-        }
-    finally:
-        for bufs in unfused.values():
-            for b in bufs:
-                b.free()
-
-    fused = io_red.fetch_device_blocks(91, 0, 3, timeout_s=30, fused=True)
-    try:
-        for p in range(3):
-            assert len(fused[p]) == 1, "fusion must land one slab per pid"
-            assert bytes(fused[p][0].read(0, fused[p][0].length)) == expect[p]
-    finally:
-        for bufs in fused.values():
-            for b in bufs:
-                b.free()
-    assert fused_c.value - f0 == 3
-
-    # the fused content is the host triple's blocks, concatenated
-    conf.set("tpu.shuffle.deviceFetch.enabled", "false")
-    host = io_red.fetch_device_blocks(91, 0, 3, timeout_s=30)
-    try:
-        for p in range(3):
-            host_set = sorted(bytes(b.read(0, b.length)) for b in host[p])
-            assert host_set == multiset[p]
-            assert host_set == sorted(a.tobytes() for a in data[p])
-    finally:
-        for bufs in host.values():
-            for b in bufs:
-                b.free()
-
-    # global off-switch: fused=True silently returns per-block shape
-    conf.set("tpu.shuffle.deviceFetch.enabled", "true")
-    conf.set("tpu.shuffle.collective.fusedMerge", "false")
-    try:
-        got = io_red.fetch_device_blocks(91, 0, 3, timeout_s=30, fused=True)
-        try:
-            assert all(len(got[p]) == 3 for p in range(3))
-        finally:
-            for bufs in got.values():
-                for b in bufs:
-                    b.free()
-    finally:
-        conf.set("tpu.shuffle.collective.fusedMerge", "true")
-
-
 def test_eviction_mid_stage_degrades_silently(cluster):
     """A slab evicted between plan and pin degrades its row to the
-    host triple — zero errors, byte-identical output, degrade counted,
-    and (under fusion) only ITS partition unfuses."""
+    host triple — zero errors, byte-identical output, and exactly that
+    row counted as a degrade, every other block landing per block from
+    the waves."""
     conf, io_map, io_red = cluster
     data = _publish_shards(io_map, seed=67)
     degrades = _counter("collective.degrades", "cs-red")
@@ -291,24 +228,20 @@ def test_eviction_mid_stage_degrades_silently(cluster):
     victim.spill_to_host()
     assert victim.spilled
 
-    got = io_red.fetch_device_blocks(91, 0, 3, timeout_s=30, fused=True)
+    blocks = _counter("collective.blocks", "cs-red")
+    b0 = blocks.value
+    got = io_red.fetch_device_blocks(91, 0, 3, timeout_s=30)
     try:
-        assert len(got[0]) == 1 and len(got[2]) == 1, "other pids stay fused"
-        assert len(got[1]) == 3, "degraded pid must unfuse"
-        # fused pids carry all their blocks (order is the merge order;
-        # membership + total length pin the content)
-        for p in (0, 2):
-            blob = bytes(got[p][0].read(0, got[p][0].length))
-            assert len(blob) == sum(len(a) for a in data[p])
-            for a in data[p]:
-                assert a.tobytes() in blob
-        have1 = sorted(bytes(b.read(0, b.length)) for b in got[1])
-        assert have1 == sorted(a.tobytes() for a in data[1])
+        for p in range(3):
+            assert len(got[p]) == 3, "one slab per block"
+            have = sorted(bytes(b.read(0, b.length)) for b in got[p])
+            assert have == sorted(a.tobytes() for a in data[p])
     finally:
         for bufs in got.values():
             for b in bufs:
                 b.free()
     assert degrades.value - d0 == 1, "exactly the evicted row degrades"
+    assert blocks.value - b0 == 8, "every other block rode a wave"
 
 
 def test_whole_stage_eviction_falls_back_to_host(cluster):
@@ -335,8 +268,7 @@ def test_whole_stage_eviction_falls_back_to_host(cluster):
 
 def test_split_phase_collective_pull(cluster):
     """fetch_host_blocks routes wave rows back as DevicePulledBlock
-    entries (always unfused — the pipeline's seams are per block) that
-    flow through verify/stage untouched."""
+    entries, one per block, that flow through verify/stage untouched."""
     from sparkrdma_tpu.shuffle.device_fetch import DevicePulledBlock
 
     conf, io_map, io_red = cluster
@@ -414,12 +346,12 @@ def test_pipeline_depth_byte_identity_and_overlap(cluster, monkeypatch):
 
 
 def test_pipeline_drain_on_midstage_abort(cluster, monkeypatch):
-    """A wave that dies mid-pipeline (its landing wait fails while the
-    next wave's transfers are already airborne) degrades ITS rows to
-    the host triple without unwinding the stage: output byte-identical,
-    every pin released, no slab leaked on either endpoint."""
+    """A wave whose landing fails mid-pipeline (the next entry's
+    transfers already airborne) raises out of the fetch, as a mover
+    failure does: every pin released, and no slab left behind on either
+    endpoint."""
     from sparkrdma_tpu.obs import attr
-    from sparkrdma_tpu.ops import remote_copy
+    from sparkrdma_tpu.shuffle.collective import ShuffleScheduleCompiler
 
     conf, io_map, io_red = cluster
     monkeypatch.setattr(attr, "_last_breakdown", None)
@@ -427,38 +359,31 @@ def test_pipeline_drain_on_midstage_abort(cluster, monkeypatch):
     conf.set("tpu.shuffle.collective.waveBytes", "192k")
     conf.set("tpu.shuffle.collective.pipelineDepth", "2")
     base_red = io_red.device_buffers.in_use_bytes
-    data = _publish_shards(io_map, seed=83)
-    degrades = _counter("collective.degrades", "cs-red")
-    d0 = degrades.value
+    _publish_shards(io_map, seed=83)
+    base_map = io_map.device_buffers.in_use_bytes
+    issue = ShuffleScheduleCompiler._issue_entry
+    issued, landings = [], []
 
-    real_wait = remote_copy.emulated_wave_wait
-    calls = {"n": 0}
+    def counting_issue(self, waves, *a, **k):
+        issued.append(len(waves))
+        return issue(self, waves, *a, **k)
 
-    def flaky_wait(inflight):
-        calls["n"] += 1
-        if calls["n"] == 1:
-            raise RuntimeError("injected: wave landing failed in flight")
-        return real_wait(inflight)
+    def failing_landing(self, entry):
+        landings.append(len(issued))
+        raise RuntimeError("injected: wave landing failed in flight")
 
-    monkeypatch.setattr(remote_copy, "emulated_wave_wait", flaky_wait)
-    got = io_red.fetch_device_blocks(91, 0, 3, timeout_s=30)
-    try:
-        have = {
-            p: sorted(bytes(b.read(0, b.length)) for b in got[p])
-            for p in range(3)
-        }
-        assert have == {
-            p: sorted(a.tobytes() for a in data[p]) for p in range(3)
-        }
-    finally:
-        for bufs in got.values():
-            for b in bufs:
-                b.free()
-    assert calls["n"] > 1, "injection must hit mid-pipeline, not last wave"
-    assert degrades.value - d0 > 0, "the dead wave's rows must degrade"
-    # leak checks: no pin outlives the stage on the source arena, and
-    # every local slab went back to the pool with the frees above
+    monkeypatch.setattr(ShuffleScheduleCompiler, "_issue_entry",
+                        counting_issue)
+    monkeypatch.setattr(ShuffleScheduleCompiler, "_landed_shard",
+                        failing_landing)
+    with pytest.raises(RuntimeError, match="injected: wave landing"):
+        io_red.fetch_device_blocks(91, 0, 3, timeout_s=30)
+    assert landings == [2], "the first landing fails, the next entry airborne"
+    assert len(issued) == 2, "no entry is issued after the failure"
+    # leak checks: no pin outlives the stage on the source arena, and no
+    # slab is left on either arena
     assert not io_map.device_buffers._pins
+    assert io_map.device_buffers.in_use_bytes == base_map
     assert io_red.device_buffers.in_use_bytes == base_red
 
 
@@ -591,7 +516,8 @@ def test_planner_lane_balanced_cuts():
 
 
 # ----------------------------------------------------------------------
-# the TPU mesh path: Pallas movers, no silent transfer-engine fallback
+# the wave movers: the Pallas kernels on a TPU, their jnp stand-ins
+# elsewhere, one path either way
 # ----------------------------------------------------------------------
 def _host_send_stack(rows, depth, rows_b, bucket_elems, dtype):
     """The host assembly the device gather replaced: a zeroed
@@ -714,17 +640,16 @@ def test_send_gather_programs_bounded_by_classes_in_wide_mixed_waves():
 
 
 @pytest.mark.parametrize(
-    "wave_bytes, depth, fused",
-    [("64m", 1, True), ("128k", 2, False)],
-    ids=["one_fused_wave", "pipelined_waves"],
+    "wave_bytes, depth",
+    [("64m", 1), ("128k", 2)],
+    ids=["one_wave", "pipelined_waves"],
 )
 def test_tpu_mesh_device_gathered_waves_land_byte_identical(
-        cluster, monkeypatch, wave_bytes, depth, fused):
-    """The TPU mesh path end to end on a one-device mesh, an identity
-    mover standing in for the Pallas kernels: the send stack gathered on
-    the device and its landed rows adopted whole (per block, or fused
-    per partition) give the host path's bytes, every row counted as
-    device-assembled and none host-assembled."""
+        cluster, monkeypatch, wave_bytes, depth):
+    """The wave path end to end on a one-device mesh, an identity mover
+    in place of the one-chip movers: the send stack gathered on the
+    device and its landed rows adopted whole, one slab per block, give
+    the host path's bytes, every row counted as device-assembled."""
     from sparkrdma_tpu.ops import remote_copy
 
     conf, io_map, io_red = cluster
@@ -738,26 +663,17 @@ def test_tpu_mesh_device_gathered_waves_land_byte_identical(
         movers.append(d)
         return sharded
 
-    monkeypatch.setattr(remote_copy, "is_tpu_mesh", lambda: True)
     monkeypatch.setattr(remote_copy, "mesh_device_count", lambda: 1)
     monkeypatch.setattr(remote_copy, "pallas_wave_pull", mover)
     monkeypatch.setattr(remote_copy, "pallas_pipelined_wave_pull", mover)
     reg = get_registry()
     before = reg.snapshot()
-    got = io_red.fetch_device_blocks(91, 0, 3, timeout_s=30, fused=fused)
+    got = io_red.fetch_device_blocks(91, 0, 3, timeout_s=30)
     c = reg.delta(before)["counters"]
     try:
         for p in range(3):
+            assert len(got[p]) == 3, "one slab per block"
             slabs = [bytes(b.read(0, b.capacity)) for b in got[p]]
-            if fused:
-                # one slab: the partition's 3 equal-length blocks,
-                # concatenated in merge order
-                (slab,) = slabs
-                n = BLOCK + p
-                assert got[p][0].length == 3 * n
-                assert sorted(slab[k * n : (k + 1) * n] for k in range(3)) \
-                    == sorted(a.tobytes() for a in data[p])
-                continue
             lengths = [b.length for b in got[p]]
             assert sorted(s[:n] for s, n in zip(slabs, lengths)) == sorted(
                 a.tobytes() for a in data[p])
@@ -768,7 +684,6 @@ def test_tpu_mesh_device_gathered_waves_land_byte_identical(
             for b in bufs:
                 b.free()
     assert c["collective.device_assembled_rows{role=cs-red}"] == 9
-    assert c.get("collective.assembly_bytes{role=cs-red}", 0) == 0
     assert (depth,) in movers if depth > 1 else movers
 
 
@@ -777,13 +692,14 @@ def _clear_wave_programs():
 
     remote_copy._wave_pull_program.cache_clear()
     remote_copy._pipelined_wave_pull_program.cache_clear()
+    remote_copy._mesh_wave_pull_program.cache_clear()
 
 
 @pytest.mark.parametrize("depth", [1, 2])
 def test_tpu_mesh_pallas_mover_failure_raises(cluster, monkeypatch, depth):
-    """On a TPU mesh the Pallas movers are the path: a mover failure
-    raises out of the fetch instead of degrading its rows to the host
-    triple, and the pins still release."""
+    """The movers are the path: a mover failure raises out of the fetch
+    instead of degrading its rows to the host triple, and the pins
+    still release."""
     from sparkrdma_tpu.ops import remote_copy
 
     conf, io_map, io_red = cluster
@@ -797,13 +713,12 @@ def test_tpu_mesh_pallas_mover_failure_raises(cluster, monkeypatch, depth):
     def broken(*_a, **_k):
         raise RuntimeError("injected: pallas mover failed")
 
-    monkeypatch.setattr(remote_copy, "is_tpu_mesh", lambda: True)
     monkeypatch.setattr(remote_copy, "pallas_wave_pull", broken)
     monkeypatch.setattr(remote_copy, "pallas_pipelined_wave_pull", broken)
     monkeypatch.setattr(remote_copy, "pallas_mesh_wave_pull", broken)
     with pytest.raises(RuntimeError, match="injected: pallas mover"):
         io_red.fetch_host_blocks(91, 0, 3, timeout_s=30)
-    assert degrades.value == d0, "a TPU mover failure must not degrade"
+    assert degrades.value == d0, "a mover failure must not degrade"
     assert not io_map.device_buffers._pins
 
 
@@ -895,7 +810,7 @@ def test_pallas_wave_fetch_four_devices_interpreted(monkeypatch, depth):
         return publish(io, sid, parts, *a, **k)
 
     epochs, allocated = [], []
-    mesh_pull = remote_copy.pallas_mesh_wave_pull
+    mesh_pull = remote_copy._mesh_wave_kernel
 
     def checked_pull(lane, send, recv, d):
         # the pooled buffer is laid out as the send stack is
@@ -911,7 +826,6 @@ def test_pallas_wave_fetch_four_devices_interpreted(monkeypatch, depth):
 
     monkeypatch.setattr(DeviceShuffleIO, "publish_device_blocks",
                         recording_publish)
-    monkeypatch.setattr(remote_copy, "is_tpu_mesh", lambda: True)
     # a 4-device mesh, as on the 4-chip host (the interpreter's
     # cross-device rendezvous stalls on the 8-device farm's thread pool)
     monkeypatch.setattr(remote_copy, "mesh_device_count", lambda: n)
@@ -955,6 +869,96 @@ def test_pallas_wave_fetch_four_devices_interpreted(monkeypatch, depth):
         assert len(allocated) == 2 * n
 
 
+def _mover_operands(mover, depth, rng):
+    """A hop or slot lane and a stack for one mover, on one chip or on a
+    4-device mesh: returns ``(run, stack)``, ``run(kernel)`` landing the
+    epoch through the Pallas program (``kernel``) or the entry point."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from sparkrdma_tpu.ops import remote_copy
+
+    rows, lanes = 4, remote_copy.wave_row_shape(1024)
+    if mover == "one_chip":
+        mesh = Mesh(jax.devices()[:1], ("x",))
+        shape = (rows, *lanes) if depth == 1 else (depth, rows, *lanes)
+        hops = jax.device_put(np.zeros(shape[:-2], np.int32),
+                              NamedSharding(mesh, P()))
+        stack = jax.device_put(
+            rng.integers(1, 1 << 32, shape, dtype=np.uint32),
+            NamedSharding(mesh, P("x")))
+
+        def run(kernel):
+            if depth == 1:
+                pull = (remote_copy._wave_kernel if kernel
+                        else remote_copy.pallas_wave_pull)
+                return pull(hops, stack)
+            pull = (remote_copy._pipelined_wave_kernel if kernel
+                    else remote_copy.pallas_pipelined_wave_pull)
+            return pull(hops, stack, depth)
+
+        return run, stack
+    n, send_rows, slots = 4, 4, depth * rows
+    mesh = Mesh(jax.devices()[:n], ("x",))
+    sharded = NamedSharding(mesh, P("x"))
+    # per slot: source chip (-1 for a few: no row), send row, hop
+    lane = np.stack([
+        rng.integers(-1, n, slots), rng.integers(0, send_rows, slots),
+        rng.integers(0, n, slots),
+    ]).astype(np.int32)
+    lane[0, :n] = np.arange(n)  # every chip sends at least once
+    lane_dev = jax.device_put(lane.reshape(-1), NamedSharding(mesh, P()))
+    send = jax.device_put(
+        rng.integers(1, 1 << 32, (n * send_rows, *lanes), dtype=np.uint32),
+        sharded)
+
+    def run(kernel):
+        recv = jnp.full((n * slots, *lanes), SENTINEL_WORD, np.uint32,
+                        device=sharded)
+        pull = (remote_copy._mesh_wave_kernel if kernel
+                else remote_copy.pallas_mesh_wave_pull)
+        return pull(lane_dev, send, recv, depth)
+
+    return run, send
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+@pytest.mark.parametrize("mover", ["one_chip", "mesh"])
+def test_wave_mover_stand_ins_match_pallas_programs_interpreted(
+        monkeypatch, mover, depth):
+    """Off a TPU each wave mover runs a jnp stand-in, and it lands the
+    same array, laid out over the same devices, as the mover's Pallas
+    program in TPU interpret mode: on one chip a fresh array holding the
+    stack, on a 4-device mesh (the interpreter stalls on the 8-device
+    farm) each slot's row on its receiving chip only, every other slot
+    still holding the sentinel the receive buffer came with."""
+    import jax
+    from jax.experimental.pallas import tpu as pltpu
+
+    from sparkrdma_tpu.ops import remote_copy
+
+    monkeypatch.setattr(remote_copy, "mesh_device_count",
+                        lambda: 1 if mover == "one_chip" else 4)
+    run, stack = _mover_operands(mover, depth, np.random.default_rng(depth))
+    stand_in = run(kernel=False)
+    _clear_wave_programs()
+    pltpu.set_tpu_interpret_mode(pltpu.InterpretParams())
+    try:
+        pallas = jax.block_until_ready(run(kernel=True))
+    finally:
+        pltpu.set_tpu_interpret_mode(None)
+        _clear_wave_programs()
+    assert stand_in.shape == pallas.shape and stand_in.dtype == pallas.dtype
+    assert stand_in.sharding.is_equivalent_to(pallas.sharding, pallas.ndim)
+    want = np.asarray(pallas)
+    if mover == "mesh":
+        assert (want == SENTINEL_WORD).any() and (want != SENTINEL_WORD).any()
+    # the landed array is the mover's own: it outlives the stack
+    stack.delete()
+    np.testing.assert_array_equal(np.asarray(stand_in), want)
+
+
 def test_one_chip_wave_keeps_its_program_and_stack_shape(cluster,
                                                         monkeypatch):
     """On a one-device mesh the wave path is the one-chip path it was:
@@ -980,7 +984,6 @@ def test_one_chip_wave_keeps_its_program_and_stack_shape(cluster,
     def no_mesh(*_a, **_k):
         raise AssertionError("the mesh mover ran on one device")
 
-    monkeypatch.setattr(remote_copy, "is_tpu_mesh", lambda: True)
     monkeypatch.setattr(remote_copy, "mesh_device_count", lambda: 1)
     monkeypatch.setattr(remote_copy, "pallas_wave_pull", mover)
     monkeypatch.setattr(remote_copy, "pallas_mesh_wave_pull", no_mesh)
