@@ -34,7 +34,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from sparkrdma_tpu.obs import get_tracer
+from sparkrdma_tpu.obs import get_registry, get_tracer
 from sparkrdma_tpu.ops.hbm_arena import DeviceReadback
 from sparkrdma_tpu.ops.sort import (
     device_sort,
@@ -55,11 +55,13 @@ class MapShardSorter:
     The e2e map side was losing to the host baseline by running
     ``np.sort`` per shard while the device sort this framework owns
     runs ~9x host speed (BENCH_r05 ``device_sort_gbps``); this class
-    moves that O(N log N) step onto the chip: pad the shard with the
-    key-space sentinel, one ``device_sort`` (the measured optimum,
-    ops/sort.py), then a device-side ``searchsorted`` against the
-    reducer range edges — the shard lands back on host already sorted
-    AND cut at every reducer boundary, so staging is pure slicing.
+    moves that O(N log N) step onto the chip: send the shard straight to
+    the sorter's device (padded on the host with the key-space sentinel
+    only when it falls short of its size class), one ``device_sort`` (the
+    measured optimum, ops/sort.py), then a device-side ``searchsorted``
+    against the reducer range edges — the shard lands back on host
+    already sorted AND cut at every reducer boundary, so staging is pure
+    slicing.
 
     Compile-once/execute-many: shards pad up to a power-of-two size
     class, so jit's dispatch cache holds ONE executable per
@@ -74,6 +76,11 @@ class MapShardSorter:
         # map.sort.{pad,h2d,device,d2h} spans: the host work around the
         # sort, apart from the sort itself
         self._tracer = tracer if tracer is not None else get_tracer("map")
+        # shards sorted, and those of them sent without a host pad
+        reg = get_registry()
+        role = self._tracer.role
+        self._m_shards = reg.counter("map.sort.shards", role=role)
+        self._m_unpadded = reg.counter("map.sort.unpadded_shards", role=role)
 
         @jax.jit
         def _step(padded, edges, n_valid):
@@ -119,18 +126,27 @@ class MapShardSorter:
         timed = self._tracer.timed
         n = len(keys)
         cap = self._size_class(n)
+        self._m_shards.inc()
         with timed("map.sort.pad"):
-            padded = np.full((cap,), np.uint32(SENTINEL), dtype=np.uint32)
-            padded[:n] = keys
+            # a shard that fills its class goes to the device as it is;
+            # one below it is copied once into an uninitialised class
+            # array whose tail alone takes the sentinel
+            if n == cap:
+                host = np.ascontiguousarray(keys, dtype=np.uint32)
+                self._m_unpadded.inc()
+            else:
+                host = np.empty((cap,), dtype=np.uint32)
+                host[:n] = keys
+                host[n:] = np.uint32(SENTINEL)
         with timed("map.sort.h2d"):
-            dev = jnp.asarray(padded)
-            if self._device is not None:
-                dev = jax.device_put(dev, self._device)
+            # on the CPU this may alias ``keys``; the alias goes with
+            # ``dev`` before this call returns
+            dev = jax.device_put(host, self._device)
+            edges_dev = jax.device_put(
+                np.asarray(edges, dtype=np.uint32), self._device)
             dev.block_until_ready()
         with timed("map.sort.device"):
-            s, cuts = self._step(
-                dev, jnp.asarray(edges, jnp.uint32), jnp.int32(n)
-            )
+            s, cuts = self._step(dev, edges_dev, np.int32(n))
             # the unsorted copy goes once the sort has read it, not at
             # return: the sorted one outlives this call until staging
             # has cut its blocks
@@ -149,9 +165,10 @@ class MapShardSorter:
         """:meth:`sort_partition` taken straight off a columnar block
         (DESIGN.md §25): column 0 of ``frame`` is the uint32 key column,
         decoded as an ``np.frombuffer`` view aliasing the landed bytes —
-        the view feeds the size-class pad copy directly, so consuming a
-        fetched shuffle block on-device costs header validation plus
-        the one HBM DMA. No pickle, no per-record tuples."""
+        the view goes to the device as it is when it fills its size
+        class (else it feeds the pad copy), so consuming a fetched
+        shuffle block on-device costs header validation plus the one
+        HBM DMA. No pickle, no per-record tuples."""
         from sparkrdma_tpu.shuffle import columnar
 
         keys = columnar.decode_columns(frame)[0]

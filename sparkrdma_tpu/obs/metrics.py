@@ -130,6 +130,10 @@ METRIC_FAMILIES: Dict[str, Tuple[str, frozenset]] = {
     "map.sort.h2d_ms": ("histogram", _L()),
     "map.sort.device_ms": ("histogram", _L()),
     "map.sort.d2h_ms": ("histogram", _L()),
+    # map shards sorted, and those of them sent to the device without a
+    # host pad because they fill their size class (models/terasort.py)
+    "map.sort.shards": ("counter", _L({"role"})),
+    "map.sort.unpadded_shards": ("counter", _L({"role"})),
     "map.stage.copy_ms": ("histogram", _L()),
     "map.stage.checksum_ms": ("histogram", _L()),
     "map.stage.arena_ms": ("histogram", _L()),
